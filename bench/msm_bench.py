@@ -46,22 +46,11 @@ import numpy as np
 
 
 def _setup_jax_cache() -> None:
-    """Persist compiled kernels next to the keygen cache (the
-    tests/conftest.py doctrine): repeat bench runs must measure the
+    """Persist compiled kernels: repeat bench runs must measure the
     kernels, not XLA's compile times."""
-    import os
-    import pathlib
+    from protocol_tpu.device import enable_compile_cache
 
-    import jax
-
-    cache_root = os.environ.setdefault(
-        "PROTOCOL_TPU_CACHE",
-        str(Path(__file__).resolve().parent.parent / ".cache" / "protocol_tpu"),
-    )
-    jax_cache = pathlib.Path(cache_root) / "jax"
-    jax_cache.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(jax_cache))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    enable_compile_cache()
 
 
 def _scalar_pool(rng: np.random.Generator, n: int, pool: int, R: int):

@@ -14,18 +14,15 @@ import sys
 
 
 def _ensure_cpu_mesh() -> None:
-    """Force the 8-device virtual CPU mesh before jax's backend
-    initializes (same doctrine as tests/conftest.py): the sharded
-    composites trace under a real Mesh without TPU hardware."""
+    """Force the 8-device virtual CPU mesh before jax is imported
+    (same doctrine as tests/conftest.py): the sharded composites trace
+    under a real Mesh without TPU hardware."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8"
         ).strip()
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -69,22 +66,11 @@ def main(argv: list[str] | None = None) -> int:
     _ensure_cpu_mesh()
     if args.zk:
         # The zk leg compiles EC kernels that take tens of seconds per
-        # (shape, kernel) pair on XLA:CPU; persist executables next to
-        # the keygen cache (same doctrine as tests/conftest.py) so
+        # (shape, kernel) pair on XLA:CPU; persist executables so
         # repeat --zk runs pay compilation once per machine.
-        import pathlib
+        from ..device import enable_compile_cache
 
-        import jax
-
-        cache_root = os.environ.setdefault(
-            "PROTOCOL_TPU_CACHE",
-            str(pathlib.Path(__file__).resolve().parents[2]
-                / ".cache" / "protocol_tpu"),
-        )
-        jax_cache = pathlib.Path(cache_root) / "jax"
-        jax_cache.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(jax_cache))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        enable_compile_cache()
     from .report import Report
 
     report = Report()

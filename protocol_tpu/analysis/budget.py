@@ -62,7 +62,7 @@ class KernelBudget:
     max_scatters: int = 0
     #: f64 avals permitted anywhere in the step jaxpr.
     allow_f64: bool = False
-    #: Exact number of ``psum``/``psum2`` collectives per step; any
+    #: Exact number of ``psum``/``psum_invariant`` collectives per step; any
     #: psum present must sit under a ``shard_map``.
     psum_count: int = 0
     #: Primitives that must appear somewhere in the step (e.g.

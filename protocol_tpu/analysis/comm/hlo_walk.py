@@ -6,7 +6,8 @@ actually emitted.  ``jax``'s AOT path exposes the post-partitioning,
 post-optimization HLO as text (``lowered.compile().as_text()``), and
 that text is a stable, line-oriented format: one op per line with the
 result type, typed operands, attributes, and — crucially — jax's
-``metadata={... source_file=... source_line=...}`` breadcrumb back to
+``metadata={... stack_frame_id=N}`` breadcrumb, resolved through the
+module's ``FileNames``/``FileLocations``/``StackFrames`` tables back to
 the user code that traced the op.  The walker extracts:
 
 - every **collective** (all-reduce / all-gather / reduce-scatter /
@@ -63,12 +64,15 @@ _OP_LINE = re.compile(
     r"\((?P<operands>.*?)\)(?P<attrs>.*)$"
 )
 
+#: ``%name = <type> op(`` — one instruction definition.
+_DEF = re.compile(r"(?P<name>%[\w.\-]+)\s*=\s*(?P<type>\([^=]*?\)|\S+)\s+[\w\-]+\(")
+#: A typed operand (``f32[8]{0} %x``, older dumps) or a bare ``%x``.
+_REF = re.compile(r"(?:[a-z]+\d*\[[\d,]*\](?:\{[\d,]*\})?\s+)?%[\w.\-]+")
 _SHAPE = re.compile(r"(?P<dtype>[a-z]+\d*)\[(?P<dims>[\d,]*)\]")
 
-_METADATA = re.compile(
-    r'metadata=\{[^}]*?source_file="(?P<file>[^"]+)"'
-    r"[^}]*?source_line=(?P<line>\d+)"
-)
+_STACK_FRAME_ID = re.compile(r"metadata=\{[^}]*?stack_frame_id=(?P<id>\d+)")
+_TABLE_ROW = re.compile(r"^(?P<id>\d+) (?P<body>.+)$")
+_FIELD = re.compile(r"(?P<key>\w+)=(?P<value>\d+)")
 _OP_NAME = re.compile(r'op_name="(?P<op_name>[^"]+)"')
 _REPLICA_GROUPS = re.compile(r"replica_groups=(?P<groups>\{[^=]*?\}\})")
 _CUSTOM_TARGET = re.compile(r'custom_call_target="(?P<target>[^"]+)"')
@@ -82,6 +86,44 @@ _HOST_TARGET_MARKS = ("callback", "python", "host_")
 #: ``) }`` so the inner ``{}`` shape-index braces cannot truncate it.
 _ALIAS_TABLE = re.compile(r"input_output_alias=\{(?P<table>.*?)\)\s*\}")
 _ALIAS_PAIR = re.compile(r"\{(?P<out>[\d,\s]*)\}:\s*\((?P<param>\d+)")
+
+
+def source_table(text: str) -> dict[int, tuple[str, int]]:
+    """``stack_frame_id`` -> ``(file, line)`` from the module's trailing
+    ``FileNames``/``FileLocations``/``StackFrames`` tables."""
+    tables: dict[str, dict[int, str]] = {}
+    current: dict[int, str] | None = None
+    for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations", "StackFrames"):
+            current = tables.setdefault(line, {})
+            continue
+        row = _TABLE_ROW.match(line) if current is not None else None
+        if row is None:
+            current = None
+            continue
+        current[int(row.group("id"))] = row.group("body")
+    files = {k: v.strip('"') for k, v in tables.get("FileNames", {}).items()}
+    locations = {}
+    for k, body in tables.get("FileLocations", {}).items():
+        fields = {m.group("key"): int(m.group("value")) for m in _FIELD.finditer(body)}
+        locations[k] = (files.get(fields.get("file_name_id", -1)), fields.get("line"))
+    out = {}
+    for k, body in tables.get("StackFrames", {}).items():
+        fields = {m.group("key"): int(m.group("value")) for m in _FIELD.finditer(body)}
+        file, lineno = locations.get(fields.get("file_location_id", -1), (None, None))
+        if file is not None and lineno is not None:
+            out[k] = (file, lineno)
+    return out
+
+
+def source_site(
+    attrs: str, table: dict[int, tuple[str, int]]
+) -> tuple[str | None, int | None]:
+    """``(file, line)`` of one op's attribute string, via ``table``."""
+    m = _STACK_FRAME_ID.search(attrs)
+    if m is None:
+        return None, None
+    return table.get(int(m.group("id")), (None, None))
 
 
 def shape_bytes(typed: str) -> int:
@@ -215,18 +257,23 @@ def parse_module(text: str) -> ModuleComm:
             out_idx = int((pair.group("out").strip() or "0").split(",")[0])
             mod.aliases[out_idx] = int(pair.group("param"))
 
+    sources = source_table(text)
+    # Compiled modules print operands by name only: resolve each to the
+    # type its defining instruction declares.
+    types = {d.group("name"): d.group("type") for d in _DEF.finditer(text)}
     for line in text.splitlines():
         m = _OP_LINE.match(line)
         if m is None:
             continue
         op = m.group("op")
         attrs = m.group("attrs")
-        meta = _METADATA.search(attrs)
-        file = meta.group("file") if meta else None
-        lineno = int(meta.group("line")) if meta else None
+        file, lineno = source_site(attrs, sources)
         if op.endswith("-done"):
             continue  # the matching -start carries the transfer
-        volume = max(shape_bytes(m.group("result")), shape_bytes(m.group("operands")))
+        operands = " ".join(
+            types.get(ref.group(0), ref.group(0)) for ref in _REF.finditer(m.group("operands"))
+        ) or m.group("operands")
+        volume = max(shape_bytes(m.group("result")), shape_bytes(operands))
         if op == "custom-call":
             target = _CUSTOM_TARGET.search(attrs)
             name = target.group("target") if target else ""
@@ -244,7 +291,7 @@ def parse_module(text: str) -> ModuleComm:
             CollectiveOp(
                 kind=_normalize_kind(op),
                 result_bytes=shape_bytes(m.group("result")),
-                operand_bytes=shape_bytes(m.group("operands")),
+                operand_bytes=shape_bytes(operands),
                 replica_groups=groups.group("groups") if groups else "",
                 op_name=op_name.group("op_name") if op_name else "",
                 file=file,
@@ -261,4 +308,6 @@ __all__ = [
     "parse_module",
     "replica_group_sizes",
     "shape_bytes",
+    "source_site",
+    "source_table",
 ]

@@ -46,7 +46,7 @@ class CommCase:
     module_text: str
     #: Entry-point argument names, parameter order (donation mapping).
     arg_names: tuple[str, ...]
-    #: psum/psum2 count in the traced jaxpr of the same entry point.
+    #: psum/psum_invariant count in the traced jaxpr of the same entry point.
     jaxpr_psums: int = 0
     #: Buffer-assignment view of the same executable (pass 12):
     #: ``compiled.memory_analysis()`` per-device byte totals, or None

@@ -49,6 +49,7 @@ import re
 from typing import Any
 
 from ..report import Finding
+from ..comm.hlo_walk import source_site, source_table
 from ..comm.lowering import COMM_BUILDERS, COMM_SCALES, build_cases
 from .ast_walk import DET_AST_RULES, run_det_ast_pass
 from .waivers import DET_WAIVERS
@@ -113,6 +114,7 @@ def diff_canonical(text_a: str, text_b: str, *, context: int = 1) -> str | None:
 
 _SCATTER_OP = re.compile(r"=\s*\S+\s+scatter\(")
 _REDUCE_PRECISION_OP = re.compile(r"=\s*\S+\s+reduce-precision\(")
+_OP_NAME = re.compile(r'op_name="([^"]+)"')
 
 
 def scan_module_text(backend: str, module_text: str) -> tuple[list[Finding], dict]:
@@ -121,19 +123,23 @@ def scan_module_text(backend: str, module_text: str) -> tuple[list[Finding], dic
     findings: list[Finding] = []
     scatter_ops = 0
     reduce_precision_ops = 0
+    sources = source_table(module_text)
     for i, line in enumerate(module_text.splitlines(), start=1):
         if _SCATTER_OP.search(line):
             scatter_ops += 1
             if "unique_indices=true" not in line:
+                op_name = _OP_NAME.search(line)
+                file, lineno = source_site(line, sources)
                 findings.append(_finding(
                     "hlo-nondeterministic-scatter",
-                    f"scatter at module line {i} lacks "
+                    f"scatter at module line {i} "
+                    f"({op_name.group(1) if op_name else '?'}) lacks "
                     "unique_indices=true — duplicate-index updates "
                     "combine in backend schedule order, so two hosts can "
                     "legally produce different f32 sums from identical "
                     "operands; segment the indices (or assert uniqueness "
                     "at plan build) before this reaches the epoch loop",
-                    backend, line=i,
+                    backend, file=file, line=lineno or i,
                 ))
         if _REDUCE_PRECISION_OP.search(line):
             reduce_precision_ops += 1
@@ -194,7 +200,10 @@ def _apply_waivers(findings: list[Finding]) -> tuple[list[Finding], list[dict], 
                 "symbol": hit[1].symbol, "reason": hit[1].reason,
             })
     stale = [
-        {"symbol": w.symbol, "rule": w.rule, "reason": w.reason}
+        {
+            "symbol": w.symbol, "rule": w.rule, "reason": w.reason,
+            "backend": getattr(w, "backend", None),
+        }
         for i, w in enumerate(DET_WAIVERS)
         if i not in matched
     ]
@@ -289,7 +298,11 @@ def run_determinism_pass(
         # A backend-subset run never evaluates the AST leg, so the
         # staleness of an AST-rule waiver cannot be judged there —
         # only waivers whose domain this run covered may go stale.
-        stale = [s for s in stale if s["rule"] not in DET_AST_RULES]
+        stale = [
+            s for s in stale
+            if s["rule"] not in DET_AST_RULES
+            and s["backend"] in (None, *targets)
+        ]
     for entry in stale:
         # A dead waiver is itself a gate failure — pass-7 doctrine,
         # enforced in every run that evaluates its table.

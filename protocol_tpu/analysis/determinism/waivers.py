@@ -8,7 +8,18 @@ divergence source takes its waiver with it.
 
 from __future__ import annotations
 
+from dataclasses import dataclass
+
 from ..concurrency.waivers import Waiver
+
+
+@dataclass(frozen=True)
+class HloWaiver(Waiver):
+    """A waiver of an HLO-leg finding: ``backend`` names the compiled
+    module it lives in, so a backend-subset run judges its staleness
+    only when it compiled that backend."""
+
+    backend: str = ""
 
 #: (rule, file substring, message substring) -> rationale — see
 #: :class:`~protocol_tpu.analysis.concurrency.waivers.Waiver`.
@@ -32,6 +43,23 @@ DET_WAIVERS: tuple[Waiver, ...] = (
             "bit-identical regardless."
         ),
     ),
+    HloWaiver(
+        rule="hlo-nondeterministic-scatter",
+        file="protocol_tpu/ops/sparse.py",
+        symbol="jit(converge_sparse)/",
+        backend="tpu-sparse",
+        reason=(
+            "tpu-sparse IS the COO segment_sum formulation: its one "
+            "sorted scatter-add (indices_are_sorted=true) is the kernel "
+            "budget's declared scatter (KERNEL_INVARIANTS['tpu-sparse'] "
+            "max_scatters=1).  It is a dominated reference rung (ROADMAP "
+            "C3), never the node default, and no pod or bit-identity "
+            "plane runs it — the pod and the divergence probe converge "
+            "on tpu-windowed.  The previous JAX's compiled CPU module "
+            "showed the scan no scatter here (PERF.md §21 of PR 17); "
+            "0.9.0's does."
+        ),
+    ),
 )
 
-__all__ = ["DET_WAIVERS"]
+__all__ = ["DET_WAIVERS", "HloWaiver"]

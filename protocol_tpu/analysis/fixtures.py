@@ -58,13 +58,12 @@ def _f64_leak() -> tuple[KernelBudget, TraceCase]:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental import enable_x64
 
     def step(t):
         wide = t.astype(jnp.float64)  # VIOLATION: f64-leak
         return wide * 2.0
 
-    with enable_x64():
+    with jax.enable_x64():
         jaxpr = jax.make_jaxpr(step)(np.ones(4, np.float32))
     budget = KernelBudget(backend="fixture:f64-leak", max_random_gathers=0)
     return budget, TraceCase("fixture:f64-leak", jaxpr)
@@ -550,7 +549,6 @@ def _surprise_all_gather():
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.sharded import _shard_map
     from .budget import CollectiveBudget, CommBudget
 
     mesh, axis = _comm_mesh()
@@ -559,7 +557,7 @@ def _surprise_all_gather():
         np.ones(64 * n_shards, np.float32), NamedSharding(mesh, P(axis))
     )
 
-    @partial(_shard_map, mesh=mesh, in_specs=P(axis), out_specs=P())
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P())
     def step(local):
         # The partitioner-surprise anti-pattern: re-materializing the
         # full edge slice on every shard before reducing.
@@ -587,7 +585,6 @@ def _comm_bytes_over_budget():
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.sharded import _shard_map
     from .budget import CollectiveBudget, CommBudget
 
     mesh, axis = _comm_mesh()
@@ -595,7 +592,7 @@ def _comm_bytes_over_budget():
     n, e = 64, 4096
     v = jax.device_put(np.ones(e, np.float32), NamedSharding(mesh, P()))
 
-    @partial(_shard_map, mesh=mesh, in_specs=P(), out_specs=P())
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P())
     def step(acc):
         # An O(E) psum inside the iteration loop: every step ships the
         # whole edge-sized vector over the wire instead of the N-sized
@@ -670,7 +667,6 @@ def _psum_lowering_mismatch():
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.sharded import _shard_map
     from .budget import CollectiveBudget, CommBudget
 
     mesh, axis = _comm_mesh()
@@ -678,7 +674,7 @@ def _psum_lowering_mismatch():
         np.arange(16, dtype=np.float32), NamedSharding(mesh, P(axis))
     )
 
-    @partial(_shard_map, mesh=mesh, in_specs=P(axis), out_specs=P())
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P())
     def step(local):
         # The dead psum survives the jaxpr but DCE strips it from the
         # compiled module: the jaxpr now LIES about the wire — exactly
@@ -775,7 +771,6 @@ def _shard_replicated_edges():
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.sharded import _shard_map
     from .budget import MemBudget
 
     mesh, axis = _comm_mesh()
@@ -786,7 +781,7 @@ def _shard_replicated_edges():
     # per-device resident holds all E entries, not E/n_shards.
     w = jax.device_put(np.ones(e, np.float32), NamedSharding(mesh, P()))
 
-    @partial(_shard_map, mesh=mesh, in_specs=P(), out_specs=P())
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P())
     def step(w_full):  # VIOLATION: shard-replicated-edges
         return lax.psum(jnp.sum(w_full), "shard") / n_shards
 

@@ -19,9 +19,10 @@ import jax
 SCATTER_PRIMITIVES = frozenset(
     {"scatter", "scatter-add", "scatter-mul", "scatter-min", "scatter-max"}
 )
-#: ``psum2`` is the rewrite shard_map applies to ``psum`` under its
-#: replication checker — the same collective on the wire.
-PSUM_PRIMITIVES = frozenset({"psum", "psum2"})
+#: ``psum_invariant`` is what ``psum`` traces to inside a
+#: ``shard_map`` with its varying-axes check on — the same collective
+#: on the wire.
+PSUM_PRIMITIVES = frozenset({"psum", "psum_invariant"})
 CALLBACK_PRIMITIVES = frozenset(
     {"pure_callback", "io_callback", "debug_callback", "callback"}
 )
@@ -117,15 +118,12 @@ def primitive_counts(jaxpr: Any) -> dict[str, int]:
 def source_site(eqn: Any) -> tuple[str | None, int | None]:
     """Best-effort ``(file, line)`` of the user code that traced this
     equation (jaxpr source_info; internal frames filtered by jax)."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return frame.file_name, frame.start_line
-    except Exception:
-        pass
-    return None, None
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return None, None
+    return frame.file_name, frame.start_line
 
 
 def has_f64(jaxpr: Any) -> list[EqnSite]:

@@ -31,7 +31,7 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass
 
-from ..comm.hlo_walk import shape_bytes
+from ..comm.hlo_walk import shape_bytes, source_site, source_table
 
 #: One op line: ``%name = <type> <op>(<operands>)<attrs>`` — the
 #: general form this time, not just collectives.
@@ -44,10 +44,6 @@ _ANY_OP = re.compile(
 #: Operand references inside the parenthesized operand list.
 _REF = re.compile(r"%(?P<ref>[\w.\-]+)")
 
-_METADATA = re.compile(
-    r'metadata=\{[^}]*?source_file="(?P<file>[^"]+)"'
-    r"[^}]*?source_line=(?P<line>\d+)"
-)
 _OP_NAME = re.compile(r'op_name="(?P<op_name>[^"]+)"')
 
 #: Computation headers: ``%name (params...) -> type {`` or ``ENTRY ...``.
@@ -134,6 +130,7 @@ def largest_temp_site(text: str) -> TempSite | None:
     (metadata-bearing ops preferred at equal size) — the attribution
     anchor for a transient-over-budget finding."""
     best: TempSite | None = None
+    sources = source_table(text)
     for block in _computation_blocks(text):
         for line in block:
             m = _ANY_OP.match(line)
@@ -141,14 +138,14 @@ def largest_temp_site(text: str) -> TempSite | None:
                 continue
             nbytes = shape_bytes(m.group("type"))
             attrs = m.group("attrs")
-            meta = _METADATA.search(attrs)
             op_name = _OP_NAME.search(attrs)
+            file, lineno = source_site(attrs, sources)
             site = TempSite(
                 bytes=nbytes,
                 op=m.group("op"),
                 op_name=op_name.group("op_name") if op_name else "",
-                file=meta.group("file") if meta else None,
-                line=int(meta.group("line")) if meta else None,
+                file=file,
+                line=lineno,
             )
             if (
                 best is None

@@ -26,6 +26,7 @@ import time
 from concurrent.futures import BrokenExecutor, ProcessPoolExecutor
 from multiprocessing import get_context
 
+from ..device import pin_to_host
 from ..obs import metrics as obs_metrics
 from ..obs.journal import (
     JOURNAL,
@@ -44,11 +45,13 @@ WorkItem = tuple[int, int, int, int, int, tuple[int, ...]]
 
 
 def _worker_init(dump_dir: str | None = None) -> None:
-    """Runs in each spawned worker before any batch: pin the native
-    runtime to one OpenMP thread so the pool scales by process,
-    pre-load the crypto tree off the critical path, and install the
-    flight-recorder dump handler so a SIGTERM'd worker leaves its
-    event ring behind for the parent's post-mortem."""
+    """Runs in each spawned worker before any batch: keep JAX off the
+    chip the parent holds, pin the native runtime to one OpenMP thread
+    so the pool scales by process, pre-load the crypto tree off the
+    critical path, and install the flight-recorder dump handler so a
+    SIGTERM'd worker leaves its event ring behind for the parent's
+    post-mortem."""
+    pin_to_host()
     os.environ["OMP_NUM_THREADS"] = "1"
     install_worker_dump_handler(dump_dir, pool="ingest-verify")
     from ..crypto import native as cnative

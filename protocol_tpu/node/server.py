@@ -22,6 +22,7 @@ from dataclasses import dataclass, field
 import json
 
 from .. import chaos
+from ..device import enable_compile_cache
 from ..obs import (
     DRIFT,
     JOURNAL,
@@ -460,6 +461,19 @@ class Node:
     _recovery: dict = field(
         default_factory=lambda: {"state": "disabled"}, repr=False
     )
+
+    def __post_init__(self) -> None:
+        # The graft proving kernels run on the device; a spawned prover
+        # worker would open the chip this process already holds.
+        if (
+            self.manager.config.zk_backend == "graft"
+            and self.config.async_prover
+            and self.config.prover_workers > 0
+        ):
+            raise ValueError(
+                "zk_backend='graft' needs the device, which the node "
+                "process holds: set prover_workers=0 to prove in-process"
+            )
 
     @classmethod
     def from_config(cls, config: ProtocolConfig) -> "Node":
@@ -1148,6 +1162,7 @@ def main(argv=None) -> None:
     # configured the root logger already, and stamps every record with
     # the current epoch/span ids either way.
     configure_logging(level=logging.INFO)
+    enable_compile_cache()
     config = ProtocolConfig.load(args.config)
     node = Node.from_config(config)
     try:

@@ -385,7 +385,7 @@ declare_mem(
         resident_edge_bytes=8.0,  # src + w (row_ptr rides resident_n)
         resident_n=16.0,  # t0 + p + dangling + (n+1) row pointers
         resident_const=4096.0,
-        transient_n=445.0,  # contribution stream + cumsum scan levels
+        transient_n=430.0,  # contribution stream + cumsum scan levels
         transient_const=8192.0,
         donated_args=("t0",),
         notes="scatter-free CSR: the compensated-cumsum rowsum streams "

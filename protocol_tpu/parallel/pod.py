@@ -50,6 +50,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from ..device import pallas_interpret
 from ..ops.gather_window import (
     PLAN_VERSION,
     WindowPlan,
@@ -254,7 +255,7 @@ class PodWindowPlan:
             )
 
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = pallas_interpret()
         return cls(
             mesh=pod.mesh,
             n=plan.n,

@@ -28,6 +28,7 @@ import threading
 from concurrent.futures import BrokenExecutor, ProcessPoolExecutor, TimeoutError
 from multiprocessing import get_context
 
+from ..device import pin_to_host
 from ..obs import metrics as obs_metrics
 from ..obs.journal import (
     JOURNAL,
@@ -38,11 +39,13 @@ from .jobs import ProofJob, ProofResult, prove_job, prover_for
 
 
 def _worker_init(omp_threads: int, dump_dir: str | None = None) -> None:
-    """Runs in each spawned worker before any job: pin (or free) the
-    native runtime's OpenMP width, install the flight-recorder dump
-    handler (a SIGTERM'd — e.g. hung-and-terminated — worker leaves
-    its event ring behind for the parent's post-mortem), and pre-load
-    the zk runtime off the first job's critical path."""
+    """Runs in each spawned worker before any job: keep JAX off the
+    chip the parent holds, pin (or free) the native runtime's OpenMP
+    width, install the flight-recorder dump handler (a SIGTERM'd — e.g.
+    hung-and-terminated — worker leaves its event ring behind for the
+    parent's post-mortem), and pre-load the zk runtime off the first
+    job's critical path."""
+    pin_to_host()
     if omp_threads > 0:
         os.environ["OMP_NUM_THREADS"] = str(omp_threads)
     install_worker_dump_handler(dump_dir, pool="prover")

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..device import pallas_interpret
 from ..ops.dense import converge_dense
 from ..ops.gather_window import (
     PLAN_VERSION,
@@ -336,8 +337,8 @@ class WindowedJaxBackend(TrustBackend):
         #: Candidate plan to reuse (e.g. checkpoint-restored); replaced
         #: when its fingerprint doesn't match the converged graph.
         self.plan = plan
-        #: Pallas interpret mode; default: interpret off real TPUs only
-        #: (CPU test runs exercise the identical lowered computation).
+        #: Pallas interpret mode; default: ``device.pallas_interpret``
+        #: (compiled on TPU, interpreted on the CPU test platform).
         self.interpret = interpret
         #: The plan the last converge actually used (for persistence).
         self.last_plan: WindowPlan | None = plan
@@ -393,9 +394,7 @@ class WindowedJaxBackend(TrustBackend):
         self.last_plan = plan
         p = graph.pre_trust_vector()
         interpret = (
-            self.interpret
-            if self.interpret is not None
-            else jax.default_backend() != "tpu"
+            self.interpret if self.interpret is not None else pallas_interpret()
         )
         with TRACER.span("converge", backend=self.name):
             out = converge_windowed(

@@ -190,8 +190,15 @@ class TestHloWalk:
             "channel_id=1, replica_groups={{0,1,2,3,4,5,6,7}}, "
             "use_global_device_ids=true, to_apply=%region_1.205, "
             'metadata={op_name="jit(run)/jit(main)/while/body/'
-            'jit(shmap_body)/psum2" source_file="/repo/parallel/sharded.py" '
-            "source_line=171}\n"
+            'shard_map/psum_invariant" stack_frame_id=2}\n'
+            "\nFileNames\n"
+            '1 "/repo/parallel/sharded.py"\n'
+            "\nFileLocations\n"
+            "1 {file_name_id=1 function_name_id=1 line=120 end_line=120}\n"
+            "2 {file_name_id=1 function_name_id=1 line=171 end_line=171}\n"
+            "\nStackFrames\n"
+            "1 {file_location_id=1 parent_frame_id=1}\n"
+            "2 {file_location_id=2 parent_frame_id=1}\n"
         )
         mod = parse_module(text)
         (op,) = mod.collectives

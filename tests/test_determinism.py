@@ -457,13 +457,17 @@ class TestRealTree:
         ]
         assert [w["symbol"] for w in waived] == ["random.Random"]
         assert waived[0]["file"] == "protocol_tpu/node/ethereum.py"
-        assert stale == []
+        # The AST leg alone judges only the AST-rule waivers.
+        assert [s for s in stale if s["rule"] in DET_AST_RULES] == []
 
     def test_waiver_table_is_enumerated_not_patterned(self):
-        assert len(DET_WAIVERS) == 1
-        w = DET_WAIVERS[0]
-        assert w.rule in DET_AST_RULES
-        assert w.reason  # every waiver carries its rationale
+        assert [w.rule for w in DET_WAIVERS] == [
+            "unseeded-rng", "hlo-nondeterministic-scatter",
+        ]
+        assert DET_WAIVERS[0].rule in DET_AST_RULES
+        assert DET_WAIVERS[1].backend == "tpu-sparse"
+        for w in DET_WAIVERS:
+            assert w.reason  # every waiver carries its rationale
 
     def test_dead_det_waiver_is_error(self, monkeypatch):
         from protocol_tpu.analysis.concurrency.waivers import Waiver

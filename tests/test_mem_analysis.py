@@ -239,10 +239,16 @@ class TestLivenessWalk:
         "ENTRY %main (a: f32[1024], b: f32[1024]) -> f32[] {\n"
         "  %a = f32[1024]{0} parameter(0)\n"
         "  %b = f32[1024]{0} parameter(1)\n"
-        '  %big = f32[4096]{0} broadcast(f32[1024]{0} %a), metadata={op_name="jit(f)/bc" source_file="/repo/x.py" source_line=7}\n'
+        '  %big = f32[4096]{0} broadcast(f32[1024]{0} %a), metadata={op_name="jit(f)/bc" stack_frame_id=1}\n'
         "  %s = f32[] reduce(f32[4096]{0} %big, f32[] %b)\n"
         "  ROOT %r = f32[] add(f32[] %s, f32[] %s)\n"
         "}\n"
+        "\nFileNames\n"
+        '1 "/repo/x.py"\n'
+        "\nFileLocations\n"
+        "1 {file_name_id=1 function_name_id=1 line=7 end_line=7}\n"
+        "\nStackFrames\n"
+        "1 {file_location_id=1 parent_frame_id=1}\n"
     )
 
     def test_live_range_peak_counts_temps_not_params(self):

@@ -1,9 +1,8 @@
-"""Perf-regression sentinel (ISSUE 6): the committed BENCH_r*.json
-trajectory is finally *read* — the sentinel gates on per-metric trend
-deltas against the best recorded value."""
+"""Perf-regression sentinel (ISSUE 6): recorded BENCH_r*.json
+trajectories are *read* — the sentinel gates on per-metric trend deltas
+against the best recorded value."""
 
 import json
-import shutil
 import sys
 from pathlib import Path
 
@@ -26,11 +25,34 @@ def _write_rounds(path: Path, values: list[float], **kw) -> None:
         (path / f"BENCH_r{i:02d}.json").write_text(json.dumps(_record(i, v, **kw)))
 
 
-class TestRealSeries:
-    def test_committed_bench_series_passes(self, tmp_path):
-        """Acceptance: exit 0 on the real BENCH_r01..r05 series."""
+#: The driver's bench-round shape: the command, its exit code, the end
+#: of its output, and the headline line parsed out of it.
+HEADLINE = "1M-peer/50M-edge global-trust convergence wall-clock (40 power iters)"
+
+
+def _bench_round(n: int, value: float) -> dict:
+    line = {"metric": HEADLINE, "value": value, "unit": "seconds"}
+    return {
+        "n": n,
+        "cmd": "python bench.py",
+        "rc": 0,
+        "tail": json.dumps(line) + "\n",
+        "parsed": line,
+    }
+
+
+def _write_bench_series(path: Path, values: list[float]) -> None:
+    for i, v in enumerate(values, start=1):
+        (path / f"BENCH_r{i:02d}.json").write_text(json.dumps(_bench_round(i, v)))
+
+
+class TestBenchSeries:
+    def test_improving_bench_series_passes(self, tmp_path):
+        """Acceptance: exit 0 on a five-round headline series that
+        only improves."""
+        _write_bench_series(tmp_path, [42.4, 18.2, 17.9, 17.8, 17.76])
         out = tmp_path / "SENTINEL.json"
-        rc = perf_sentinel.main(["--history", str(REPO), "--out", str(out)])
+        rc = perf_sentinel.main(["--history", str(tmp_path), "--out", str(out)])
         assert rc == 0
         report = json.loads(out.read_text())
         assert report["ok"] is True
@@ -38,6 +60,12 @@ class TestRealSeries:
         # The headline series was parsed across all five rounds.
         headline = [k for k in report["series"] if "convergence wall-clock" in k]
         assert headline and report["series"][headline[0]]["rounds"] == 5
+
+    def test_sentinel_passes_on_the_tree(self, tmp_path):
+        """The default-glob run over the committed records exits 0."""
+        out = tmp_path / "SENTINEL.json"
+        assert perf_sentinel.main(["--history", str(REPO), "--out", str(out)]) == 0
+        assert json.loads(out.read_text())["ok"] is True
 
 
 class TestIngestSeries:
@@ -131,11 +159,8 @@ class TestSyntheticRegression:
     def test_regressed_latest_round_fails(self, tmp_path):
         """Acceptance: exit non-zero on a synthetically regressed
         fixture — the newest round is >threshold worse than the best."""
-        for f in REPO.glob("BENCH_r0*.json"):
-            shutil.copy(f, tmp_path / f.name)
-        rec = json.loads((REPO / "BENCH_r05.json").read_text())
-        rec["n"] = 6
-        rec["parsed"]["value"] = rec["parsed"]["value"] * 3
+        _write_bench_series(tmp_path, [42.4, 18.2, 17.9, 17.8, 17.76])
+        rec = _bench_round(6, 17.76 * 3)
         (tmp_path / "BENCH_r06.json").write_text(json.dumps(rec))
         out = tmp_path / "SENTINEL.json"
         rc = perf_sentinel.main(["--history", str(tmp_path), "--out", str(out)])
@@ -386,11 +411,16 @@ class TestCommBytesSeries:
             f"{self.METRIC} :: comm_bytes_per_iter"
         ]
 
-    def test_legacy_multichip_rounds_yield_no_series(self):
-        """The pre-ISSUE-9 MULTICHIP_r01..r05 records (rc/ok/tail only)
-        are in the default globs but carry no metric entries — they
-        must contribute nothing rather than break the parse."""
-        series = perf_sentinel.collect_series([REPO / "MULTICHIP_r01.json"])
+    def test_legacy_multichip_rounds_yield_no_series(self, tmp_path):
+        """Pre-ISSUE-9 MULTICHIP rounds (rc/ok/tail only) match the
+        default globs but carry no metric entries — they must
+        contribute nothing rather than break the parse."""
+        legacy = tmp_path / "MULTICHIP_r01.json"
+        legacy.write_text(json.dumps({
+            "n_devices": 8, "rc": 0, "ok": True, "skipped": False,
+            "tail": "dryrun_multichip: 8 devices, n=512, 8 iters — OK\n",
+        }))
+        series = perf_sentinel.collect_series([legacy])
         assert series == {}
 
     def test_committed_multichip_round_feeds_the_gate(self, tmp_path):
