@@ -50,18 +50,6 @@ WAIVERS: tuple[Waiver, ...] = (
     ),
     Waiver(
         rule="unguarded-rmw",
-        file="protocol_tpu/utils/telemetry.py",
-        symbol="TimerStats.",
-        reason=(
-            "TimerStats.record() mutates count/total, but every call "
-            "site reaches it as `self.timers[name].record(...)` inside "
-            "`with self._lock` on Telemetry — a cross-class guard the "
-            "analyzer cannot see through a subscript receiver.  The "
-            "lock-witness stress test watches these writes at runtime."
-        ),
-    ),
-    Waiver(
-        rule="unguarded-rmw",
         file="protocol_tpu/zk/cs.py",
         symbol="ConstraintSystem.n_rows",
         reason=(

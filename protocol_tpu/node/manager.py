@@ -583,16 +583,16 @@ class Manager:
                     scale=cfg.scale,
                 )
 
-        # Proving time lands in telemetry, the structured analog of the
+        # Proving time lands in the ``snark`` span (and so in
+        # eigentrust_phase_seconds), the structured analog of the
         # reference's "Proving time: {:?}" print (circuit/src/utils.rs:305-321).
         from ..prover.jobs import job_seed
-        from ..utils.telemetry import TELEMETRY
 
         # The statement-bound blinding seed keeps the synchronous path
         # byte-identical to the pooled path for the same input (the
         # async-prover equivalence contract).
         seed = job_seed(self.build_proof_job(epoch))
-        with TELEMETRY.timer("epoch.prove"), TRACER.span("snark"):
+        with TRACER.span("snark"):
             proof_bytes = self.prover.prove(pub_ins, witness, seed=seed)
         if __debug__:
             assert self.prover.verify(pub_ins, proof_bytes)
@@ -607,49 +607,54 @@ class Manager:
             freshness={"completed": len(e2e)},
         )
 
-    def _warm_t0(self, id_order: list[int]) -> np.ndarray | None:
+    def _warm_t0(
+        self, id_order: list[int], epoch: int | None = None
+    ) -> np.ndarray | None:
         """Remap the previous epoch's fixed point onto the new graph's
         id space: surviving peers keep their score, departed peers'
         mass drops out, joined peers start at zero, and the result is
         L1-renormalized.  None (cold start) when there is no previous
         state or the overlap is empty — the backends treat None as
-        "start from the pre-trust vector"."""
-        # Scores and their peer-hash column publish together in
-        # converge_prepared (pipeline device thread); read them as a
-        # matched pair or the warm seed maps scores onto wrong peers.
-        with self._state_lock:
-            scores, hashes = self.last_scores, self.last_peer_hashes
-        if scores is None or hashes is None or not len(hashes) or not len(scores):
-            return None
-        # Vectorized remap (PERF.md §20): the per-peer dict walk cost
-        # ~7 s of pure Python at the pod's 10M-peer scale; folding the
-        # Poseidon hashes to 64-bit keys and matching via one sorted
-        # searchsorted pass is ~30x faster.  A low-64-bit collision
-        # (≈ n²/2⁶⁴ odds) can only misplace one seed entry — the seed
-        # is renormalized and the fixed point is start-independent, so
-        # the failure mode is a marginally longer converge, never a
-        # wrong score.
-        from ..parallel.partition import keys_from_hashes
+        "start from the pre-trust vector".  Runs under a ``warm_seed``
+        span (tagged with ``epoch`` where the caller knows it)."""
+        attrs = {"peers": len(id_order)} | ({} if epoch is None else {"epoch": epoch})
+        with TRACER.span("warm_seed", **attrs):
+            # Scores and their peer-hash column publish together in
+            # converge_prepared (pipeline device thread); read them as a
+            # matched pair or the warm seed maps scores onto wrong peers.
+            with self._state_lock:
+                scores, hashes = self.last_scores, self.last_peer_hashes
+            if scores is None or hashes is None or not len(hashes) or not len(scores):
+                return None
+            # Vectorized remap (PERF.md §20): the per-peer dict walk cost
+            # ~7 s of pure Python at the pod's 10M-peer scale; folding the
+            # Poseidon hashes to 64-bit keys and matching via one sorted
+            # searchsorted pass is ~30x faster.  A low-64-bit collision
+            # (≈ n²/2⁶⁴ odds) can only misplace one seed entry — the seed
+            # is renormalized and the fixed point is start-independent, so
+            # the failure mode is a marginally longer converge, never a
+            # wrong score.
+            from ..parallel.partition import keys_from_hashes
 
-        prev_keys = keys_from_hashes(hashes)
-        new_keys = keys_from_hashes(id_order)
-        order = np.argsort(prev_keys, kind="stable")
-        sorted_prev = prev_keys[order]
-        pos = np.searchsorted(sorted_prev, new_keys)
-        pos = np.minimum(pos, max(len(sorted_prev) - 1, 0))
-        hit = (
-            (sorted_prev[pos] == new_keys)
-            if len(sorted_prev)
-            else np.zeros(len(new_keys), bool)
-        )
-        j = order[pos]
-        hit &= j < len(scores)
-        prev_scores = np.maximum(np.asarray(scores, np.float64), 0.0)
-        t0 = np.where(hit, prev_scores[np.minimum(j, len(scores) - 1)], 0.0)
-        total = t0.sum()
-        if not hit.any() or not np.isfinite(total) or total <= 0:
-            return None
-        return t0 / total
+            prev_keys = keys_from_hashes(hashes)
+            new_keys = keys_from_hashes(id_order)
+            order = np.argsort(prev_keys, kind="stable")
+            sorted_prev = prev_keys[order]
+            pos = np.searchsorted(sorted_prev, new_keys)
+            pos = np.minimum(pos, max(len(sorted_prev) - 1, 0))
+            hit = (
+                (sorted_prev[pos] == new_keys)
+                if len(sorted_prev)
+                else np.zeros(len(new_keys), bool)
+            )
+            j = order[pos]
+            hit &= j < len(scores)
+            prev_scores = np.maximum(np.asarray(scores, np.float64), 0.0)
+            t0 = np.where(hit, prev_scores[np.minimum(j, len(scores) - 1)], 0.0)
+            total = t0.sum()
+            if not hit.any() or not np.isfinite(total) or total <= 0:
+                return None
+            return t0 / total
 
     @contextmanager
     def _plan_cache(self, backend, delta_rows: np.ndarray | None = None):
@@ -712,7 +717,7 @@ class Manager:
             },
             graph={"peers": int(graph.n), "edges": int(graph.nnz)},
         )
-        t0 = self._warm_t0(id_order) if self.config.warm_start else None
+        t0 = self._warm_t0(id_order, epoch.number) if self.config.warm_start else None
         delta_rows = None
         if cached_plan is not None and dirty:
             pos = {h: i for i, h in enumerate(id_order)}
@@ -786,6 +791,24 @@ class Manager:
             result = backend.converge(
                 graph, alpha=alpha, tol=tol, max_iter=max_iter, t0=prepared.t0
             )
+        # Everything after the converge returned is the epoch's
+        # monitors and state publish, one span (no span wraps the whole
+        # device stage: the gaps between its spans stay visible).
+        with TRACER.span("monitors", epoch=prepared.epoch.number):
+            self._land(prepared, result, jit_snapshot, steady_state)
+        return result
+
+    def _land(
+        self,
+        prepared: PreparedEpoch,
+        result: ConvergenceResult,
+        jit_snapshot,
+        steady_state: bool,
+    ) -> None:
+        """Publish a converged epoch and run its monitors: the recompile
+        watch, the warm-start state, the result ring, convergence
+        health, score drift (its own ``drift`` span), lineage and the
+        timeline."""
         RECOMPILES.observe(
             jit_snapshot,
             steady_state=steady_state,
@@ -806,7 +829,7 @@ class Manager:
         # for the next _warm_t0.
         with self._state_lock:
             self._dirty_hashes -= prepared.dirty_snapshot
-            self.last_graph = graph
+            self.last_graph = prepared.graph
             self.last_scores = result.scores
             self.last_peer_hashes = prepared.id_order
             self.last_wal_seq = prepared.wal_seq
@@ -831,12 +854,13 @@ class Manager:
         # epoch (aligned by peer hash), top movers, and the stall
         # detector over the residual trajectory — the /scores/drift
         # surface.
-        DRIFT.observe(
-            prepared.epoch.number,
-            prepared.id_order,
-            result.scores,
-            result.residuals,
-        )
+        with TRACER.span("drift"):
+            DRIFT.observe(
+                prepared.epoch.number,
+                prepared.id_order,
+                result.scores,
+                result.residuals,
+            )
         # The epoch's lineage cohort has a converged (not yet proven)
         # fixed point; the timeline gets the converge fragment.
         LINEAGE.epoch_converged(prepared.epoch.number)
@@ -850,7 +874,6 @@ class Manager:
                 "delta_plan": prepared.delta_rows is not None,
             },
         )
-        return result
 
     def converge_epoch(
         self, epoch: Epoch, *, alpha: float = 0.0, tol: float = 1e-6, max_iter: int = 50

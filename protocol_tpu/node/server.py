@@ -36,7 +36,6 @@ from ..obs import (
 )
 from ..obs import metrics as obs_metrics
 from ..obs.export import PROMETHEUS_CONTENT_TYPE, profile_session
-from ..utils.telemetry import TELEMETRY
 from .config import ProtocolConfig
 from .epoch import Epoch
 from .errors import EigenError
@@ -287,7 +286,6 @@ def handle_request(
                 (e.number for e in manager.cached_proofs), default=None
             ),
             "backend": manager.config.backend,
-            "telemetry": TELEMETRY.snapshot(),
             "traced_epochs": TRACER.epochs(),
         }
         return 200, json.dumps(status)
@@ -635,9 +633,8 @@ class Node:
                     if self.config.profile_dir
                     else None
                 )
-                with TELEMETRY.timer("epoch.converge_open_graph"):
-                    with profile_session(profile_dir):
-                        result = self.manager.converge_epoch(epoch, alpha=0.1)
+                with profile_session(profile_dir):
+                    result = self.manager.converge_epoch(epoch, alpha=0.1)
                 scores = result.scores
                 log.info(
                     "epoch %s: open graph n=%d converged in %d iters (resid %.2e) on %s",
@@ -655,7 +652,6 @@ class Node:
                 # — on a small host the worker gets the inter-tick gap
                 # instead of time-slicing against converge.
                 self._prove_or_enqueue(epoch)
-        TELEMETRY.count("epochs")
         obs_metrics.EPOCHS_TOTAL.inc()
         # Continuous SLO evaluation: every landed tick advances the
         # burn windows (scrapes of GET /slo evaluate too).
@@ -675,7 +671,7 @@ class Node:
         thread and its attribution grafts back into this epoch's
         trace."""
         if self._prover_plane is None:
-            with TELEMETRY.timer("epoch.calculate_proofs"), TRACER.span("prove"):
+            with TRACER.span("prove"):
                 self.manager.calculate_proofs(epoch)
             return
         with TRACER.span("prove_enqueue"):
@@ -718,7 +714,7 @@ class Node:
             )
         except EigenError:
             proof_json = None
-        with TELEMETRY.timer("epoch.checkpoint"), TRACER.span("checkpoint"):
+        with TRACER.span("checkpoint"):
             store = CheckpointStore(self.config.checkpoint_dir)
             store.save(
                 epoch,
@@ -772,9 +768,8 @@ class Node:
                     if self.config.profile_dir
                     else None
                 )
-                with TELEMETRY.timer("epoch.converge_open_graph"):
-                    with profile_session(profile_dir):
-                        result = self.manager.converge_prepared(prepared, alpha=0.1)
+                with profile_session(profile_dir):
+                    result = self.manager.converge_prepared(prepared, alpha=0.1)
                 scores = result.scores
                 log.info(
                     "epoch %s: open graph n=%d converged in %d iters (resid %.2e) on %s%s",
@@ -790,7 +785,6 @@ class Node:
                 # Tick-end enqueue (see _epoch_tick): the prove gets
                 # the inter-tick gap, never this tick's core budget.
                 self._prove_or_enqueue(epoch)
-        TELEMETRY.count("epochs")
         obs_metrics.EPOCHS_TOTAL.inc()
         SLO_ENGINE.evaluate()
         if self._ingest is not None:

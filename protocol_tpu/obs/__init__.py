@@ -1,9 +1,8 @@
 """Node-wide observability: trace spans, metrics, exporters, the
 flight recorder, and runtime invariant watchers.
 
-The structured successor of the bare ``utils.telemetry`` timers: one
-coherent instrumentation layer threaded through ingest, convergence,
-proving, checkpointing, and serving.  Five pieces:
+The node's one instrumentation layer, threaded through ingest,
+convergence, proving, checkpointing, and serving.  Five pieces:
 
 - :mod:`~protocol_tpu.obs.trace` — hierarchical spans (context
   managers, monotonic timing, contextvar nesting) collected into a

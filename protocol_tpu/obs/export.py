@@ -5,7 +5,8 @@ format (text/plain; version=0.0.4) the node serves at ``GET /metrics``;
 ``metrics_json`` is the same state for tooling that prefers JSON.
 ``profile_session`` is the opt-in device-timeline capture around
 ``converge_epoch`` (``ProtocolConfig.profile_dir``): it wraps
-``jax.profiler.trace`` and degrades to a no-op when jax is absent, so
+``jax.profiler.trace``, mirrors the program's spans into it
+(``mirror_spans``), and degrades to a no-op when jax is absent, so
 importing this module never touches the device runtime.
 """
 
@@ -13,9 +14,14 @@ from __future__ import annotations
 
 import contextlib
 import math
+import threading
 from typing import Any, Iterator
 
 from .metrics import METRICS, Histogram, Metric, MetricsRegistry
+from .trace import TRACER, Tracer
+
+#: Name prefix of the profiler annotation that mirrors a span.
+SPAN_ANNOTATION_PREFIX = "program:"
 
 
 def _escape_label_value(value: str) -> str:
@@ -114,11 +120,50 @@ def metrics_json(registry: MetricsRegistry | None = None) -> dict[str, Any]:
 
 
 @contextlib.contextmanager
+def mirror_spans(tracer: Tracer = TRACER) -> Iterator[None]:
+    """While open, mirror every span of ``tracer`` into the running
+    ``jax.profiler`` trace as a ``program:<name>`` annotation, so the
+    device timeline names each idle gap by what the program was doing.
+    The hooks are chained to the ones already installed and restored
+    on exit; a span still open at exit is not mirrored."""
+    import jax
+
+    prev_open, prev_close = tracer.on_span_open, tracer.on_span_close
+    lock = threading.Lock()
+    annotations: dict[int, Any] = {}
+
+    def on_open(span) -> None:
+        if prev_open is not None:
+            prev_open(span)
+        ann = jax.profiler.TraceAnnotation(SPAN_ANNOTATION_PREFIX + span.name)
+        ann.__enter__()
+        with lock:
+            annotations[span.span_id] = ann
+
+    def on_close(span) -> None:
+        with lock:
+            ann = annotations.pop(span.span_id, None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if prev_close is not None:
+            prev_close(span)
+
+    tracer.on_span_open, tracer.on_span_close = on_open, on_close
+    try:
+        yield
+    finally:
+        tracer.on_span_open, tracer.on_span_close = prev_open, prev_close
+        with lock:
+            annotations.clear()
+
+
+@contextlib.contextmanager
 def profile_session(log_dir: str | None) -> Iterator[None]:
     """Opt-in ``jax.profiler`` capture: a real device-timeline trace
     (view with tensorboard/xprof) around the wrapped region when
-    ``log_dir`` is set; a no-op context when it is None or jax is
-    missing.  The node wraps ``converge_epoch`` with this when
+    ``log_dir`` is set, with the program's spans mirrored into it as
+    ``program:<name>`` annotations; a no-op context when it is None or
+    jax is missing.  The node wraps ``converge_epoch`` with this when
     ``ProtocolConfig.profile_dir`` is configured."""
     if not log_dir:
         yield
@@ -128,13 +173,15 @@ def profile_session(log_dir: str | None) -> Iterator[None]:
     except ImportError:  # pragma: no cover - jax ships in every image
         yield
         return
-    with jax.profiler.trace(log_dir):
+    with jax.profiler.trace(log_dir), mirror_spans():
         yield
 
 
 __all__ = [
     "PROMETHEUS_CONTENT_TYPE",
+    "SPAN_ANNOTATION_PREFIX",
     "metrics_json",
+    "mirror_spans",
     "profile_session",
     "prometheus_text",
 ]

@@ -44,9 +44,11 @@ projection in §7.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 from dataclasses import dataclass
 from functools import partial
+from typing import Callable, ContextManager
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +84,18 @@ BLOCK_ROWS = 64
 
 #: log2(WINDOW): window ids and window-local indices are shifts/masks.
 _WIN_BITS = 10
+
+#: A plan sub-phase hook: ``phase(name, **counts)`` returns a context
+#: manager around one host sub-phase of plan construction; ``counts``
+#: are sizes of the plan's work, for the caller's record.  Kernel trees
+#: hold no clock (graftlint ``clock-in-kernel-tree``), so the caller
+#: (``trust/backend.py``) injects the timing one.
+PlanPhase = Callable[..., ContextManager]
+
+
+def _no_phase(name: str, **counts: int) -> ContextManager:
+    """The default ``PlanPhase``: records nothing."""
+    return contextlib.nullcontext()
 
 
 def _counting_sort(
@@ -640,6 +654,7 @@ class WindowPlan:
         *,
         n: int | None = None,
         fingerprint: str,
+        phase: PlanPhase = _no_phase,
     ) -> "WindowPlan":
         """Incrementally fold an edge delta into the layout, returning a
         NEW plan (arrays are copied where touched, shared elsewhere —
@@ -692,115 +707,118 @@ class WindowPlan:
                 ]
             )
 
-        touched = np.unique(np.concatenate([ins_src, del_src]) >> _WIN_BITS)
-        wid = self.wid.copy()
-        local = self.local.reshape(-1).copy()
-        weight = self.weight.reshape(-1).copy()
-        n_data_rows = self.n_data_rows
+        with phase("plan_repack", delta_edges=int(ins_src.size)):
+            touched = np.unique(np.concatenate([ins_src, del_src]) >> _WIN_BITS)
+            wid = self.wid.copy()
+            local = self.local.reshape(-1).copy()
+            weight = self.weight.reshape(-1).copy()
+            n_data_rows = self.n_data_rows
 
-        # Segments whose rows stay untouched survive verbatim; the
-        # touched windows' runs are rebuilt below.  Only live runs
-        # participate — the inert device pads are regenerated at exit.
-        row_window = wid.astype(np.int64).copy()
-        row_window[self.n_data_rows :] = -1
-        end_live = self.seg_end.astype(np.int64)[: self.n_segments]
-        first_live = self.seg_first[: self.n_segments]
-        seg_win = row_window[end_live // ROW]
-        keep = ~np.isin(seg_win, touched)
-        new_end: list[np.ndarray] = [end_live[keep]]
-        new_first: list[np.ndarray] = [first_live[keep]]
-        new_dst: list[np.ndarray] = [self.seg_dst.astype(np.int64)[keep]]
+            # Segments whose rows stay untouched survive verbatim; the
+            # touched windows' runs are rebuilt below.  Only live runs
+            # participate — the inert device pads are regenerated at exit.
+            row_window = wid.astype(np.int64).copy()
+            row_window[self.n_data_rows :] = -1
+            end_live = self.seg_end.astype(np.int64)[: self.n_segments]
+            first_live = self.seg_first[: self.n_segments]
+            seg_win = row_window[end_live // ROW]
+            keep = ~np.isin(seg_win, touched)
+            new_end: list[np.ndarray] = [end_live[keep]]
+            new_first: list[np.ndarray] = [first_live[keep]]
+            new_dst: list[np.ndarray] = [self.seg_dst.astype(np.int64)[keep]]
 
-        iw = ins_src >> _WIN_BITS
-        dw = del_src >> _WIN_BITS
-        for w in touched.tolist():
-            rows_w = self._window_vreg_rows(int(w))
-            osrc, odst, ow = self._edges_of_segments(self._segments_of_rows(rows_w))
-            # Delete by (src, dst) identity; duplicate edges are a
-            # multiset — each delete consumes one instance.
-            dm = dw == w
-            if dm.any():
-                okey = osrc.astype(np.int64) << 32 | odst.astype(np.int64)
-                dkey = np.sort(del_src[dm] << 32 | del_dst[dm])
-                order = np.argsort(okey, kind="stable")
-                sk = okey[order]
-                pos = np.searchsorted(sk, dkey, side="left")
-                # The i-th duplicate of a delete key consumes the i-th
-                # plan instance of that edge.
-                grp = np.concatenate([[True], dkey[1:] != dkey[:-1]])
-                first = np.nonzero(grp)[0][np.cumsum(grp) - 1]
-                take = pos + (np.arange(len(dkey)) - first)
-                if take.size and (
-                    int(take.max()) >= len(sk) or not (sk[take] == dkey).all()
-                ):
-                    raise PlanDeltaError("delete names an edge absent from the plan")
-                drop = np.zeros(len(okey), bool)
-                drop[order[take]] = True
-                osrc, odst, ow = osrc[~drop], odst[~drop], ow[~drop]
-            im = iw == w
-            if im.any():
-                osrc = np.concatenate([osrc, ins_src[im].astype(np.int32)])
-                odst = np.concatenate([odst, ins_dst[im].astype(np.int32)])
-                ow = np.concatenate([ow, ins_w[im]])
-            count = osrc.shape[0]
-            # Zero the window's slots, then repack dst-sorted from the
-            # first row — the run differencing needs gap-free packing.
-            if rows_w.size:
-                slots_w = (rows_w[:, None] * ROW + np.arange(ROW)[None, :]).reshape(-1)
-                local[slots_w] = 0
-                weight[slots_w] = 0.0
-            if count > rows_w.size * ROW:
-                extra = -(-(count - rows_w.size * ROW) // ROW)
-                if n_data_rows + extra > self.n_rows:
-                    raise PlanDeltaError(
-                        f"window {w} outgrew the spare-row headroom; rebuild"
-                    )
-                grown = np.arange(n_data_rows, n_data_rows + extra, dtype=np.int64)
-                wid[grown] = w
-                n_data_rows += extra
-                rows_w = np.concatenate([rows_w, grown])
-            if count == 0:
-                continue
-            order = np.argsort(odst, kind="stable")
-            d = odst[order].astype(np.int64)
-            slots = rows_w[np.arange(count) // ROW] * ROW + np.arange(count) % ROW
-            local[slots] = (osrc[order] & (WINDOW - 1)).astype(np.int32)
-            weight[slots] = ow[order]
-            lead = np.arange(count) % ROW == 0
-            brk = np.empty(count, bool)
-            brk[0] = True
-            brk[1:] = (d[1:] != d[:-1]) | lead[1:]
-            endm = np.empty(count, bool)
-            endm[-1] = True
-            endm[:-1] = brk[1:]
-            new_end.append(slots[endm])
-            new_first.append(lead[brk])
-            new_dst.append(d[brk])
+            iw = ins_src >> _WIN_BITS
+            dw = del_src >> _WIN_BITS
+            for w in touched.tolist():
+                rows_w = self._window_vreg_rows(int(w))
+                osrc, odst, ow = self._edges_of_segments(self._segments_of_rows(rows_w))
+                # Delete by (src, dst) identity; duplicate edges are a
+                # multiset — each delete consumes one instance.
+                dm = dw == w
+                if dm.any():
+                    okey = osrc.astype(np.int64) << 32 | odst.astype(np.int64)
+                    dkey = np.sort(del_src[dm] << 32 | del_dst[dm])
+                    order = np.argsort(okey, kind="stable")
+                    sk = okey[order]
+                    pos = np.searchsorted(sk, dkey, side="left")
+                    # The i-th duplicate of a delete key consumes the i-th
+                    # plan instance of that edge.
+                    grp = np.concatenate([[True], dkey[1:] != dkey[:-1]])
+                    first = np.nonzero(grp)[0][np.cumsum(grp) - 1]
+                    take = pos + (np.arange(len(dkey)) - first)
+                    if take.size and (
+                        int(take.max()) >= len(sk) or not (sk[take] == dkey).all()
+                    ):
+                        raise PlanDeltaError("delete names an edge absent from the plan")
+                    drop = np.zeros(len(okey), bool)
+                    drop[order[take]] = True
+                    osrc, odst, ow = osrc[~drop], odst[~drop], ow[~drop]
+                im = iw == w
+                if im.any():
+                    osrc = np.concatenate([osrc, ins_src[im].astype(np.int32)])
+                    odst = np.concatenate([odst, ins_dst[im].astype(np.int32)])
+                    ow = np.concatenate([ow, ins_w[im]])
+                count = osrc.shape[0]
+                # Zero the window's slots, then repack dst-sorted from the
+                # first row — the run differencing needs gap-free packing.
+                if rows_w.size:
+                    slots_w = (rows_w[:, None] * ROW + np.arange(ROW)[None, :]).reshape(-1)
+                    local[slots_w] = 0
+                    weight[slots_w] = 0.0
+                if count > rows_w.size * ROW:
+                    extra = -(-(count - rows_w.size * ROW) // ROW)
+                    if n_data_rows + extra > self.n_rows:
+                        raise PlanDeltaError(
+                            f"window {w} outgrew the spare-row headroom; rebuild"
+                        )
+                    grown = np.arange(n_data_rows, n_data_rows + extra, dtype=np.int64)
+                    wid[grown] = w
+                    n_data_rows += extra
+                    rows_w = np.concatenate([rows_w, grown])
+                if count == 0:
+                    continue
+                order = np.argsort(odst, kind="stable")
+                d = odst[order].astype(np.int64)
+                slots = rows_w[np.arange(count) // ROW] * ROW + np.arange(count) % ROW
+                local[slots] = (osrc[order] & (WINDOW - 1)).astype(np.int32)
+                weight[slots] = ow[order]
+                lead = np.arange(count) % ROW == 0
+                brk = np.empty(count, bool)
+                brk[0] = True
+                brk[1:] = (d[1:] != d[:-1]) | lead[1:]
+                endm = np.empty(count, bool)
+                endm[-1] = True
+                endm[:-1] = brk[1:]
+                new_end.append(slots[endm])
+                new_first.append(lead[brk])
+                new_dst.append(d[brk])
 
-        all_end = np.concatenate(new_end)
-        order = np.argsort(all_end, kind="stable")
-        live_end = all_end[order]
-        if live_end.size > 1 and not (np.diff(live_end) > 0).all():
-            raise AssertionError("delta produced overlapping runs (plan bug)")
-        live_first = np.concatenate(new_first)[order]
-        seg_dst = np.concatenate(new_dst)[order].astype(np.int32)
-        # Keep the device capacity (and so every array shape + the
-        # compiled kernel) whenever the new run count still fits; grow
-        # by whole quanta otherwise — one recompile, then stable again.
-        s_new = int(seg_dst.shape[0])
-        max_pad = (self.n_rows - n_data_rows) * ROW
-        capacity = self.seg_capacity
-        if s_new > capacity or capacity - s_new > max_pad:
-            capacity = _segment_capacity(s_new, max_pad)
-        seg_end, seg_first, seg_perm, dst_ptr = _pad_segment_tables(
-            live_end,
-            live_first,
-            seg_dst,
-            capacity=capacity,
-            n=n_new,
-            n_rows=self.n_rows,
-            n_data_rows=n_data_rows,
-        )
+        with phase("plan_splice"):
+            all_end = np.concatenate(new_end)
+            order = np.argsort(all_end, kind="stable")
+            live_end = all_end[order]
+            if live_end.size > 1 and not (np.diff(live_end) > 0).all():
+                raise AssertionError("delta produced overlapping runs (plan bug)")
+            live_first = np.concatenate(new_first)[order]
+            seg_dst = np.concatenate(new_dst)[order].astype(np.int32)
+            # Keep the device capacity (and so every array shape + the
+            # compiled kernel) whenever the new run count still fits; grow
+            # by whole quanta otherwise — one recompile, then stable again.
+            s_new = int(seg_dst.shape[0])
+            max_pad = (self.n_rows - n_data_rows) * ROW
+            capacity = self.seg_capacity
+            if s_new > capacity or capacity - s_new > max_pad:
+                capacity = _segment_capacity(s_new, max_pad)
+        with phase("plan_seg_tables"):
+            seg_end, seg_first, seg_perm, dst_ptr = _pad_segment_tables(
+                live_end,
+                live_first,
+                seg_dst,
+                capacity=capacity,
+                n=n_new,
+                n_rows=self.n_rows,
+                n_data_rows=n_data_rows,
+            )
         return WindowPlan(
             n=n_new,
             n_rows=self.n_rows,
@@ -830,6 +848,7 @@ class WindowPlan:
         *,
         n: int | None = None,
         fingerprint: str,
+        phase: PlanPhase = _no_phase,
     ) -> "WindowPlan":
         """Replace every out-edge of the given source peers with the
         supplied (normalized) edges — the natural delta unit, because
@@ -837,25 +856,30 @@ class WindowPlan:
         rewrite that peer's whole out-row.  Deletes are recovered from
         the plan itself, so callers need no copy of the previous edge
         list.  Raises :class:`PlanDeltaError` like ``apply_delta``."""
-        rows = np.unique(np.asarray(rows, np.int64))
-        new_src = np.asarray(new_src, np.int64)
-        if new_src.size and not np.isin(new_src, rows).all():
-            raise PlanDeltaError("replacement edge outside the replaced rows")
-        parts = [
-            self._edges_of_segments(
-                self._segments_of_rows(self._window_vreg_rows(int(w)))
-            )
-            for w in np.unique(rows >> _WIN_BITS).tolist()
-        ]
-        if parts:
-            osrc = np.concatenate([p[0] for p in parts])
-            odst = np.concatenate([p[1] for p in parts])
-            m = np.isin(osrc.astype(np.int64), rows)
-            deletes = (osrc[m], odst[m])
-        else:
-            deletes = None
+        with phase("plan_extract"):
+            rows = np.unique(np.asarray(rows, np.int64))
+            new_src = np.asarray(new_src, np.int64)
+            if new_src.size and not np.isin(new_src, rows).all():
+                raise PlanDeltaError("replacement edge outside the replaced rows")
+            parts = [
+                self._edges_of_segments(
+                    self._segments_of_rows(self._window_vreg_rows(int(w)))
+                )
+                for w in np.unique(rows >> _WIN_BITS).tolist()
+            ]
+            if parts:
+                osrc = np.concatenate([p[0] for p in parts])
+                odst = np.concatenate([p[1] for p in parts])
+                m = np.isin(osrc.astype(np.int64), rows)
+                deletes = (osrc[m], odst[m])
+            else:
+                deletes = None
         return self.apply_delta(
-            (new_src, new_dst, new_w), deletes, n=n, fingerprint=fingerprint
+            (new_src, new_dst, new_w),
+            deletes,
+            n=n,
+            fingerprint=fingerprint,
+            phase=phase,
         )
 
 
@@ -879,28 +903,32 @@ def build_window_plan(
     *,
     n: int,
     spare_rows: int | None = None,
+    phase: PlanPhase = _no_phase,
 ) -> WindowPlan:
     """One-time host construction of the fused-pipeline layout for a
     row-normalized, self-edge-free edge list.  ``spare_rows`` of
     zero-weight tail headroom (adaptive by default: one grid block or
     ~6% of the data rows) lets ``apply_delta`` absorb window growth —
     and segment-table fragmentation — across epochs without a rebuild
-    or a device-shape change."""
-    b = bucket_by_window(
-        src, w, table_size=n, dst=dst, n_dst=n, spare_rows=spare_rows
-    )
+    or a device-shape change.  ``phase`` wraps the two sub-phases,
+    ``plan_bucket`` and ``plan_seg_tables``."""
+    with phase("plan_bucket", edges=int(src.shape[0])):
+        b = bucket_by_window(
+            src, w, table_size=n, dst=dst, n_dst=n, spare_rows=spare_rows
+        )
     # Device segment tables at quantized capacity: the inert pads give
     # apply_delta shape-stability headroom (no recompile per epoch).
     max_pad = (b["n_rows"] - b["n_data_rows"]) * ROW
-    seg_end, seg_first, seg_perm, dst_ptr = _pad_segment_tables(
-        b["seg_end"],
-        b["seg_first"],
-        b["seg_dst"],
-        capacity=_segment_capacity(b["n_segments"], max_pad),
-        n=n,
-        n_rows=b["n_rows"],
-        n_data_rows=b["n_data_rows"],
-    )
+    with phase("plan_seg_tables"):
+        seg_end, seg_first, seg_perm, dst_ptr = _pad_segment_tables(
+            b["seg_end"],
+            b["seg_first"],
+            b["seg_dst"],
+            capacity=_segment_capacity(b["n_segments"], max_pad),
+            n=n,
+            n_rows=b["n_rows"],
+            n_data_rows=b["n_data_rows"],
+        )
     return WindowPlan(
         n=n,
         n_rows=b["n_rows"],
@@ -932,6 +960,7 @@ def try_plan_delta(
     n: int,
     rows: np.ndarray,
     fingerprint: str,
+    phase: PlanPhase = _no_phase,
 ) -> WindowPlan | None:
     """Fold per-epoch churn into a cached plan: replace the out-edges of
     the hinted ``rows`` (every source peer whose attestation changed
@@ -944,7 +973,10 @@ def try_plan_delta(
     repack, PERF.md §11), or when it fails the edge-count tripwire (a
     stale/incomplete ``rows`` hint would stamp the new fingerprint
     onto a layout that doesn't encode the new graph — in that case the
-    caller must rebuild).
+    caller must rebuild).  ``phase`` wraps the sub-phases:
+    ``plan_select`` here, then ``plan_extract`` (``replace_rows``),
+    ``plan_repack``, ``plan_splice`` and ``plan_seg_tables``
+    (``apply_delta``).
     """
     rows = np.unique(np.asarray(rows, np.int64))
     if rows.size == 0:
@@ -960,10 +992,12 @@ def try_plan_delta(
     touched_windows = int(np.unique(rows >> _WIN_BITS).size)
     if touched_windows > max(64, data_windows // 4):
         return None
-    mask = np.isin(src, rows.astype(src.dtype))
+    with phase("plan_select", rows=int(rows.size), touched_windows=touched_windows):
+        mask = np.isin(src, rows.astype(src.dtype))
+        new_src, new_dst, new_w = src[mask], dst[mask], w[mask]
     try:
         new_plan = plan.replace_rows(
-            rows, src[mask], dst[mask], w[mask], n=n, fingerprint=fingerprint
+            rows, new_src, new_dst, new_w, n=n, fingerprint=fingerprint, phase=phase
         )
     except PlanDeltaError:
         return None

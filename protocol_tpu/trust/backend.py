@@ -98,6 +98,51 @@ def _history(hist, iterations: int) -> np.ndarray:
     return np.asarray(hist, dtype=np.float64)[: int(iterations)]
 
 
+def _fetch(out, backend: str, record_residuals: bool, span) -> ConvergenceResult:
+    """The converge's one device-to-host fetch, inside its ``converge``
+    span (which therefore closes on the result, not on the dispatch),
+    stamped with the iteration count and the final residual."""
+    t, it, resid = out[:3]
+    result = ConvergenceResult(
+        scores=np.asarray(t, dtype=np.float64),
+        iterations=int(it),
+        residual=float(resid),
+        backend=backend,
+        residuals=_history(out[3], it) if record_residuals else None,
+    )
+    span.attrs.update(iterations=result.iterations, residual=result.residual)
+    return result
+
+
+def _normalized(graph: TrustGraph) -> tuple[TrustGraph, np.ndarray, np.ndarray]:
+    """``(self-edge-free graph, normalized weights, dangling mask)``,
+    each step under its own span inside the caller's ``graph_prep``."""
+    with TRACER.span("drop_self_edges"):
+        g = graph.drop_self_edges()
+    with TRACER.span("row_normalize"):
+        w, dangling = g.row_normalized()
+    return g, w, dangling
+
+
+def _by_dst(graph: TrustGraph, g: TrustGraph, w: np.ndarray) -> TrustGraph:
+    """The normalized graph sorted by destination (the sparse and CSR
+    layouts), under a ``sort_by_dst`` span."""
+    with TRACER.span("sort_by_dst"):
+        return TrustGraph(g.n, g.src, g.dst, w, graph.pre_trusted).sorted_by_dst()
+
+
+def _plan_phase(plan_span):
+    """The plan's sub-phase hook (``ops.gather_window.PlanPhase``): each
+    sub-phase is a ``TRACER`` span under ``plan``, and the counts it
+    reports (rows, touched windows, edges) land on the ``plan`` span."""
+
+    def phase(name: str, **counts: int):
+        plan_span.attrs.update(counts)
+        return TRACER.span(name)
+
+    return phase
+
+
 def _initial_vector(t0, p: np.ndarray) -> np.ndarray:
     """Resolve the iteration's starting vector: the caller's warm-start
     ``t0`` (the previous epoch's fixed point, remapped over
@@ -211,36 +256,47 @@ class DenseJaxBackend(TrustBackend):
 
     def converge(self, graph, *, alpha=0.0, tol=1e-6, max_iter=50,
                  record_residuals=True, t0=None):
-        g = graph.drop_self_edges()
-        dense = g.to_dense().astype(np.float32)
-        row_sums = dense.sum(axis=1)
-        p = graph.pre_trust_vector().astype(np.float32)
-        dangling = row_sums <= 0
-        norm = np.where(dangling[:, None], p[None, :], dense / np.where(dangling, 1.0, row_sums)[:, None])
-        m = (1.0 - alpha) * norm.T + alpha * np.outer(p, np.ones(g.n, np.float32))
-        t = jnp.asarray(_initial_vector(t0, p))
-        m = jnp.asarray(m.astype(np.float32))
-        it = 0
-        resid = np.inf
-        history: list[float] = []
-        # Fixed-size scan chunks with host-side residual checks between
-        # chunks: keeps the hot loop compiled while honoring tol.  The
-        # residual trajectory is therefore chunk-granular here (one
-        # entry per host check), unlike the while-loop backends.
-        chunk = 8 if tol > 0 else max_iter
-        while it < max_iter:
-            steps = min(chunk, max_iter - it)
-            t_new = converge_dense(m, t, steps)
-            t_new = t_new / jnp.sum(t_new)
-            resid = float(jnp.sum(jnp.abs(t_new - t)))
-            if record_residuals:
-                history.append(resid)
-            t = t_new
-            it += steps
-            if tol > 0 and resid < tol:
-                break
+        with TRACER.span("graph_prep", edges=graph.nnz):
+            with TRACER.span("drop_self_edges"):
+                g = graph.drop_self_edges()
+            with TRACER.span("row_normalize"):
+                dense = g.to_dense().astype(np.float32)
+                row_sums = dense.sum(axis=1)
+                p = graph.pre_trust_vector().astype(np.float32)
+                dangling = row_sums <= 0
+                norm = np.where(
+                    dangling[:, None],
+                    p[None, :],
+                    dense / np.where(dangling, 1.0, row_sums)[:, None],
+                )
+                m = (1.0 - alpha) * norm.T + alpha * np.outer(p, np.ones(g.n, np.float32))
+        with TRACER.span("converge", backend=self.name) as span:
+            t = jnp.asarray(_initial_vector(t0, p))
+            m = jnp.asarray(m.astype(np.float32))
+            it = 0
+            resid = np.inf
+            history: list[float] = []
+            # Fixed-size scan chunks with host-side residual checks
+            # between chunks: keeps the hot loop compiled while honoring
+            # tol.  The residual trajectory is therefore chunk-granular
+            # here (one entry per host check), unlike the while-loop
+            # backends.
+            chunk = 8 if tol > 0 else max_iter
+            while it < max_iter:
+                steps = min(chunk, max_iter - it)
+                t_new = converge_dense(m, t, steps)
+                t_new = t_new / jnp.sum(t_new)
+                resid = float(jnp.sum(jnp.abs(t_new - t)))
+                if record_residuals:
+                    history.append(resid)
+                t = t_new
+                it += steps
+                if tol > 0 and resid < tol:
+                    break
+            scores = np.asarray(t, dtype=np.float64)
+            span.attrs.update(iterations=it, residual=resid)
         return ConvergenceResult(
-            scores=np.asarray(t, dtype=np.float64),
+            scores=scores,
             iterations=it,
             residual=resid,
             backend=self.name,
@@ -253,11 +309,11 @@ class SparseJaxBackend(TrustBackend):
 
     def converge(self, graph, *, alpha=0.0, tol=1e-6, max_iter=50,
                  record_residuals=True, t0=None):
-        g = graph.drop_self_edges()
-        w, dangling = g.row_normalized()
-        g = TrustGraph(g.n, g.src, g.dst, w, graph.pre_trusted).sorted_by_dst()
-        p = graph.pre_trust_vector()
-        with TRACER.span("converge", backend=self.name):
+        with TRACER.span("graph_prep", edges=graph.nnz):
+            g, w, dangling = _normalized(graph)
+            g = _by_dst(graph, g, w)
+            p = graph.pre_trust_vector()
+        with TRACER.span("converge", backend=self.name) as span:
             out = converge_sparse(
                 jnp.asarray(g.src),
                 jnp.asarray(g.dst),
@@ -271,14 +327,7 @@ class SparseJaxBackend(TrustBackend):
                 max_iter=max_iter,
                 record_residuals=record_residuals,
             )
-        t, it, resid = out[:3]
-        return ConvergenceResult(
-            scores=np.asarray(t, dtype=np.float64),
-            iterations=int(it),
-            residual=float(resid),
-            backend=self.name,
-            residuals=_history(out[3], it) if record_residuals else None,
-        )
+            return _fetch(out, self.name, record_residuals, span)
 
 
 class CsrJaxBackend(TrustBackend):
@@ -289,14 +338,15 @@ class CsrJaxBackend(TrustBackend):
 
     def converge(self, graph, *, alpha=0.0, tol=1e-6, max_iter=50,
                  record_residuals=True, t0=None):
-        g = graph.drop_self_edges()
-        w, dangling = g.row_normalized()
-        g = TrustGraph(g.n, g.src, g.dst, w, graph.pre_trusted).sorted_by_dst()
-        p = graph.pre_trust_vector()
-        with TRACER.span("converge", backend=self.name):
+        with TRACER.span("graph_prep", edges=graph.nnz):
+            g, w, dangling = _normalized(graph)
+            g = _by_dst(graph, g, w)
+            row_ptr = g.row_ptr_by_dst()
+            p = graph.pre_trust_vector()
+        with TRACER.span("converge", backend=self.name) as span:
             out = converge_csr(
                 jnp.asarray(g.src),
-                jnp.asarray(g.row_ptr_by_dst()),
+                jnp.asarray(row_ptr),
                 jnp.asarray(g.weight),
                 jnp.asarray(_initial_vector(t0, p)),
                 jnp.asarray(p),
@@ -306,14 +356,7 @@ class CsrJaxBackend(TrustBackend):
                 max_iter=max_iter,
                 record_residuals=record_residuals,
             )
-        t, it, resid = out[:3]
-        return ConvergenceResult(
-            scores=np.asarray(t, dtype=np.float64),
-            iterations=int(it),
-            residual=float(resid),
-            backend=self.name,
-            residuals=_history(out[3], it) if record_residuals else None,
-        )
+            return _fetch(out, self.name, record_residuals, span)
 
 
 class WindowedJaxBackend(TrustBackend):
@@ -364,9 +407,16 @@ class WindowedJaxBackend(TrustBackend):
             JOURNAL.record("plan", outcome="reuse", backend=self.name)
             return plan
         if valid and rows is not None:
-            with TRACER.span("plan", backend=self.name, reason="delta"):
+            with TRACER.span("plan", backend=self.name, reason="delta") as span:
                 delta = try_plan_delta(
-                    plan, g.src, g.dst, w, n=g.n, rows=rows, fingerprint=fp
+                    plan,
+                    g.src,
+                    g.dst,
+                    w,
+                    n=g.n,
+                    rows=rows,
+                    fingerprint=fp,
+                    phase=_plan_phase(span),
                 )
             if delta is not None:
                 PLAN_OUTCOMES.inc(outcome="delta")
@@ -377,8 +427,8 @@ class WindowedJaxBackend(TrustBackend):
         reason = "cold" if plan is None else (
             "stale-layout" if not valid else "fingerprint-miss"
         )
-        with TRACER.span("plan", backend=self.name, reason=reason):
-            plan = build_window_plan(g.src, g.dst, w, n=g.n)
+        with TRACER.span("plan", backend=self.name, reason=reason) as span:
+            plan = build_window_plan(g.src, g.dst, w, n=g.n, phase=_plan_phase(span))
         PLAN_REBUILDS.inc()
         PLAN_OUTCOMES.inc(outcome="rebuild")
         JOURNAL.record("plan", outcome="rebuild", backend=self.name, reason=reason)
@@ -386,22 +436,33 @@ class WindowedJaxBackend(TrustBackend):
 
     def converge(self, graph, *, alpha=0.0, tol=1e-6, max_iter=50,
                  record_residuals=True, t0=None):
-        g = graph.drop_self_edges()
-        w, dangling = g.row_normalized()
-        fp = graph_fingerprint(g.n, g.src, g.dst, w)
+        with TRACER.span("graph_prep", edges=graph.nnz):
+            g, w, dangling = _normalized(graph)
+            with TRACER.span("fingerprint"):
+                fp = graph_fingerprint(g.n, g.src, g.dst, w)
+            p = graph.pre_trust_vector()
+            dangling = dangling.astype(np.float32)
         plan = self._resolve_plan(g, w, fp)
         self.plan = plan
         self.last_plan = plan
-        p = graph.pre_trust_vector()
         interpret = (
             self.interpret if self.interpret is not None else pallas_interpret()
         )
-        with TRACER.span("converge", backend=self.name):
+        # The operands land on the device before the converge span
+        # opens, so that span times the converge and its one fetch.
+        with TRACER.span("upload") as span:
+            args = jax.block_until_ready(
+                (
+                    *plan.device_args(),
+                    jnp.asarray(_initial_vector(t0, p)),
+                    jnp.asarray(p),
+                    jnp.asarray(dangling),
+                )
+            )
+            span.attrs["bytes"] = sum(int(a.nbytes) for a in args)
+        with TRACER.span("converge", backend=self.name) as span:
             out = converge_windowed(
-                *plan.device_args(),
-                jnp.asarray(_initial_vector(t0, p)),
-                jnp.asarray(p),
-                jnp.asarray(dangling.astype(np.float32)),
+                *args,
                 n_rows=plan.n_rows,
                 table_entries=plan.table_entries,
                 alpha=jax.device_put(np.float32(alpha)),
@@ -410,14 +471,7 @@ class WindowedJaxBackend(TrustBackend):
                 interpret=interpret,
                 record_residuals=record_residuals,
             )
-        t, it, resid = out[:3]
-        return ConvergenceResult(
-            scores=np.asarray(t, dtype=np.float64),
-            iterations=int(it),
-            residual=float(resid),
-            backend=self.name,
-            residuals=_history(out[3], it) if record_residuals else None,
-        )
+            return _fetch(out, self.name, record_residuals, span)
 
 
 class ShardedJaxBackend(TrustBackend):
@@ -478,23 +532,17 @@ class ShardedJaxBackend(TrustBackend):
             self.plan = self.last_plan = swp.plan
             problem = swp
         else:
-            problem = ShardedTrustProblem.build(graph, mesh)
+            with TRACER.span("graph_prep", edges=graph.nnz):
+                problem = ShardedTrustProblem.build(graph, mesh)
         start = (
             None if t0 is None else _initial_vector(t0, graph.pre_trust_vector())
         )
-        with TRACER.span("converge", backend=name):
+        with TRACER.span("converge", backend=name) as span:
             out = converge_sharded(
                 problem, alpha=alpha, tol=tol, max_iter=max_iter,
                 record_residuals=record_residuals, t0=start,
             )
-        t, it, resid = out[:3]
-        return ConvergenceResult(
-            scores=np.asarray(t, dtype=np.float64),
-            iterations=it,
-            residual=resid,
-            backend=name,
-            residuals=_history(out[3], it) if record_residuals else None,
-        )
+            return _fetch(out, name, record_residuals, span)
 
 
 _BACKENDS = {

@@ -574,6 +574,48 @@ class TestProfileSession:
             jnp.asarray(np.ones(8, np.float32)).sum().block_until_ready()
         assert any((tmp_path / "prof").rglob("*")), "no profiler output written"
 
+    def test_mirrors_program_spans_inside_the_session_only(self, monkeypatch, tmp_path):
+        """An operator's capture names host activity as the benchmark's
+        does: spans inside the session become ``program:<name>``
+        annotations, chained to (and then restoring) the hooks already
+        installed; spans outside it are not mirrored."""
+        import contextlib
+
+        from protocol_tpu.obs import profile_session
+
+        events: list[tuple[str, str]] = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                events.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                events.append(("exit", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        monkeypatch.setattr(jax.profiler, "trace", lambda d: contextlib.nullcontext())
+        hooks = (TRACER.on_span_open, TRACER.on_span_close)
+        closed: list[str] = []
+        monkeypatch.setattr(TRACER, "on_span_close", lambda sp: closed.append(sp.name))
+        with TRACER.span("before"):
+            pass
+        with profile_session(str(tmp_path / "prof")):
+            with TRACER.span("plan"):
+                with TRACER.span("plan_select"):
+                    pass
+        with TRACER.span("after"):
+            pass
+        assert events == [
+            ("enter", "program:plan"), ("enter", "program:plan_select"),
+            ("exit", "program:plan_select"), ("exit", "program:plan"),
+        ]
+        # the hook chain: the installed close hook saw every span
+        assert closed == ["before", "plan_select", "plan", "after"]
+        assert TRACER.on_span_open is hooks[0]
+
 
 # ---------------------------------------------------------------------------
 # Exposition hardening (ISSUE 6 satellite)
