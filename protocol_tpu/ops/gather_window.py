@@ -605,10 +605,14 @@ class WindowPlan:
     def _segments_of_rows(self, rows: np.ndarray) -> np.ndarray:
         """Indices into the bucket-order segment table of every run
         living in ``rows`` — seg_end is strictly increasing, so each
-        row's runs are one searchsorted slice."""
-        end = self.seg_end.astype(np.int64)
-        lo = np.searchsorted(end, rows * ROW, side="left")
-        hi = np.searchsorted(end, (rows + 1) * ROW - 1, side="right")
+        row's runs are one searchsorted slice.  The queries match the
+        stored int32 table: a mixed-dtype search would cast the whole
+        table per call."""
+        rows = np.asarray(rows, np.int64)
+        lo = np.searchsorted(self.seg_end, (rows * ROW).astype(np.int32), side="left")
+        hi = np.searchsorted(
+            self.seg_end, ((rows + 1) * ROW - 1).astype(np.int32), side="right"
+        )
         parts = [np.arange(a, b, dtype=np.int64) for a, b in zip(lo, hi) if b > a]
         if not parts:
             return np.empty(0, np.int64)
@@ -623,13 +627,11 @@ class WindowPlan:
         if idx.size == 0:
             z = np.empty(0, np.int32)
             return z, z, np.empty(0, np.float32)
-        end = self.seg_end.astype(np.int64)
-        start = np.where(
-            self.seg_first[idx],
-            (end[idx] // ROW) * ROW,
-            end[np.maximum(idx, 1) - 1] + 1,
-        )
-        lens = end[idx] - start + 1
+        # Widen only the gathered ends, never the table.
+        end = self.seg_end[idx].astype(np.int64)
+        prev = self.seg_end[np.maximum(idx, 1) - 1].astype(np.int64)
+        start = np.where(self.seg_first[idx], (end // ROW) * ROW, prev + 1)
+        lens = end - start + 1
         total = int(lens.sum())
         run_of = np.repeat(np.cumsum(lens) - lens, lens)
         slots = np.repeat(start, lens) + (np.arange(total, dtype=np.int64) - run_of)
@@ -664,12 +666,16 @@ class WindowPlan:
         weights), ``deletes`` is ``(src, dst)`` of edges to remove; ``n``
         grows the peer set (new peers join with no plan presence until
         an insert names them).  Host-side cost: O(Δ log Δ) sorting over
-        the delta plus a repack of the touched windows' slots, then two
-        streaming O(S) passes (segment-table splice + the dst counting
-        sort behind ``seg_perm``/``dst_ptr``) — far below the full
-        rebuild's O(E) counting sorts.  The result's ``fingerprint`` is
-        the caller-supplied identity of the post-delta graph and the
-        predecessor chain lands in ``lineage``.
+        the delta; per touched window, two binary searches per vreg-row
+        into the segment table and a gather and repack of that window's
+        runs and slots alone; then a fixed set of whole-table passes,
+        however many windows are touched — the copies of ``wid``,
+        ``local`` and ``weight`` (O(slots)), the kept-run compress
+        (O(S)), the splice (a stable argsort of the S run ends) and the
+        dst counting sort behind ``seg_perm``/``dst_ptr`` (O(S)) — far
+        below the full rebuild's O(E) counting sorts.  The result's
+        ``fingerprint`` is the caller-supplied identity of the
+        post-delta graph and the predecessor chain lands in ``lineage``.
 
         Raises :class:`PlanDeltaError` when the delta cannot be folded
         (peer set shrank, a deleted edge is absent, or a window outgrew
@@ -714,24 +720,24 @@ class WindowPlan:
             weight = self.weight.reshape(-1).copy()
             n_data_rows = self.n_data_rows
 
-            # Segments whose rows stay untouched survive verbatim; the
-            # touched windows' runs are rebuilt below.  Only live runs
-            # participate — the inert device pads are regenerated at exit.
-            row_window = wid.astype(np.int64).copy()
-            row_window[self.n_data_rows :] = -1
-            end_live = self.seg_end.astype(np.int64)[: self.n_segments]
-            first_live = self.seg_first[: self.n_segments]
-            seg_win = row_window[end_live // ROW]
-            keep = ~np.isin(seg_win, touched)
-            new_end: list[np.ndarray] = [end_live[keep]]
-            new_first: list[np.ndarray] = [first_live[keep]]
-            new_dst: list[np.ndarray] = [self.seg_dst.astype(np.int64)[keep]]
+            # The touched windows' rows and the runs living in them — the
+            # only part of the segment table the delta reads.  Runs in
+            # every other row survive verbatim; the touched ones are
+            # rebuilt below.  Only live runs participate — the inert
+            # device pads (above every data row) are regenerated at exit.
+            touched_rows = [self._window_vreg_rows(int(w)) for w in touched.tolist()]
+            touched_segs = [self._segments_of_rows(r) for r in touched_rows]
+            keep = np.ones(self.n_segments, bool)
+            for idx in touched_segs:
+                keep[idx] = False
+            new_end: list[np.ndarray] = [self.seg_end[: self.n_segments][keep]]
+            new_first: list[np.ndarray] = [self.seg_first[: self.n_segments][keep]]
+            new_dst: list[np.ndarray] = [self.seg_dst[keep]]
 
             iw = ins_src >> _WIN_BITS
             dw = del_src >> _WIN_BITS
-            for w in touched.tolist():
-                rows_w = self._window_vreg_rows(int(w))
-                osrc, odst, ow = self._edges_of_segments(self._segments_of_rows(rows_w))
+            for w, rows_w, idx in zip(touched.tolist(), touched_rows, touched_segs):
+                osrc, odst, ow = self._edges_of_segments(idx)
                 # Delete by (src, dst) identity; duplicate edges are a
                 # multiset — each delete consumes one instance.
                 dm = dw == w

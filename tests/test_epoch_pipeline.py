@@ -26,8 +26,10 @@ from protocol_tpu.node.epoch import Epoch
 from protocol_tpu.node.manager import Manager, ManagerConfig
 from protocol_tpu.node.pipeline import EpochPipeline
 from protocol_tpu.obs.metrics import PLAN_OUTCOMES
+from protocol_tpu.ops import gather_window as gw
 from protocol_tpu.ops.gather_window import (
     ROW,
+    WINDOW,
     PlanDeltaError,
     WindowPlan,
     build_window_plan,
@@ -324,6 +326,297 @@ class TestApplyDelta:
         assert snap.plan.n_data_rows == p2.n_data_rows
         for k in WindowPlan._CORE + WindowPlan._HOST:
             np.testing.assert_array_equal(getattr(snap.plan, k), getattr(p2, k))
+
+
+# -- Oracle: the delta as it addressed the segment table before it read
+# only the touched windows' run ranges (a full int64 cast of ``seg_end``
+# per helper call, kept runs chosen by window id over every run).
+
+
+def _old_segments_of_rows(plan, rows):
+    end = plan.seg_end.astype(np.int64)
+    lo = np.searchsorted(end, rows * ROW, side="left")
+    hi = np.searchsorted(end, (rows + 1) * ROW - 1, side="right")
+    parts = [np.arange(a, b, dtype=np.int64) for a, b in zip(lo, hi) if b > a]
+    if not parts:
+        return np.empty(0, np.int64)
+    return np.concatenate(parts)
+
+
+def _old_edges_of_segments(plan, idx):
+    if idx.size == 0:
+        z = np.empty(0, np.int32)
+        return z, z, np.empty(0, np.float32)
+    end = plan.seg_end.astype(np.int64)
+    start = np.where(
+        plan.seg_first[idx],
+        (end[idx] // ROW) * ROW,
+        end[np.maximum(idx, 1) - 1] + 1,
+    )
+    lens = end[idx] - start + 1
+    total = int(lens.sum())
+    run_of = np.repeat(np.cumsum(lens) - lens, lens)
+    slots = np.repeat(start, lens) + (np.arange(total, dtype=np.int64) - run_of)
+    dst = np.repeat(plan.seg_dst[idx], lens)
+    rows = slots // ROW
+    src = (
+        plan.wid[rows].astype(np.int64) * WINDOW + plan.local.reshape(-1)[slots]
+    ).astype(np.int32)
+    return src, dst, plan.weight.reshape(-1)[slots]
+
+
+def _old_apply_delta(plan, inserts, deletes, *, n=None, fingerprint, phase=None):
+    empty_i = (np.empty(0, np.int32),) * 2 + (np.empty(0, np.float32),)
+    ins_src, ins_dst, ins_w = (
+        tuple(np.asarray(a) for a in inserts) if inserts is not None else empty_i
+    )
+    del_src, del_dst = (
+        tuple(np.asarray(a, np.int64) for a in deletes)
+        if deletes is not None
+        else (np.empty(0, np.int64),) * 2
+    )
+    ins_src = np.asarray(ins_src, np.int64)
+    ins_dst = np.asarray(ins_dst, np.int64)
+    ins_w = np.asarray(ins_w, np.float32)
+    n_new = plan.n if n is None else int(n)
+    table_entries = -(-n_new // WINDOW) * WINDOW
+    n_windows = table_entries // WINDOW
+    row_offset = plan.row_offset
+    if n_windows + 1 > len(row_offset):
+        row_offset = np.concatenate(
+            [
+                row_offset,
+                np.full(n_windows + 1 - len(row_offset), row_offset[-1], np.int64),
+            ]
+        )
+    touched = np.unique(np.concatenate([ins_src, del_src]) >> gw._WIN_BITS)
+    wid = plan.wid.copy()
+    local = plan.local.reshape(-1).copy()
+    weight = plan.weight.reshape(-1).copy()
+    n_data_rows = plan.n_data_rows
+    row_window = wid.astype(np.int64).copy()
+    row_window[plan.n_data_rows :] = -1
+    end_live = plan.seg_end.astype(np.int64)[: plan.n_segments]
+    first_live = plan.seg_first[: plan.n_segments]
+    keep = ~np.isin(row_window[end_live // ROW], touched)
+    new_end = [end_live[keep]]
+    new_first = [first_live[keep]]
+    new_dst = [plan.seg_dst.astype(np.int64)[keep]]
+    iw = ins_src >> gw._WIN_BITS
+    dw = del_src >> gw._WIN_BITS
+    for w in touched.tolist():
+        rows_w = plan._window_vreg_rows(int(w))
+        osrc, odst, ow = _old_edges_of_segments(plan, _old_segments_of_rows(plan, rows_w))
+        dm = dw == w
+        if dm.any():
+            okey = osrc.astype(np.int64) << 32 | odst.astype(np.int64)
+            dkey = np.sort(del_src[dm] << 32 | del_dst[dm])
+            order = np.argsort(okey, kind="stable")
+            sk = okey[order]
+            pos = np.searchsorted(sk, dkey, side="left")
+            grp = np.concatenate([[True], dkey[1:] != dkey[:-1]])
+            first = np.nonzero(grp)[0][np.cumsum(grp) - 1]
+            take = pos + (np.arange(len(dkey)) - first)
+            assert (sk[take] == dkey).all()
+            drop = np.zeros(len(okey), bool)
+            drop[order[take]] = True
+            osrc, odst, ow = osrc[~drop], odst[~drop], ow[~drop]
+        im = iw == w
+        if im.any():
+            osrc = np.concatenate([osrc, ins_src[im].astype(np.int32)])
+            odst = np.concatenate([odst, ins_dst[im].astype(np.int32)])
+            ow = np.concatenate([ow, ins_w[im]])
+        count = osrc.shape[0]
+        if rows_w.size:
+            slots_w = (rows_w[:, None] * ROW + np.arange(ROW)[None, :]).reshape(-1)
+            local[slots_w] = 0
+            weight[slots_w] = 0.0
+        if count > rows_w.size * ROW:
+            extra = -(-(count - rows_w.size * ROW) // ROW)
+            grown = np.arange(n_data_rows, n_data_rows + extra, dtype=np.int64)
+            wid[grown] = w
+            n_data_rows += extra
+            rows_w = np.concatenate([rows_w, grown])
+        if count == 0:
+            continue
+        order = np.argsort(odst, kind="stable")
+        d = odst[order].astype(np.int64)
+        slots = rows_w[np.arange(count) // ROW] * ROW + np.arange(count) % ROW
+        local[slots] = (osrc[order] & (WINDOW - 1)).astype(np.int32)
+        weight[slots] = ow[order]
+        lead = np.arange(count) % ROW == 0
+        brk = np.empty(count, bool)
+        brk[0] = True
+        brk[1:] = (d[1:] != d[:-1]) | lead[1:]
+        endm = np.empty(count, bool)
+        endm[-1] = True
+        endm[:-1] = brk[1:]
+        new_end.append(slots[endm])
+        new_first.append(lead[brk])
+        new_dst.append(d[brk])
+    all_end = np.concatenate(new_end)
+    order = np.argsort(all_end, kind="stable")
+    live_end = all_end[order]
+    live_first = np.concatenate(new_first)[order]
+    seg_dst = np.concatenate(new_dst)[order].astype(np.int32)
+    s_new = int(seg_dst.shape[0])
+    max_pad = (plan.n_rows - n_data_rows) * ROW
+    capacity = plan.seg_capacity
+    if s_new > capacity or capacity - s_new > max_pad:
+        capacity = gw._segment_capacity(s_new, max_pad)
+    seg_end, seg_first, seg_perm, dst_ptr = gw._pad_segment_tables(
+        live_end,
+        live_first,
+        seg_dst,
+        capacity=capacity,
+        n=n_new,
+        n_rows=plan.n_rows,
+        n_data_rows=n_data_rows,
+    )
+    return WindowPlan(
+        n=n_new,
+        n_rows=plan.n_rows,
+        table_entries=table_entries,
+        n_segments=s_new,
+        n_data_rows=n_data_rows,
+        n_edges=plan.n_edges - int(del_src.size) + int(ins_src.size),
+        wid=wid,
+        local=local.reshape(plan.local.shape),
+        weight=weight.reshape(plan.weight.shape),
+        seg_end=seg_end.astype(np.int32),
+        seg_first=seg_first,
+        seg_perm=seg_perm.astype(np.int32, copy=False),
+        dst_ptr=dst_ptr.astype(np.int32),
+        seg_dst=seg_dst,
+        row_offset=row_offset,
+        fingerprint=fingerprint,
+        lineage=(plan.lineage + (plan.fingerprint,))[-gw.LINEAGE_DEPTH :],
+    )
+
+
+def _delta_chain(plan, rng):
+    """Deltas that grow window 0 into overflow rows, grow ``n`` by new
+    windows, delete every edge of window 1, then refill it by whole-row
+    replacement; yields each step's name and plan."""
+    n = plan.n
+    src0 = rng.integers(0, WINDOW, 3 * ROW).astype(np.int32)
+    dst0 = rng.integers(0, n, src0.size).astype(np.int32)
+    ins = (src0, dst0, rng.random(src0.size).astype(np.float32))
+    plan = plan.apply_delta(ins, None, fingerprint="grow-window")
+    yield "grow-window", plan
+    n2 = n + 2 * WINDOW
+    src1 = rng.integers(n, n2, 500).astype(np.int32)
+    dst1 = rng.integers(0, n2, 500).astype(np.int32)
+    ins = (src1, dst1, rng.random(500).astype(np.float32))
+    plan = plan.apply_delta(ins, None, n=n2, fingerprint="grow-n")
+    yield "grow-n", plan
+    src, dst, _ = plan._edges_of_segments(
+        plan._segments_of_rows(plan._window_vreg_rows(1))
+    )
+    plan = plan.apply_delta(None, (src, dst), fingerprint="clear-window")
+    yield "clear-window", plan
+    rows = np.arange(WINDOW, WINDOW + 40)
+    ns = np.repeat(rows, 7).astype(np.int32)
+    nd = rng.integers(0, n2, ns.size).astype(np.int32)
+    nw = rng.random(ns.size).astype(np.float32)
+    plan = plan.replace_rows(rows, ns, nd, nw, fingerprint="refill")
+    yield "refill", plan
+
+
+def _assert_same_plan(a, b):
+    for k in WindowPlan._META:
+        assert getattr(a, k) == getattr(b, k), k
+    for k in WindowPlan._CORE + WindowPlan._HOST:
+        x, y = getattr(a, k), getattr(b, k)
+        assert x.dtype == y.dtype, k
+        np.testing.assert_array_equal(x, y, err_msg=k)
+    assert a.lineage == b.lineage
+
+
+class TestDeltaSegmentAddressing:
+    """The delta reads the segment table only through the touched
+    windows' run ranges; these pin it to the whole-table formulation."""
+
+    @pytest.mark.parametrize("graph", ["scale_free", "erdos_renyi"])
+    def test_helpers_match_full_table_formulation(self, graph):
+        g = (
+            scale_free(3500, 21000, seed=17)
+            if graph == "scale_free"
+            else erdos_renyi(3500, avg_degree=6.0, seed=17)
+        ).drop_self_edges()
+        w, _ = g.row_normalized()
+        plan = build_window_plan(g.src, g.dst, w, n=g.n)
+        rng = np.random.default_rng(18)
+        runs_of_window1 = {}
+        for step, p in _delta_chain(plan, rng):
+            for win in range(p.table_entries // WINDOW):
+                rows = p._window_vreg_rows(win)
+                idx = p._segments_of_rows(rows)
+                old_idx = _old_segments_of_rows(p, rows)
+                assert idx.dtype == old_idx.dtype, (step, win)
+                np.testing.assert_array_equal(idx, old_idx, err_msg=f"{step} {win}")
+                for a, b in zip(p._edges_of_segments(idx), _old_edges_of_segments(p, idx)):
+                    assert a.dtype == b.dtype, (step, win)
+                    np.testing.assert_array_equal(a, b, err_msg=f"{step} {win}")
+                if win == 1:
+                    runs_of_window1[step] = idx.size
+            if step == "grow-window":
+                # Window 0 spilled into overflow rows past the original packing.
+                assert p.n_data_rows > plan.n_data_rows
+                assert (p._window_vreg_rows(0) >= p.row_offset[-1]).any()
+        assert runs_of_window1["clear-window"] == 0 < runs_of_window1["refill"]
+        assert p.n == g.n + 2 * WINDOW
+
+    @pytest.mark.parametrize("delta", ["replace_rows_chain", "explicit_deletes"])
+    def test_delta_bit_identical_to_full_table_formulation(self, delta, monkeypatch):
+        g = scale_free(3500, 21000, seed=19).drop_self_edges()
+        w, _ = g.row_normalized()
+        plan = build_window_plan(g.src, g.dst, w, n=g.n)
+        rng = np.random.default_rng(20)
+        if delta == "replace_rows_chain":
+            steps = []
+            for i in range(5):
+                rows = rng.choice(g.n, 12, replace=False)
+                # One heavy row per epoch, so windows spill into overflow.
+                deg = np.r_[rng.integers(1, 6, 11), 700 + 200 * i]
+                ns = np.repeat(rows, deg).astype(np.int32)
+                nd = rng.integers(0, g.n, ns.size).astype(np.int32)
+                nw = rng.random(ns.size).astype(np.float32)
+                steps.append(
+                    lambda p, a=(rows, ns, nd, nw), i=i: p.replace_rows(
+                        *a, fingerprint=f"fp{i}"
+                    )
+                )
+        else:
+            src, dst, _ = plan.recovered_edges()
+            # Distinct plan instances, so a pair held twice may be named
+            # twice (multiset delete).
+            pick = rng.choice(src.size, 60, replace=False)
+            dels = (src[pick], dst[pick])
+            ins = (
+                rng.integers(0, g.n, 40).astype(np.int32),
+                rng.integers(0, g.n, 40).astype(np.int32),
+                rng.random(40).astype(np.float32),
+            )
+            steps = [lambda p: p.apply_delta(ins, dels, fingerprint="explicit")]
+
+        def chain():
+            p, out = plan, []
+            for step in steps:
+                p = step(p)
+                out.append(p)
+            return out
+
+        with monkeypatch.context() as mp:
+            mp.setattr(WindowPlan, "_segments_of_rows", _old_segments_of_rows)
+            mp.setattr(WindowPlan, "_edges_of_segments", _old_edges_of_segments)
+            mp.setattr(WindowPlan, "apply_delta", _old_apply_delta)
+            oracle = chain()
+        changed = chain()
+        if delta == "replace_rows_chain":
+            assert oracle[-1].n_data_rows > plan.n_data_rows  # overflow rows in play
+        for a, b in zip(changed, oracle):
+            _assert_same_plan(a, b)
 
 
 class TestManagerWarmState:
