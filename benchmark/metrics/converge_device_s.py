@@ -1,6 +1,7 @@
 """converge_device_s: device seconds per window epoch of the converge
-executable (the trace's modules whose name holds ``converge``), not the
-program's ``converge`` span, which closes before the device is done."""
+executable, read from the device trace (the modules whose name holds
+``converge``); the host's share of the program's ``converge`` span is
+``converge_host_s``."""
 
 
 def read(run):

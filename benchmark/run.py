@@ -31,7 +31,6 @@ are compared with it.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import os
 import sys
@@ -238,12 +237,9 @@ def _emit(tag: str, payload: dict) -> None:
 def read_metric(name: str, run: dict):
     """The per-layer metric ``name``, from ``benchmark/metrics/<name>.py``:
     a number, or None where the run gave it nothing to read."""
-    path = BENCH / "metrics" / f"{name}.py"
-    module_name = "benchmark_metric_" + name.replace(".", "_").replace("-", "_")
-    spec = importlib.util.spec_from_file_location(module_name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read(run)
+    from benchmark.modules import load_module
+
+    return load_module(BENCH / "metrics" / f"{name}.py", "benchmark_metric_").read(run)
 
 
 def check(epochs: list[Epoch], setup: list[Epoch], trust: dict, limits: dict) -> dict:

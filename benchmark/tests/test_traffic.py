@@ -1,10 +1,19 @@
 """The generator: the same seed gives the same graphs, another seed
-others; each mix rewrites what its file says."""
+others; each mix rewrites what its file says; a graph kind is a file
+found by name."""
+
+import hashlib
 
 import numpy as np
+import pytest
 
 from benchmark.tests.helpers import tiny_spec
 from benchmark.traffic.generate import Churn, base_graph
+
+#: ``c4-trickle``'s base graph and three churn steps at 4096 peers /
+#: 40,000 attestations, seed 3, as the generator drew them before graph
+#: kinds moved into files of their own: what the cell reads must not move.
+TRICKLE_DIGEST = "3591d290d6feadbb2da306c93b22092342ef0a2c1cf30fa7a93ad185b13cfd12"
 
 
 def _epochs(spec, seed, k=3):
@@ -48,3 +57,34 @@ def test_graph_fixed_by_config_run_seed_draws_churn():
     a, b = base_graph(spec.config, 1), base_graph(spec.config, 2**31 + 7)
     for f in ("src", "dst", "weight", "pre_trusted"):
         np.testing.assert_array_equal(getattr(a, f), getattr(b, f))
+
+
+def test_trickle_graphs_are_pinned():
+    h = hashlib.sha256()
+    for g in _epochs(tiny_spec("c4-trickle", 4096, 40_000), 3):
+        for f in ("src", "dst", "weight", "pre_trusted"):
+            h.update(getattr(g, f).tobytes())
+    assert h.hexdigest() == TRICKLE_DIGEST
+
+
+def test_graph_kind_is_a_file_found_by_name(tmp_path):
+    (tmp_path / "ring.py").write_text(
+        "import numpy as np\n"
+        "from benchmark.traffic.generate import Graph\n"
+        "def draw(n, nnz, *, seed, weight=1.0):\n"
+        "    src = np.arange(nnz, dtype=np.int32) % n\n"
+        "    return Graph(n, src, (src + 1) % n, np.full(nnz, weight, np.float32),\n"
+        "                 np.arange(n) == seed % n)\n"
+    )
+    config = {"graph": {"kind": "ring", "peers": 5, "attestations": 7, "weight": 2.0}}
+    g = base_graph(config, 12, kinds=tmp_path)
+    assert g.n == 5 and g.nnz == 7 and (g.weight == 2.0).all()
+    np.testing.assert_array_equal(g.dst[:5], [1, 2, 3, 4, 0])
+    assert g.pre_trusted.tolist() == [False, False, True, False, False]
+
+
+def test_unknown_graph_kind_names_the_known():
+    spec = tiny_spec("c4-trickle", 4096, 40_000)
+    spec.config["graph"]["kind"] = "kronecker"
+    with pytest.raises(SystemExit, match="'kronecker'.*scale_free"):
+        base_graph(spec.config, 1)

@@ -1,9 +1,11 @@
 """The benchmark's one traffic generator: a deployment's base graph from
 the seed, then one epoch of sender re-attestations after another.
 
-Copied from the program (``protocol_tpu/models/graphs.py::scale_free``,
-``protocol_tpu/models/churn.py``) so that no later change to the
-program can move the yardstick.  Every parameter comes from a
+The base graph is drawn by the module of the configuration's
+``graph.kind``, ``benchmark/graphs/<kind>.py``, found by name: a new
+deployment's shape is a new file there.  The churn is copied from the
+program (``protocol_tpu/models/churn.py``) so that no later change to
+the program can move the yardstick.  Every parameter comes from a
 configuration file (``benchmark/configs``) or a traffic file
 (``benchmark/traffic/*.json``); a new mix is a new data file.
 
@@ -26,8 +28,11 @@ import queue
 import threading
 import time
 from dataclasses import dataclass
+from pathlib import Path
 
 import numpy as np
+
+from benchmark.modules import load_module
 
 
 @dataclass
@@ -46,53 +51,25 @@ class Graph:
         return int(self.src.shape[0])
 
 
-def scale_free(
-    n: int,
-    nnz: int,
-    *,
-    exponent: float = 1.1,
-    n_pre_trusted: int = 64,
-    weight_low: int = 1,
-    weight_high: int = 101,
-    seed: int = 0,
-    chunk: int = 1 << 22,
-) -> Graph:
-    """Sources uniform, destinations Zipf-like (popularity ∝
-    rank^-exponent by inverse-CDF sampling on a permuted rank order)."""
-    rng = np.random.default_rng(seed)
-    perm = rng.permutation(n).astype(np.int32)
-    srcs, dsts, ws = [], [], []
-    remaining = nnz
-    while remaining > 0:
-        m = min(chunk, remaining)
-        remaining -= m
-        src = rng.integers(0, n, m, dtype=np.int32)
-        u = rng.random(m)
-        if abs(exponent - 1.0) < 1e-9:
-            ranks = np.exp(u * np.log(n))
-        else:
-            a = 1.0 - exponent
-            ranks = (1.0 + u * (n**a - 1.0)) ** (1.0 / a)
-        dst = perm[np.clip(ranks.astype(np.int64) - 1, 0, n - 1)]
-        w = rng.integers(weight_low, weight_high, m).astype(np.float32)
-        srcs.append(src)
-        dsts.append(dst)
-        ws.append(w)
-    pre = np.zeros(n, bool)
-    pre[rng.choice(n, min(n_pre_trusted, n), replace=False)] = True
-    return Graph(n, np.concatenate(srcs), np.concatenate(dsts), np.concatenate(ws), pre)
+#: One module per graph kind, ``<kind>.py``, each with ``draw(n, nnz, *,
+#: seed, **params) -> Graph``.
+GRAPH_KINDS = Path(__file__).resolve().parents[1] / "graphs"
 
 
-GRAPHS = {"scale_free": scale_free}
-
-
-def base_graph(config: dict, seed: int) -> Graph:
-    """The configuration's deployment graph, drawn from ``seed``, or
-    from the configuration's own ``seed`` where it fixes the graph."""
+def base_graph(config: dict, seed: int, kinds: Path = GRAPH_KINDS) -> Graph:
+    """The configuration's deployment graph, drawn by the module of its
+    ``graph.kind`` in ``kinds`` from ``seed``, or from the
+    configuration's own ``seed`` where it fixes the graph; the other
+    keys of ``graph`` are the module's parameters."""
     spec = dict(config["graph"])
     kind = spec.pop("kind")
+    path = kinds / f"{kind}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in kinds.glob("*.py"))
+        raise SystemExit(f"unknown graph kind {kind!r}; known: {known}")
+    module = load_module(path, "benchmark_graph_")
     n, nnz = spec.pop("peers"), spec.pop("attestations")
-    return GRAPHS[kind](n, nnz, seed=spec.pop("seed", seed), **spec)
+    return module.draw(n, nnz, seed=spec.pop("seed", seed), **spec)
 
 
 class Churn:
