@@ -138,12 +138,13 @@ def headline_entry(
             )
         phases["plan"] = round(plan_span.duration_s or 0.0, 4)
         interpret = pallas_interpret()
-        device_args = tuple(jax.device_put(a) for a in plan.device_args()) + (
+        plan_args = tuple(jax.device_put(a) for a in plan.device_args())
+        vec_args = (
             jax.device_put(jnp.asarray(p)),
             jax.device_put(jnp.asarray(dangling.astype(np.float32))),
         )
         alpha = jax.device_put(np.float32(0.1))
-        jax.block_until_ready(device_args)
+        jax.block_until_ready((plan_args, vec_args))
         extra = {
             "plan_seconds": round(plan_dt, 4),
             "bridge_segments": plan.n_segments,
@@ -153,11 +154,10 @@ def headline_entry(
         # module this bench executes, outside the timed region.
         extra["peak_memory_bytes"] = _peak_memory_bytes(
             converge_windowed.lower(
-                *device_args[:7],
+                *plan_args,
                 jax.device_put(jnp.asarray(p)),
-                *device_args[7:],
-                n_rows=plan.n_rows,
-                table_entries=plan.table_entries,
+                *vec_args,
+                **plan.static_kwargs(),
                 alpha=alpha,
                 tol=0.0,
                 max_iter=iters,
@@ -169,11 +169,10 @@ def headline_entry(
             # t0 is donated by converge_windowed: fresh buffer per call.
             t0 = jax.device_put(jnp.asarray(p))
             t, it, resid = converge_windowed(
-                *device_args[:7],
+                *plan_args,
                 t0,
-                *device_args[7:],
-                n_rows=plan.n_rows,
-                table_entries=plan.table_entries,
+                *vec_args,
+                **plan.static_kwargs(),
                 alpha=alpha,
                 tol=0.0,
                 max_iter=iters,
@@ -210,15 +209,12 @@ def headline_entry(
         from jax.sharding import PartitionSpec as P
 
         from protocol_tpu.analysis.comm.hlo_walk import parse_module
-        from protocol_tpu.parallel.sharded import _get_windowed_runner
+        from protocol_tpu.parallel.sharded import windowed_operands, windowed_runner
 
-        runner = _get_windowed_runner(
-            mesh, swp.n, swp.rows_per_shard, swp.table_entries, swp.interpret
-        )
+        runner = windowed_runner(swp)
         alpha_repl = jax.device_put(np.float32(0.1), NamedSharding(mesh, P()))
         compiled = runner.lower(
-            swp.wid, swp.local, swp.weight, swp.seg_end, swp.seg_first,
-            swp.seg_perm, swp.dst_ptr, swp.t0(), swp.p, swp.dangling,
+            *windowed_operands(swp), swp.t0(), swp.p, swp.dangling,
             alpha_repl, max_iter=iters, tol=0.0,
         ).compile()
         mod = parse_module(compiled.as_text())

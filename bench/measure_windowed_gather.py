@@ -43,7 +43,9 @@ eps = jnp.float32(1e-38)
 @jax.jit
 def chain_windowed(wid, tbl, loc, wgt):
     def step(_, acc):
-        out = gather_windowed(wid, tbl + acc * eps, loc, wgt, n_rows=b["n_rows"])
+        out = gather_windowed(
+            wid, tbl + acc * eps, loc, wgt, n_rows=b["n_rows"], table_chunk=b["table_chunk"]
+        )
         return out[0, 0]
     return lax.fori_loop(0, REPS, step, jnp.float32(0))
 

@@ -188,8 +188,7 @@ def converge_text(plan) -> str:
     vec = jax.ShapeDtypeStruct((plan.n,), jnp.float32)
     return converge_windowed.lower(
         *(spec(a) for a in plan.device_args()), vec, vec, vec,
-        n_rows=plan.n_rows,
-        table_entries=plan.table_entries,
+        **plan.static_kwargs(),
         alpha=jax.ShapeDtypeStruct((), jnp.float32),
         tol=TOL,
         max_iter=MAX_ITER,
@@ -336,7 +335,11 @@ def sharded_epoch(
     from protocol_tpu.analysis.comm.hlo_walk import parse_module
     from protocol_tpu.node.manager import Manager, ManagerConfig
     from protocol_tpu.parallel.mesh import default_mesh
-    from protocol_tpu.parallel.sharded import ShardedWindowPlan, _get_windowed_runner
+    from protocol_tpu.parallel.sharded import (
+        ShardedWindowPlan,
+        windowed_operands,
+        windowed_runner,
+    )
 
     backend = "tpu-sharded:tpu-windowed"
     manager = Manager(ManagerConfig(backend=backend, prover="commitment"))
@@ -349,11 +352,9 @@ def sharded_epoch(
     mesh = default_mesh()
     check(mesh.size == chips, f"default mesh has {mesh.size} devices, want {chips}")
     swp = ShardedWindowPlan.build(graph, mesh, plan=manager.window_plan)
-    arrays = (swp.wid, swp.local, swp.weight, swp.seg_end, swp.seg_first, swp.seg_perm, swp.dst_ptr)
+    arrays = windowed_operands(swp)
     devices = [sorted(s.device.id for s in a.addressable_shards) for a in arrays]
-    run = _get_windowed_runner(
-        mesh, swp.n, swp.rows_per_shard, swp.table_entries, swp.interpret
-    )
+    run = windowed_runner(swp)
     alpha = jax.device_put(np.float32(ALPHA), NamedSharding(mesh, P()))
     text = run.lower(
         *arrays, swp.t0(), swp.p, swp.dangling, alpha,

@@ -1,7 +1,7 @@
 """protocol_tpu.analysis — graftlint, the two-pass static analyzer.
 
 The invariants that make the trust backends fast — one random gather
-per windowed step, streaming boundary reads, no f64 upcasts, no host
+per windowed step, no f64 upcasts, no host
 callbacks inside the jit'd loop, one psum under shard_map — are
 contracts of the *lowered* computation, invisible to ruff and mypy.
 This subsystem checks them by machine:

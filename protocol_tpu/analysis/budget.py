@@ -33,16 +33,12 @@ class GatherBudget:
 
     ``dim`` names a dimension the trace recipe reports (e.g.
     ``n_segments``); every gather whose leading output dimension equals
-    that size is counted against this budget.  ``boundary_sorted``
-    additionally requires exactly one ``(dim, 2)``-shaped gather marked
-    ``indices_are_sorted`` + ``unique_indices`` — the streaming
-    two-lane boundary read of the single-pass bridge (PERF.md §8).
+    that size is counted against this budget.
     """
 
     dim: str
     max_total: int
     max_random: int
-    boundary_sorted: bool = False
 
 
 @dataclass(frozen=True)
@@ -84,7 +80,7 @@ class KernelBudget:
         n += 1  # psum count/placement is always asserted (incl. == 0)
         n += len(self.require_primitives)
         for gb in self.gather_budgets:
-            n += 2 + (1 if gb.boundary_sorted else 0)
+            n += 2
         if self.donated_args:
             n += 1
         return n

@@ -204,8 +204,7 @@ def _lower_windowed(n: int, e: int) -> CommCase:
         jnp.asarray(dangling),
     )
     kw = dict(
-        n_rows=plan.n_rows,
-        table_entries=plan.table_entries,
+        **plan.static_kwargs(),
         alpha=jnp.asarray(0.1, jnp.float32),
         tol=1e-6,
         max_iter=4,
@@ -224,8 +223,8 @@ def _lower_windowed(n: int, e: int) -> CommCase:
         },
         module_text=compiled.as_text(),
         arg_names=(
-            "wid", "local", "weight", "seg_end", "seg_first", "seg_perm",
-            "dst_ptr", "t0", "p", "dangling",
+            "wid", "local", "weight", "run_start", "run_end", "dst_ptr",
+            "t0", "p", "dangling",
         ),
         jaxpr_psums=_jaxpr_psums(jaxpr),
         mem=_mem_stats(compiled),
@@ -272,17 +271,18 @@ def _lower_sharded_windowed(n: int, e: int) -> CommCase:
     import jax.numpy as jnp
 
     from ...parallel.mesh import SHARD_AXIS, default_mesh
-    from ...parallel.sharded import ShardedWindowPlan, _get_windowed_runner
+    from ...parallel.sharded import (
+        ShardedWindowPlan,
+        windowed_operands,
+        windowed_runner,
+    )
 
     mesh = default_mesh()
     graph = _graph(n, e)
     swp = ShardedWindowPlan.build(graph, mesh)
-    run = _get_windowed_runner(
-        mesh, swp.n, swp.rows_per_shard, swp.table_entries, swp.interpret
-    )
+    run = windowed_runner(swp)
     args = (
-        swp.wid, swp.local, swp.weight, swp.seg_end, swp.seg_first,
-        swp.seg_perm, swp.dst_ptr, swp.t0(), swp.p, swp.dangling,
+        *windowed_operands(swp), swp.t0(), swp.p, swp.dangling,
         jnp.asarray(0.1, jnp.float32),
     )
     kw = dict(max_iter=4, tol=1e-6)
@@ -299,8 +299,8 @@ def _lower_sharded_windowed(n: int, e: int) -> CommCase:
         },
         module_text=compiled.as_text(),
         arg_names=(
-            "wid", "local", "weight", "seg_end", "seg_first", "seg_perm",
-            "dst_ptr", "t0", "p", "dangling", "alpha",
+            "wid", "local", "weight", "run_start", "run_end", "dst_ptr",
+            "t0", "p", "dangling", "alpha",
         ),
         jaxpr_psums=_jaxpr_psums(jaxpr),
         mem=_mem_stats(compiled),

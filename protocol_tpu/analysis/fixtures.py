@@ -87,34 +87,6 @@ def _callback_in_jit() -> tuple[KernelBudget, TraceCase]:
     return budget, TraceCase("fixture:callback-in-jit", jaxpr)
 
 
-def _unsorted_boundary() -> tuple[KernelBudget, TraceCase]:
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    hi = jnp.asarray(np.arange(32, dtype=np.float32))
-    seg_end = jnp.asarray(np.array([3, 7, 12, 19, 25, 31], np.int32))
-
-    def step(hi, seg_end):
-        cum2 = jnp.stack([hi, hi], axis=-1)
-        # The bridge's boundary read without the streaming declaration
-        # (indices_are_sorted/unique_indices) — XLA plans a random read.
-        ends = cum2[seg_end]  # VIOLATION: unsorted-boundary
-        return ends[:, 0] + ends[:, 1]
-
-    jaxpr = jax.make_jaxpr(step)(hi, seg_end)
-    budget = KernelBudget(
-        backend="fixture:unsorted-boundary",
-        max_random_gathers=4,
-        gather_budgets=(
-            GatherBudget(dim="n_segments", max_total=4, max_random=4, boundary_sorted=True),
-        ),
-    )
-    return budget, TraceCase(
-        "fixture:unsorted-boundary", jaxpr, dims={"n_segments": 6}
-    )
-
-
 def _scatter_in_step() -> tuple[KernelBudget, TraceCase]:
     import jax
     import jax.numpy as jnp
@@ -998,12 +970,6 @@ FIXTURES: dict[str, Fixture] = {
         Fixture("f64-leak", "f64-dtype", _f64_leak, "f64-leak"),
         Fixture(
             "callback-in-jit", "callback-in-jit", _callback_in_jit, "callback-in-jit"
-        ),
-        Fixture(
-            "unsorted-boundary",
-            "boundary-sorted",
-            _unsorted_boundary,
-            "unsorted-boundary",
         ),
         Fixture(
             "scatter-in-step", "scatter-budget", _scatter_in_step, "scatter-in-step"

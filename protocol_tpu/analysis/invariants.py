@@ -170,7 +170,7 @@ def _trace_windowed(graph) -> TraceCase:
     plan = build_window_plan(g.src, g.dst, w, n=g.n)
     # Keep the budget dimensions distinguishable: the rowsum gathers are
     # (n+1)-sized, the bridge reads seg_capacity-sized (the device
-    # length of the padded segment tables, >= n_segments live runs).
+    # length of the padded run table, >= n_segments live runs).
     assert plan.seg_capacity != g.n + 1, "synthetic graph aliases budget dims"
     p = g.pre_trust_vector()
     args = plan.device_args() + (
@@ -182,16 +182,14 @@ def _trace_windowed(graph) -> TraceCase:
     jaxpr = jax.make_jaxpr(
         lambda *a: power_step_windowed(
             *a,
-            n_rows=plan.n_rows,
-            table_entries=plan.table_entries,
+            **plan.static_kwargs(),
             interpret=True,
         )
     )(*args)
     lowered = converge_windowed.lower(
-        *args[:10],
-        n_rows=plan.n_rows,
-        table_entries=plan.table_entries,
-        alpha=args[10],
+        *args[:-1],
+        **plan.static_kwargs(),
+        alpha=args[-1],
         tol=1e-6,
         max_iter=4,
         interpret=True,
@@ -243,22 +241,18 @@ def _trace_sharded_windowed(graph) -> TraceCase:
     import jax.numpy as jnp
 
     from ..parallel.mesh import default_mesh
-    from ..parallel.sharded import ShardedWindowPlan, _get_windowed_runner
+    from ..parallel.sharded import (
+        ShardedWindowPlan,
+        windowed_operands,
+        windowed_runner,
+    )
 
     mesh = default_mesh()
     swp = ShardedWindowPlan.build(graph, mesh)
     assert swp.s_max != swp.n + 1, "synthetic graph aliases budget dims"
-    run = _get_windowed_runner(
-        mesh, swp.n, swp.rows_per_shard, swp.table_entries, swp.interpret
-    )
+    run = windowed_runner(swp)
     args = (
-        swp.wid,
-        swp.local,
-        swp.weight,
-        swp.seg_end,
-        swp.seg_first,
-        swp.seg_perm,
-        swp.dst_ptr,
+        *windowed_operands(swp),
         swp.t0(),
         swp.p,
         swp.dangling,
@@ -344,21 +338,6 @@ def check_case(budget: KernelBudget, case: TraceCase) -> list[Finding]:
                 f"single-pass bridge allows exactly one random pass)",
                 sized_random[-1],
             )
-        if gb.boundary_sorted:
-            boundary = [
-                g
-                for g in sized
-                if g.out_shape == (size, 2) and g.sorted_indices and g.unique_indices
-            ]
-            if len(boundary) != 1:
-                candidates = [g for g in sized if g.out_shape == (size, 2)]
-                err(
-                    "boundary-sorted",
-                    f"expected exactly one sorted+unique ({gb.dim}, 2) "
-                    f"boundary gather (the streaming bridge read), found "
-                    f"{len(boundary)}",
-                    candidates[-1] if candidates else None,
-                )
 
     # Scatters (scatter-free is the whole point of the CSR/windowed
     # formulations — TPU scatter serializes on destination indices).
@@ -547,7 +526,6 @@ def run_jaxpr_pass(
                         "dim": gb.dim,
                         "max_total": gb.max_total,
                         "max_random": gb.max_random,
-                        "boundary_sorted": gb.boundary_sorted,
                     }
                     for gb in budget.gather_budgets
                 ],

@@ -14,28 +14,36 @@ selects.  This module composes exactly those into a windowed gather and
   and a weight mask.  The loop-free formulation (argsort + cumulative
   counts) buckets 50M edges in seconds, not the ~34 s of the original
   per-window Python loop (PERF.md §6).
-- Kernel side (`gather_windowed`): the ≤4 MB score table lives in VMEM
-  as (rows, 128); per vreg-row the kernel dynamic-slices the (8, 128)
-  window and resolves the 1024 local indices with an 8-way
-  broadcast/lane-gather/select chain (~30 vreg ops per 1024 edges).
-- Bridge side (`power_step_windowed`, PERF.md §7-8): the kernel output
-  is in *bucket order*, not the dst order the rowsum needs.  Bridging
+- Kernel side (`gather_windowed`): the score table is read from HBM
+  as (rows, 128) in blocks of at most ``TABLE_CHUNK`` entries (4 MB),
+  one block per grid step, so its VMEM use does not grow with N; a
+  table that fits one chunk is one block, resident for the whole
+  call.  Every grid step's rows lie in one chunk (``bucket_by_window``
+  aligns each chunk's rows to BLOCK_ROWS, ``apply_delta`` starts a
+  window's overflow in a fresh block where the open one reads another
+  chunk).  The rows a rebuild laid out ascend by chunk, so each chunk
+  crosses into VMEM once for them; a delta's overflow blocks follow in
+  the order windows outgrew their rows, and each change of chunk among
+  them reads that chunk again.  Per vreg-row the
+  kernel dynamic-slices the (8, 128) window and resolves the 1024
+  local indices with an 8-way broadcast/lane-gather/select chain (~30
+  vreg ops per 1024 edges).
+- Bridge side (`power_step_windowed`): the kernel output is in
+  *bucket order*, not the dst order the rowsum needs.  Bridging
   per-edge would itself be an O(E) random gather (the circularity that
   stalled PERF.md §1).  Instead `bucket_by_window` additionally sorts
-  each window's edges by dst and emits a static single-pass reduction
-  plan (`bridge_partials`): the (hi, lo) lanes of the row-local
-  compensated prefix sum are interleaved into one (slots, 2) array so a
-  single 2-wide slice gather at the run *ends* — in bucket order, where
-  the end slots are strictly increasing, so the read streams — fetches
-  both lanes of every boundary at once; each run's start prefix is just
-  the *previous gathered element* (runs are consecutive within a
-  vreg-row), so the differencing is a shift, not a second gather; and
-  the one host-precomputed dst permutation of the resulting
-  ``n_segments`` partials is the only data-randomly-addressed pass per
-  iteration.  Random volume: 1× n_segments (was 4× — hi/lo at both
-  boundaries — before the interleave, PERF.md §7 open variable), with
-  ``n_segments <= min(E, n_windows · N)``, which the hub-heavy bench
-  graph compresses far below E (the plan records the measured ratio).
+  each window's edges by dst, so a (row, dst) run is consecutive slots,
+  and the plan carries two bridge tables: ``run_start`` flags every
+  run's first slot, and ``run_end`` holds each run's end slot in dst
+  order.  The device sums each run in place with a segmented scan
+  along the row (`bridge_partials`: log2(1024) shifted adds, no
+  gather), and one gather at ``run_end`` lifts the run sums straight
+  into dst order — the only data-randomly-addressed pass per
+  iteration, of ``n_segments`` elements, with ``n_segments <=
+  min(E, n_windows · N)``, which the hub-heavy bench graph compresses
+  far below E (the plan records the measured ratio).  A run's sum is
+  taken over its own slots, so no prefix is differenced and plain f32
+  keeps it exact to the run's length.
 
 Correctness is validated in interpret mode on CPU (tests); per-op
 wall-clock on the real chip is in PERF.md §6 and the fused-pipeline
@@ -64,7 +72,7 @@ from ..analysis.budget import (
     declare_comm,
     declare_mem,
 )
-from .sparse import _ds_cumsum_axis1, rowsum_sorted, run_power_iteration
+from .sparse import rowsum_sorted, run_power_iteration
 
 try:
     # The C two-pass kernel underneath scipy's COO→CSR conversion; the
@@ -80,6 +88,9 @@ WINDOW = 1024
 ROW = 1024
 #: Vreg-rows per grid step.
 BLOCK_ROWS = 64
+#: Score-table entries per VMEM block (4 MB of f32, 1024 windows): a
+#: larger table is streamed in chunks of this many entries.
+TABLE_CHUNK = 1 << 20
 
 
 #: log2(WINDOW): window ids and window-local indices are shifts/masks.
@@ -153,6 +164,41 @@ def _unpack_lanes(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return v[:, 0], v[:, 1]
 
 
+def table_blocks(table_entries: int, table_chunk: int) -> tuple[int, int]:
+    """``(entries per block, blocks)`` of the score table as the kernel
+    reads it: the whole table as one block where it fits one chunk,
+    else chunks of ``table_chunk`` entries."""
+    if table_entries <= table_chunk:
+        return table_entries, 1
+    return table_chunk, -(-table_entries // table_chunk)
+
+
+def _align_chunks(rows_per: np.ndarray, table_chunk: int) -> int:
+    """Pad, in place, each chunk's rows but the last to a multiple of
+    BLOCK_ROWS by growing the chunk's last window; returns the rows
+    added.  ``rows_per`` is the vreg-row count of every window."""
+    chunk_windows = table_chunk // WINDOW
+    n_windows = rows_per.shape[0]
+    if n_windows <= chunk_windows:
+        return 0
+    starts = np.arange(0, n_windows, chunk_windows)
+    pad = -np.add.reduceat(rows_per, starts) % BLOCK_ROWS
+    pad[-1] = 0
+    rows_per[starts[1:] - 1] += pad[:-1]
+    return int(pad.sum())
+
+
+def _skipped_rows(
+    wid: np.ndarray, first: int, stop: int, chunk_windows: int
+) -> dict[int, list[int]]:
+    """Rows in ``[first, stop)`` a delta skipped (wid -1), by the chunk
+    of their grid block: the chunk of the block's other rows."""
+    rows = first + np.flatnonzero(wid[first:stop] < 0)
+    top = wid.reshape(-1, BLOCK_ROWS).max(axis=1)
+    chunk = top[rows // BLOCK_ROWS] // chunk_windows
+    return {int(c): rows[chunk == c].tolist() for c in np.unique(chunk)}
+
+
 def bucket_by_window(
     src: np.ndarray,
     w: np.ndarray,
@@ -171,15 +217,15 @@ def bucket_by_window(
     weight 0.
 
     With ``dst`` (and ``n_dst``) given, edges are additionally sorted by
-    destination *within* each window and the dict gains the static
-    single-pass reduction plan (PERF.md §7-8): ``seg_end`` flat end
-    slots of every per-(vreg-row, dst) run in *bucket order* (strictly
-    increasing — the boundary read streams), ``seg_first`` flagging
-    row-leading runs (whose start prefix is an exact zero),
-    ``seg_perm`` the bucket→dst permutation of the run partials, and
-    ``dst_ptr`` delimiting each destination's runs in permuted order —
-    everything ``power_step_windowed`` needs to reduce bucket-order
-    contributions to a dense Cᵀt with one n_segments-sized random pass.
+    destination *within* each window and the dict gains the run
+    tables: ``seg_end`` flat end slots of every per-(vreg-row, dst)
+    run in *bucket order* (strictly increasing), ``seg_first`` flagging
+    row-leading runs, ``run_start`` flagging every row's first slot and
+    every run's first slot (shaped like ``weight``), ``run_end`` the end
+    slots in dst order, and ``dst_ptr`` delimiting each destination's
+    runs in that order — everything ``power_step_windowed`` needs to
+    reduce bucket-order contributions to a dense Cᵀt with one
+    n_segments-sized random pass.
 
     Fully vectorized: stable counting sorts (scipy COO→CSR, O(E)) plus
     cumulative-count placement — the previous per-window Python loop
@@ -191,8 +237,15 @@ def bucket_by_window(
     ``WindowPlan.apply_delta`` allocates overflow rows from (and where
     the inert segment-table pads end), so a window outgrowing its
     original padding doesn't force a full rebuild (PERF.md §11).
-    None sizes it adaptively: one grid block or ~6% of the data rows,
-    whichever is larger.
+    None sizes it adaptively: one grid block, ~6% of the data rows, or
+    (with ``dst``) the slots for 3/16 of the runs' count in pad runs,
+    whichever is largest, plus a block per chunk past the first.
+
+    Where the table spans several chunks of ``TABLE_CHUNK`` entries (as
+    it stands at the call), each chunk's rows but the last are padded to
+    a multiple of BLOCK_ROWS — the pad rows (``align_rows``) join the
+    chunk's last window — so that no grid step reads two chunks.  A
+    table of one chunk gets no pad rows.
     """
     e = src.shape[0]
     if e == 0:
@@ -257,11 +310,9 @@ def bucket_by_window(
     # Rows per window, each padded to a full vreg-row; grid padded to
     # block granularity.  Windows with no edges contribute zero rows.
     rows_per = -(-counts // ROW)
+    align_rows = _align_chunks(rows_per, TABLE_CHUNK)
     row_offset = np.concatenate([[0], np.cumsum(rows_per)]).astype(np.int64)
     n_data_rows = int(row_offset[-1])
-    if spare_rows is None:
-        spare_rows = max(BLOCK_ROWS, n_data_rows // 16)
-    total_rows = -(-(n_data_rows + spare_rows) // BLOCK_ROWS) * BLOCK_ROWS
     # Flat slot of each window-sorted edge: consecutive within its
     # window, starting at the window's first (fresh) vreg-row.  One
     # repeat over the per-window pad shift; the scatter below is
@@ -273,6 +324,27 @@ def bucket_by_window(
     out_pos = np.repeat(
         (row_offset[:-1] * ROW - win_off).astype(np.int32), counts
     ) + np.arange(e, dtype=np.int32)
+    if ds is not None:
+        # Segments are maximal same-dst slot runs within one vreg-row:
+        # edges are dst-sorted inside each window and packed into
+        # consecutive slots, so a run breaks only at a dst change or a
+        # row boundary (a window change always starts a fresh row, so it
+        # needs no term).
+        brk = np.empty(e, bool)
+        brk[0] = True
+        brk[1:] = (ds[1:] != ds[:-1]) | (out_pos[1:] & (ROW - 1) == 0)
+    if spare_rows is None:
+        # One grid block, ~6% of the data rows, or a spare slot for each
+        # pad run of 3/16 of the runs (the 1/8 ``_segment_capacity``
+        # pads, and half as much again for deltas to grow into), which
+        # matters where runs are many per row; plus a block per further
+        # chunk, as a delta that grows windows of several chunks may
+        # skip to a fresh block at each.
+        pad_slots = 0 if ds is None else 3 * int(np.count_nonzero(brk)) // 16
+        spare_rows = max(BLOCK_ROWS, n_data_rows // 16, -(-pad_slots // ROW)) + (
+            BLOCK_ROWS * (table_blocks(n_windows * WINDOW, TABLE_CHUNK)[1] - 1)
+        )
+    total_rows = -(-(n_data_rows + spare_rows) // BLOCK_ROWS) * BLOCK_ROWS
     local = np.zeros(total_rows * ROW, np.int32)
     weight = np.zeros(total_rows * ROW, np.float32)
     local[out_pos] = local_sorted
@@ -288,40 +360,35 @@ def bucket_by_window(
         "n_rows": total_rows,
         "n_data_rows": n_data_rows,
         "row_offset": row_offset,
+        "align_rows": align_rows,
+        "table_chunk": TABLE_CHUNK,
     }
     if ds is None:
         return result
 
     # -- static single-pass reduction plan (PERF.md §7-8) ---------------
-    # Segments are maximal same-dst slot runs within one vreg-row: edges
-    # are dst-sorted inside each window and packed into consecutive
-    # slots, so a run breaks only at a dst change or a row boundary (a
-    # window change always starts a fresh row, so it needs no term).
-    brk = np.empty(e, bool)
-    brk[0] = True
-    brk[1:] = (ds[1:] != ds[:-1]) | (out_pos[1:] & (ROW - 1) == 0)
     end_mask = np.empty(e, bool)
     end_mask[-1] = True
     end_mask[:-1] = brk[1:]
     seg_dst = ds[brk]
-    # The boundary table stays in BUCKET order: run end slots are then
-    # strictly increasing, so the device's one boundary gather reads
-    # monotonically (streams) instead of jumping dst-to-dst through the
-    # prefix-sum array.  A run's start prefix is the previous run's end
-    # prefix (runs are consecutive within a row) — an on-device shift —
-    # except at row-leading runs, where it is an exact zero.
+    # The host keeps the run table in BUCKET order (the delta update
+    # addresses runs by slot); the device reads run ends in dst order,
+    # which the counting sort carries out as its payload.
     seg_end = np.ascontiguousarray(out_pos[end_mask])
     seg_first = np.ascontiguousarray(out_pos[brk] & (ROW - 1) == 0)
-    # Host-side dst sort of the segment table becomes a single stored
-    # permutation: the device applies it once to the n_segments run
-    # partials — the only data-randomly-addressed pass per iteration.
-    seg_perm, seg_counts, _ = _counting_sort(seg_dst, n_dst)
+    run_start = np.zeros(total_rows * ROW, bool)
+    run_start[::ROW] = True
+    run_start[out_pos[brk]] = True
+    _, seg_counts, run_end = _counting_sort(
+        seg_dst, n_dst, payload=seg_end.astype(np.int32)
+    )
     dst_ptr = np.zeros(n_dst + 1, np.int64)
     np.cumsum(seg_counts, out=dst_ptr[1:])
     result.update(
         seg_end=seg_end,
         seg_first=seg_first,
-        seg_perm=seg_perm.astype(np.int32, copy=False),
+        run_start=run_start.reshape(total_rows * 8, 128),
+        run_end=run_end,
         dst_ptr=dst_ptr.astype(np.int32),
         seg_dst=np.ascontiguousarray(seg_dst, dtype=np.int32),
         n_segments=int(seg_dst.shape[0]),
@@ -329,16 +396,23 @@ def bucket_by_window(
     return result
 
 
-def _kernel(wid_ref, t_ref, local_ref, w_ref, out_ref):
-    """One grid step: BLOCK_ROWS vreg-rows of 1024 edges each.
+def _kernel(wid_ref, chunk_ref, t_ref, local_ref, w_ref, out_ref, *, block_windows):
+    """One grid step: BLOCK_ROWS vreg-rows of 1024 edges each, against
+    the table block of the step's chunk.
 
-    ``wid_ref`` is the scalar-prefetch ref (SMEM) of the FULL wid
-    array — dynamic-slice starts must come from scalar memory, not a
-    VMEM vector load, to lower on Mosaic."""
+    ``wid_ref`` and ``chunk_ref`` are the scalar-prefetch refs (SMEM) of
+    the FULL wid and per-step chunk arrays — dynamic-slice starts must
+    come from scalar memory, not a VMEM vector load, to lower on Mosaic.
+    A row that holds no edge may name a window outside the step's chunk
+    (or -1); the clamp keeps its read inside the block, and its weight
+    is 0."""
     blk = pl.program_id(0)
+    base = chunk_ref[blk] * block_windows
     for v in range(BLOCK_ROWS):
-        wid = wid_ref[blk * BLOCK_ROWS + v]
-        win = t_ref[pl.ds(wid * 8, 8), :]  # (8,128) window slice
+        off = jnp.minimum(
+            jnp.maximum(wid_ref[blk * BLOCK_ROWS + v] - base, 0), block_windows - 1
+        )
+        win = t_ref[pl.ds(off * 8, 8), :]  # (8,128) window slice
         lidx = local_ref[pl.ds(v * 8, 8), :]
         sub = lidx // 128
         lane = lidx % 128
@@ -350,7 +424,7 @@ def _kernel(wid_ref, t_ref, local_ref, w_ref, out_ref):
         out_ref[pl.ds(v * 8, 8), :] = acc * w_ref[pl.ds(v * 8, 8), :]
 
 
-@partial(jax.jit, static_argnames=("n_rows", "interpret"))
+@partial(jax.jit, static_argnames=("n_rows", "table_chunk", "interpret"))
 def gather_windowed(
     wid: jax.Array,
     table: jax.Array,
@@ -358,35 +432,51 @@ def gather_windowed(
     weight: jax.Array,
     *,
     n_rows: int,
+    table_chunk: int,
     interpret: bool = False,
 ) -> jax.Array:
     """``out[r, j] = weight[r, j] * table[wid[r//8]*1024 + local[r, j]]``
-    with the table resident in VMEM as (rows, 128)."""
-    assert table.size % WINDOW == 0
+    with the table read into VMEM in blocks of ``table_blocks``: one
+    resident block where it fits ``table_chunk`` entries, else one
+    chunk per grid step.  ``table_chunk`` must be the one the rows were
+    laid out for (``WindowPlan.table_chunk``).  A step's chunk is that
+    of the largest window among its rows: a row that holds no edge names
+    a window of the step's chunk, window 0 (the spare tail) or -1 (a row
+    a delta skipped), so it never raises it."""
+    assert table.size % WINDOW == 0 and table_chunk % WINDOW == 0
     assert n_rows % BLOCK_ROWS == 0, (
         f"n_rows must be a multiple of {BLOCK_ROWS} (bucket_by_window pads "
         "to this); a partial trailing block would be silently unwritten"
     )
-    t2d = table.reshape(-1, 128)
+    block, n_chunks = table_blocks(table.size, table_chunk)
+    t2d = jnp.pad(table, (0, block * n_chunks - table.size)).reshape(-1, 128)
     n_blocks = n_rows // BLOCK_ROWS
+    block_windows = block // WINDOW
+    chunk = jnp.clip(
+        jnp.max(wid.reshape(n_blocks, BLOCK_ROWS), axis=1) // block_windows,
+        0,
+        n_chunks - 1,
+    )
     from jax.experimental.pallas import tpu as pltpu
 
+    rows = lambda i, wid_ref, chunk_ref: (i, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec(t2d.shape, lambda i, wid_ref: (0, 0)),
-            pl.BlockSpec((BLOCK_ROWS * 8, 128), lambda i, wid_ref: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS * 8, 128), lambda i, wid_ref: (i, 0)),
+            pl.BlockSpec((block // 128, 128), lambda i, wid_ref, chunk_ref: (chunk_ref[i], 0)),
+            pl.BlockSpec((BLOCK_ROWS * 8, 128), rows),
+            pl.BlockSpec((BLOCK_ROWS * 8, 128), rows),
         ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS * 8, 128), lambda i, wid_ref: (i, 0)),
+        out_specs=pl.BlockSpec((BLOCK_ROWS * 8, 128), rows),
     )
     return pl.pallas_call(
-        _kernel,
+        partial(_kernel, block_windows=block_windows),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows * 8, 128), jnp.float32),
         interpret=interpret,
-    )(wid, t2d, local, weight)
+        name="gather_windowed",
+    )(wid, chunk, t2d, local, weight)
 
 
 # ---------------------------------------------------------------------------
@@ -400,10 +490,13 @@ def gather_windowed(
 #: ``seg_end`` + row-leading mask + folded dst permutation, PERF.md §8);
 #: v3 adds the host-side delta-update bookkeeping (bucket-order
 #: ``seg_dst``, per-window ``row_offset``, the live-row watermark, and
-#: the fingerprint lineage chain, PERF.md §11).  Checkpoint-restored
-#: plans of any other version are discarded and rebuilt — the same
-#: path a fingerprint mismatch takes.
-PLAN_VERSION = 3
+#: the fingerprint lineage chain, PERF.md §11); v4 aligns each table
+#: chunk's rows to grid blocks and records ``table_chunk``, so a table
+#: larger than one VMEM block is streamed, and stores the run ends in
+#: dst order (``run_end``) in place of the bucket→dst permutation.  Checkpoint-restored plans
+#: of any other version are discarded and rebuilt — the same path a
+#: fingerprint mismatch takes.
+PLAN_VERSION = 4
 
 #: Ancestor fingerprints a delta-updated plan remembers (checkpoint
 #: forensics: how many epochs of churn separate this layout from its
@@ -440,11 +533,11 @@ def _pad_segment_tables(
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Pad the live bucket-order run tables to ``capacity`` device
     entries and fold the dst sort: pad runs end at the topmost
-    zero-weight spare slots (strictly above every live run, so the
-    boundary read stays sorted and their partials are exact zeros) and
-    carry sentinel dst ``n``, which the counting sort parks beyond
-    ``dst_ptr[n]`` — never reduced into any destination.  Returns
-    ``(seg_end, seg_first, seg_perm, dst_ptr)`` at device capacity."""
+    zero-weight spare slots (strictly above every live run, so their
+    sums are exact zeros) and carry sentinel dst ``n``, which the
+    counting sort parks beyond ``dst_ptr[n]`` — never reduced into any
+    destination.  Returns ``(seg_end, seg_first, run_end, dst_ptr)`` at
+    device capacity, ``run_end`` being ``seg_end`` in dst order."""
     s = int(seg_end.shape[0])
     pad = capacity - s
     if pad < 0 or pad > (n_rows - n_data_rows) * ROW:
@@ -454,21 +547,18 @@ def _pad_segment_tables(
     total_slots = n_rows * ROW
     end = np.concatenate(
         [
-            seg_end.astype(np.int64),
-            np.arange(total_slots - pad, total_slots, dtype=np.int64),
+            seg_end.astype(np.int32),
+            np.arange(total_slots - pad, total_slots, dtype=np.int32),
         ]
     )
     first = np.concatenate([seg_first.astype(bool), np.ones(pad, bool)])
     key = np.concatenate([seg_dst.astype(np.int64), np.full(pad, n, np.int64)])
-    perm, counts, _ = _counting_sort(np.ascontiguousarray(key, np.int32), n + 1)
+    _, counts, run_end = _counting_sort(
+        np.ascontiguousarray(key, np.int32), n + 1, payload=end
+    )
     dst_ptr = np.zeros(n + 1, np.int64)
     np.cumsum(counts[:n], out=dst_ptr[1:])
-    return (
-        end.astype(np.int32),
-        first,
-        np.asarray(perm, np.int32),
-        dst_ptr.astype(np.int32),
-    )
+    return end, first, run_end, dst_ptr.astype(np.int32)
 
 
 def _segment_capacity(s: int, max_pad_slots: int) -> int:
@@ -507,13 +597,15 @@ class WindowPlan:
     wid: np.ndarray  # (n_rows,) int32 window id per vreg-row
     local: np.ndarray  # (n_rows*8, 128) int32 window-local indices
     weight: np.ndarray  # (n_rows*8, 128) f32 slot weights (0 = padding)
+    run_start: np.ndarray  # (n_rows*8, 128) bool slot starts a row or a run
     seg_end: np.ndarray  # (S,) int32 last slot of each run, bucket order
-    seg_first: np.ndarray  # (S,) bool run is row-leading (start prefix = 0)
-    seg_perm: np.ndarray  # (S,) int32 bucket→dst permutation of partials
+    seg_first: np.ndarray  # (S,) bool run is row-leading
+    run_end: np.ndarray  # (S,) int32 last slot of each run, dst order
     dst_ptr: np.ndarray  # (n+1,) int32 run range per destination
     seg_dst: np.ndarray  # (S,) int32 run destination, bucket order (host-side)
     row_offset: np.ndarray  # (n_windows+1,) int64 original rows per window
     fingerprint: str  # graph identity for safe reuse
+    table_chunk: int  # table entries per chunk the rows are aligned to
     version: int = PLAN_VERSION  # layout version (see PLAN_VERSION)
     #: Fingerprints of the plans this one was delta-derived from,
     #: oldest first, capped at LINEAGE_DEPTH; empty for a from-scratch
@@ -521,14 +613,27 @@ class WindowPlan:
     lineage: tuple[str, ...] = ()
     order: np.ndarray | None = None  # (E,) bucket position k ← edge order[k]
     out_pos: np.ndarray | None = None  # (E,) slot of edge order[k]
+    #: Rows the step that made this plan padded for chunk alignment:
+    #: the rebuild's per-chunk pads, or the rows a delta skipped to
+    #: start a window's overflow in a block of its own chunk.  Not
+    #: persisted (a restored plan padded nothing).
+    align_rows: int = 0
 
+    #: Persisted layout arrays.
+    _CORE = (
+        "wid", "local", "weight", "run_start", "seg_end", "seg_first", "run_end",
+        "dst_ptr",
+    )
     #: Device operands, in ``converge_windowed`` order — exactly what
     #: crosses the host→HBM boundary.
-    _CORE = ("wid", "local", "weight", "seg_end", "seg_first", "seg_perm", "dst_ptr")
+    _DEVICE = ("wid", "local", "weight", "run_start", "run_end", "dst_ptr")
     #: Host-only bookkeeping for ``apply_delta`` (persisted, never
     #: shipped to the device).
     _HOST = ("seg_dst", "row_offset")
-    _META = ("n", "n_rows", "table_entries", "n_segments", "n_data_rows", "n_edges")
+    _META = (
+        "n", "n_rows", "table_entries", "n_segments", "n_data_rows", "n_edges",
+        "table_chunk",
+    )
 
     @property
     def compression(self) -> float:
@@ -536,6 +641,22 @@ class WindowPlan:
         how much the run-level reduction shrinks the random-access
         volume vs a per-edge bucket→dst permutation."""
         return self.n_edges / max(self.n_segments, 1)
+
+    def static_kwargs(self) -> dict:
+        """The layout's static arguments of ``converge_windowed``,
+        ``power_step_windowed`` and ``windowed_ct``: the one place a
+        caller takes them from, so the kernel reads the rows with the
+        chunk they were laid out for."""
+        return {
+            "n_rows": self.n_rows,
+            "table_entries": self.table_entries,
+            "table_chunk": self.table_chunk,
+        }
+
+    @property
+    def table_chunks(self) -> int:
+        """Blocks the kernel streams the score table in (1: resident)."""
+        return table_blocks(self.table_entries, self.table_chunk)[1]
 
     @property
     def seg_capacity(self) -> int:
@@ -545,8 +666,8 @@ class WindowPlan:
         return int(self.seg_end.shape[0])
 
     def device_args(self) -> tuple:
-        """Core arrays as device arrays, in ``converge_windowed`` order."""
-        return tuple(jnp.asarray(getattr(self, k)) for k in self._CORE)
+        """Device operands, in ``converge_windowed`` order."""
+        return tuple(jnp.asarray(getattr(self, k)) for k in self._DEVICE)
 
     def to_arrays(self, *, core_only: bool = True) -> dict:
         """npz-ready mapping (checkpoint format)."""
@@ -670,9 +791,9 @@ class WindowPlan:
         into the segment table and a gather and repack of that window's
         runs and slots alone; then a fixed set of whole-table passes,
         however many windows are touched — the copies of ``wid``,
-        ``local`` and ``weight`` (O(slots)), the kept-run compress
+        ``local``, ``weight`` and ``run_start`` (O(slots)), the kept-run compress
         (O(S)), the splice (a stable argsort of the S run ends) and the
-        dst counting sort behind ``seg_perm``/``dst_ptr`` (O(S)) — far
+        dst counting sort behind ``run_end``/``dst_ptr`` (O(S)) — far
         below the full rebuild's O(E) counting sorts.  The result's
         ``fingerprint`` is the caller-supplied identity of the
         post-delta graph and the predecessor chain lands in ``lineage``.
@@ -702,6 +823,18 @@ class WindowPlan:
                 raise PlanDeltaError("delta edge index outside [0, n)")
         table_entries = -(-n_new // WINDOW) * WINDOW
         n_windows = table_entries // WINDOW
+        # Over several chunks, a grid block's rows must share one chunk:
+        # a window's overflow starts a new block where the open one
+        # reads another chunk, and the rows skipped get wid -1.  A later
+        # window of the skipped block's chunk takes them first.
+        chunk_windows = self.table_chunk // WINDOW
+        chunked = table_blocks(table_entries, self.table_chunk)[1] > 1
+        align_rows = 0
+        skipped = (
+            _skipped_rows(self.wid, int(self.row_offset[-1]), self.n_data_rows, chunk_windows)
+            if chunked
+            else {}
+        )
         row_offset = self.row_offset
         if n_windows + 1 > len(row_offset):
             # New windows own no original rows; overflow allocation
@@ -718,6 +851,7 @@ class WindowPlan:
             wid = self.wid.copy()
             local = self.local.reshape(-1).copy()
             weight = self.weight.reshape(-1).copy()
+            run_start = self.run_start.reshape(-1).copy()
             n_data_rows = self.n_data_rows
 
             # The touched windows' rows and the runs living in them — the
@@ -771,16 +905,38 @@ class WindowPlan:
                     slots_w = (rows_w[:, None] * ROW + np.arange(ROW)[None, :]).reshape(-1)
                     local[slots_w] = 0
                     weight[slots_w] = 0.0
+                    run_start[slots_w] = slots_w % ROW == 0
                 if count > rows_w.size * ROW:
                     extra = -(-(count - rows_w.size * ROW) // ROW)
-                    if n_data_rows + extra > self.n_rows:
+                    free = skipped.get(w // chunk_windows, [])
+                    reused = np.asarray(free[:extra], np.int64)
+                    del free[:extra]
+                    extra -= reused.size
+                    start = n_data_rows
+                    blk = start - start % BLOCK_ROWS
+                    if (
+                        extra
+                        and chunked
+                        and start > blk
+                        and int(wid[blk:start].max()) // chunk_windows
+                        != w // chunk_windows
+                    ):
+                        start = blk + BLOCK_ROWS
+                        skipped.setdefault(
+                            int(wid[blk:n_data_rows].max()) // chunk_windows, []
+                        ).extend(range(n_data_rows, start))
+                    if start + extra > self.n_rows:
                         raise PlanDeltaError(
                             f"window {w} outgrew the spare-row headroom; rebuild"
                         )
-                    grown = np.arange(n_data_rows, n_data_rows + extra, dtype=np.int64)
+                    wid[n_data_rows:start] = -1
+                    align_rows += start - n_data_rows
+                    grown = np.concatenate(
+                        [reused, np.arange(start, start + extra, dtype=np.int64)]
+                    )
                     wid[grown] = w
-                    n_data_rows += extra
-                    rows_w = np.concatenate([rows_w, grown])
+                    n_data_rows = start + extra
+                    rows_w = np.sort(np.concatenate([rows_w, grown]))
                 if count == 0:
                     continue
                 order = np.argsort(odst, kind="stable")
@@ -795,6 +951,7 @@ class WindowPlan:
                 endm = np.empty(count, bool)
                 endm[-1] = True
                 endm[:-1] = brk[1:]
+                run_start[slots[brk]] = True
                 new_end.append(slots[endm])
                 new_first.append(lead[brk])
                 new_dst.append(d[brk])
@@ -816,7 +973,7 @@ class WindowPlan:
             if s_new > capacity or capacity - s_new > max_pad:
                 capacity = _segment_capacity(s_new, max_pad)
         with phase("plan_seg_tables"):
-            seg_end, seg_first, seg_perm, dst_ptr = _pad_segment_tables(
+            seg_end, seg_first, run_end, dst_ptr = _pad_segment_tables(
                 live_end,
                 live_first,
                 seg_dst,
@@ -835,14 +992,17 @@ class WindowPlan:
             wid=wid,
             local=local.reshape(self.local.shape),
             weight=weight.reshape(self.weight.shape),
-            seg_end=seg_end.astype(np.int32),
+            run_start=run_start.reshape(self.run_start.shape),
+            seg_end=seg_end,
             seg_first=seg_first,
-            seg_perm=seg_perm.astype(np.int32, copy=False),
-            dst_ptr=dst_ptr.astype(np.int32),
+            run_end=run_end,
+            dst_ptr=dst_ptr,
             seg_dst=seg_dst,
             row_offset=row_offset,
             fingerprint=fingerprint,
+            table_chunk=self.table_chunk,
             lineage=(self.lineage + (self.fingerprint,))[-LINEAGE_DEPTH:],
+            align_rows=align_rows,
         )
 
     def replace_rows(
@@ -917,7 +1077,9 @@ def build_window_plan(
     ~6% of the data rows) lets ``apply_delta`` absorb window growth —
     and segment-table fragmentation — across epochs without a rebuild
     or a device-shape change.  ``phase`` wraps the two sub-phases,
-    ``plan_bucket`` and ``plan_seg_tables``."""
+    ``plan_bucket`` and ``plan_seg_tables``.  The rows are aligned to
+    the table chunks of ``TABLE_CHUNK`` as it stands at the call."""
+    table_chunk = TABLE_CHUNK
     with phase("plan_bucket", edges=int(src.shape[0])):
         b = bucket_by_window(
             src, w, table_size=n, dst=dst, n_dst=n, spare_rows=spare_rows
@@ -926,7 +1088,7 @@ def build_window_plan(
     # apply_delta shape-stability headroom (no recompile per epoch).
     max_pad = (b["n_rows"] - b["n_data_rows"]) * ROW
     with phase("plan_seg_tables"):
-        seg_end, seg_first, seg_perm, dst_ptr = _pad_segment_tables(
+        seg_end, seg_first, run_end, dst_ptr = _pad_segment_tables(
             b["seg_end"],
             b["seg_first"],
             b["seg_dst"],
@@ -945,15 +1107,18 @@ def build_window_plan(
         wid=b["wid"],
         local=b["local"],
         weight=b["weight"],
+        run_start=b["run_start"],
         seg_end=seg_end,
         seg_first=seg_first,
-        seg_perm=seg_perm,
+        run_end=run_end,
         dst_ptr=dst_ptr,
         seg_dst=b["seg_dst"],
         row_offset=b["row_offset"],
         fingerprint=graph_fingerprint(n, src, dst, w),
+        table_chunk=table_chunk,
         order=b["order"],
         out_pos=b["out_pos"],
+        align_rows=b["align_rows"],
     )
 
 
@@ -1045,44 +1210,40 @@ def partition_delta(
 
 
 def bridge_partials(
-    hi: jax.Array,
-    lo: jax.Array,
-    seg_end: jax.Array,
-    seg_first: jax.Array,
-    seg_perm: jax.Array,
+    out: jax.Array, run_start: jax.Array, run_end: jax.Array
 ) -> jax.Array:
-    """Reduce the flattened row-local (hi, lo) prefix lanes to
-    dst-sorted per-(row, dst) run partials in a single pass (PERF.md
-    §8): one 2-wide slice gather at the bucket-order run ends (strictly
-    increasing indices — the read streams, and XLA is told so), an
-    adjacent-element shift for each run's start prefix (runs are
-    consecutive within a vreg-row; row-leading runs read an exact
-    zero), and the one host-precomputed dst permutation — the only
-    n_segments-sized random access per iteration."""
-    cum2 = jnp.stack([hi, lo], axis=-1)
-    ends = cum2.at[seg_end].get(indices_are_sorted=True, unique_indices=True)
-    eh, el = ends[:, 0], ends[:, 1]
-    zero = jnp.zeros(1, eh.dtype)
-    prev_h = jnp.where(seg_first, 0.0, jnp.concatenate([zero, eh[:-1]]))
-    prev_l = jnp.where(seg_first, 0.0, jnp.concatenate([zero, el[:-1]]))
-    # Difference hi/lo lanes separately so the hi cancellation stays
-    # exact (Sterbenz), matching rowsum_sorted's row differencing.
-    partial = (eh - prev_h) + (el - prev_l)
-    return partial[seg_perm]
+    """Dst-ordered per-(row, dst) run sums of the kernel's slot
+    products ``out`` (``(n_rows*8, 128)``, bucket order): a segmented
+    inclusive scan along each 1024-slot row — Hillis-Steele, log2(1024)
+    shifted adds that stop at ``run_start`` flags, no gather — leaves
+    each run's sum in its end slot, and one gather at ``run_end``
+    (dst order) reads them out: the only n_segments-sized random access
+    per iteration."""
+    n_rows = out.shape[0] // 8
+    v = out.reshape(n_rows, ROW)
+    f = run_start.reshape(n_rows, ROW)
+    shift = 1
+    while shift < ROW:
+        vs = jnp.pad(v, ((0, 0), (shift, 0)))[:, :-shift]
+        fs = jnp.pad(f, ((0, 0), (shift, 0)))[:, :-shift]
+        v = jnp.where(f, v, v + vs)
+        f = f | fs
+        shift <<= 1
+    return v.reshape(-1)[run_end]
 
 
 def windowed_ct(
     wid: jax.Array,
     local: jax.Array,
     weight: jax.Array,
-    seg_end: jax.Array,
-    seg_first: jax.Array,
-    seg_perm: jax.Array,
+    run_start: jax.Array,
+    run_end: jax.Array,
     dst_ptr: jax.Array,
     t: jax.Array,
     *,
     n_rows: int,
     table_entries: int,
+    table_chunk: int,
     interpret: bool = False,
 ) -> jax.Array:
     """Dense Cᵀt over the plan's slot set — the fused pipeline minus
@@ -1091,35 +1252,34 @@ def windowed_ct(
     the plan arrays cover one shard's rows/runs and the returned vector
     is that shard's partial product (completed by ``lax.psum``):
 
-    1. windowed Pallas gather-multiply from the VMEM-resident score
-       table (bucket order — no random access, PERF.md §6: 7.9 ms at
-       50M edges);
-    2. row-local double-single prefix sum over the (n_rows, 1024) slot
-       matrix (sequential vector work, the ``_ds_cumsum`` machinery);
-    3. ``bridge_partials``: run partials out of the interleaved prefix
-       lanes — one streaming boundary read + one n_segments random
-       permutation (PERF.md §8; was 4 random gathers);
-    4. ``rowsum_sorted`` over the dst-delimited partials → dense Cᵀt.
+    1. windowed Pallas gather-multiply from the score table, read into
+       VMEM in ``table_chunk`` blocks (bucket order — no random access,
+       PERF.md §6: 7.9 ms at 50M edges);
+    2. ``bridge_partials``: each run summed in place by a segmented
+       scan along its row, and the run sums read out in dst order —
+       one n_segments random gather;
+    3. ``rowsum_sorted`` over the dst-delimited run sums → dense Cᵀt.
     """
     n = t.shape[0]
     table = jnp.pad(t, (0, table_entries - n))
     out = gather_windowed(
-        wid, table, local, weight, n_rows=n_rows, interpret=interpret
+        wid,
+        table,
+        local,
+        weight,
+        n_rows=n_rows,
+        table_chunk=table_chunk,
+        interpret=interpret,
     )
-    hi, lo = _ds_cumsum_axis1(out.reshape(n_rows, ROW))
-    partial = bridge_partials(
-        hi.reshape(-1), lo.reshape(-1), seg_end, seg_first, seg_perm
-    )
-    return rowsum_sorted(partial, dst_ptr)
+    return rowsum_sorted(bridge_partials(out, run_start, run_end), dst_ptr)
 
 
 def power_step_windowed(
     wid: jax.Array,
     local: jax.Array,
     weight: jax.Array,
-    seg_end: jax.Array,
-    seg_first: jax.Array,
-    seg_perm: jax.Array,
+    run_start: jax.Array,
+    run_end: jax.Array,
     dst_ptr: jax.Array,
     t: jax.Array,
     p: jax.Array,
@@ -1128,6 +1288,7 @@ def power_step_windowed(
     *,
     n_rows: int,
     table_entries: int,
+    table_chunk: int,
     interpret: bool = False,
 ) -> jax.Array:
     """One damped step of the fused fixed-slot pipeline: ``windowed_ct``
@@ -1136,13 +1297,13 @@ def power_step_windowed(
         wid,
         local,
         weight,
-        seg_end,
-        seg_first,
-        seg_perm,
+        run_start,
+        run_end,
         dst_ptr,
         t,
         n_rows=n_rows,
         table_entries=table_entries,
+        table_chunk=table_chunk,
         interpret=interpret,
     )
     dangling_mass = jnp.sum(t * dangling)
@@ -1153,7 +1314,7 @@ def power_step_windowed(
 @partial(
     jax.jit,
     static_argnames=(
-        "n_rows", "table_entries", "tol", "max_iter", "interpret",
+        "n_rows", "table_entries", "table_chunk", "tol", "max_iter", "interpret",
         "record_residuals",
     ),
     donate_argnames=("t0",),
@@ -1162,9 +1323,8 @@ def converge_windowed(
     wid: jax.Array,
     local: jax.Array,
     weight: jax.Array,
-    seg_end: jax.Array,
-    seg_first: jax.Array,
-    seg_perm: jax.Array,
+    run_start: jax.Array,
+    run_end: jax.Array,
     dst_ptr: jax.Array,
     t0: jax.Array,
     p: jax.Array,
@@ -1172,6 +1332,7 @@ def converge_windowed(
     *,
     n_rows: int,
     table_entries: int,
+    table_chunk: int,
     alpha: jax.Array | float = 0.1,
     tol: float = 1e-6,
     max_iter: int = 50,
@@ -1184,15 +1345,15 @@ def converge_windowed(
     the plan arrays are not — they are reused across epochs.
     ``record_residuals`` appends the device-side residual history to
     the returned tuple (the telemetry path; no host sync, no new
-    gathers — see ``run_power_iteration``)."""
+    gathers — see ``run_power_iteration``).  The layout's static
+    arguments are ``WindowPlan.static_kwargs()``."""
     return run_power_iteration(
         lambda t: power_step_windowed(
             wid,
             local,
             weight,
-            seg_end,
-            seg_first,
-            seg_perm,
+            run_start,
+            run_end,
             dst_ptr,
             t,
             p,
@@ -1200,6 +1361,7 @@ def converge_windowed(
             alpha,
             n_rows=n_rows,
             table_entries=table_entries,
+            table_chunk=table_chunk,
             interpret=interpret,
         ),
         t0,
@@ -1214,10 +1376,10 @@ def converge_windowed(
 # `python -m protocol_tpu.analysis` against the traced jaxpr.
 # ---------------------------------------------------------------------------
 
-#: The tentpole contract of the fused fixed-slot pipeline (PERF.md §8):
-#: exactly two n_segments-sized gathers per step — the streaming
-#: sorted+unique (S, 2) boundary read and ONE random dst permutation —
-#: plus the four (n+1)-sized rowsum pointer reads; no scatter; the
+#: The tentpole contract of the fused fixed-slot pipeline: exactly one
+#: n_segments-sized gather per step — the run sums read out in dst
+#: order at ``run_end`` — plus the four (n+1)-sized rowsum pointer
+#: reads; no scatter; the
 #: windowed Pallas kernel must actually be present (gathers inside its
 #: interpret body are excluded from the counts: on the real chip they
 #: are Mosaic codegen, not XLA gathers).
@@ -1228,14 +1390,12 @@ declare(
         max_scatters=0,
         require_primitives=("pallas_call",),
         gather_budgets=(
-            GatherBudget(
-                dim="n_segments", max_total=2, max_random=1, boundary_sorted=True
-            ),
+            GatherBudget(dim="n_segments", max_total=1, max_random=1),
         ),
         donated_args=("t0",),
         notes=(
-            "fused pipeline: 1 random n_segments pass (dst perm), "
-            "streaming 2-wide boundary read, 4 rowsum pointer reads"
+            "fused pipeline: 1 random n_segments pass (run sums in dst "
+            "order), 4 rowsum pointer reads"
         ),
     )
 )
@@ -1254,29 +1414,31 @@ declare_comm(
 
 #: Peak-HBM budget (graftlint pass 12, PERF.md §19).  Resident: the
 #: plan's vreg-row tables — ``local`` + ``weight`` at (8, 128) slots x
-#: 4 B each plus ``wid`` = 8196 B/row — the bucket-order segment table
-#: (seg_end + seg_first + seg_perm = 9 B/run), the (n+1) dst pointers
-#: and the three f32[N] vectors.  Transient: the Pallas INTERPRET
-#: compile (the analyzer's CPU mesh) re-expresses the Mosaic kernel as
-#: XLA ops, so its VMEM-analog scratch shows up as ~12 row-table
-#: copies in the temp arena (``transient_rows`` = 12 x 8192); on the
-#: real chip that scratch is VMEM, not HBM.  Rows are a plan-layout
-#: dimension (1024 edge slots each) — there is still no per-edge
-#: transient coefficient, and the committed slack is below a 4 B/edge
-#: temporary (pinned by test).
+#: 4 B each, the 1 B ``run_start`` flag per slot, plus ``wid`` = 9220
+#: B/row — the dst-order run ends (``run_end``, 4 B/run), the (n+1)
+#: dst pointers and the three f32[N] vectors.  Transient: the Pallas
+#: INTERPRET compile (the analyzer's CPU mesh) re-expresses the Mosaic
+#: kernel as XLA ops, so its VMEM-analog scratch (on the real chip
+#: VMEM, not HBM), the kernel's per-block chunk ids and the segmented
+#: scan's row-shaped values and flags ride ``transient_rows`` (fitted:
+#: 52161 B/row, about 6.4 row-table copies); the run sums and the
+#: rowsum's prefix lanes ride ``transient_segments``.  Rows are a
+#: plan-layout dimension (1024 edge slots each) — there is still no
+#: per-edge transient coefficient, and the committed slack is below a
+#: 4 B/edge temporary (pinned by test).
 declare_mem(
     MemBudget(
         backend="tpu-windowed",
-        resident_rows=8196.0,
-        resident_segments=9.0,
+        resident_rows=9220.0,
+        resident_segments=4.0,
         resident_n=16.0,
         resident_const=4096.0,
-        transient_rows=98304.0,
+        transient_rows=52161.0,
         transient_n=36.0,
-        transient_segments=9.0,
+        transient_segments=5.0,
         transient_const=4096.0,
         donated_args=("t0",),
         notes="fused pipeline: plan tables resident; interpret-mode "
-        "kernel scratch ~12x the 8 KB row tables",
+        "kernel scratch and scan temporaries ~6.4x the 8 KB row tables",
     )
 )

@@ -115,10 +115,9 @@ class PodWindowPlan:
     """Pod-partitioned fused-pipeline layout.
 
     Field-compatible with ``ShardedWindowPlan`` (``converge_sharded``
-    dispatches any non-CSR problem to the windowed runner, and the
-    runner cache keys on ``(mesh, n, rows_per_shard, table_entries,
-    interpret)`` — identical code paths, multi-process mesh), plus the
-    pod bookkeeping the dryrun and the node durability plane read:
+    dispatches any non-CSR problem to ``windowed_runner``, which reads
+    the same fields — identical code paths, multi-process mesh), plus
+    the pod bookkeeping the dryrun and the node durability plane read:
     which host this is, the peer→host owner map, and how long the
     *local* plan build took (the pod's plan-build critical path is the
     max of these, PERF.md §20).
@@ -133,9 +132,8 @@ class PodWindowPlan:
     wid: jax.Array
     local: jax.Array
     weight: jax.Array
-    seg_end: jax.Array
-    seg_first: jax.Array
-    seg_perm: jax.Array
+    run_start: jax.Array
+    run_end: jax.Array
     dst_ptr: jax.Array
     p: jax.Array
     dangling: jax.Array
@@ -266,9 +264,8 @@ class PodWindowPlan:
             wid=shard1d(parts["wid"]),
             local=shard2d(parts["local"]),
             weight=shard2d(parts["weight"]),
-            seg_end=shard1d(parts["seg_end"].reshape(-1)),
-            seg_first=shard1d(parts["seg_first"].reshape(-1)),
-            seg_perm=shard1d(parts["seg_perm"].reshape(-1)),
+            run_start=shard2d(parts["run_start"]),
+            run_end=shard1d(parts["run_end"].reshape(-1)),
             dst_ptr=shard2d(parts["dst_ptr"]),
             p=jax.device_put(graph.pre_trust_vector(), repl),
             dangling=jax.device_put(dangling.astype(np.float32), repl),

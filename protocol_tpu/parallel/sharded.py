@@ -35,8 +35,10 @@ Two kernels share this recipe (``SHARDED_KERNELS``, selectable as
   never span rows, so the bucket-order segment table splits at the
   same cuts), rebased to shard-local slots and padded to the mesh
   maximum; each shard runs the identical ``windowed_ct`` step —
-  windowed Pallas gather from the replicated score table, row-local
-  prefix sum, single-pass boundary bridge — over its slice, and the
+  windowed Pallas gather from the replicated score table (streamed by
+  chunk: the cuts are grid-block cuts, so a shard's blocks keep the
+  plan's chunk alignment), row-local prefix sum, single-pass boundary
+  bridge — over its slice, and the
   per-shard partial Cᵀt vectors are completed by the same ``lax.psum``
   (dst rows whose runs land on several shards are partially summed on
   each side, exactly like CSR rows straddling a shard cut).
@@ -140,10 +142,10 @@ class ShardedTrustProblem:
 
 
 # Compiled runners keyed by (mesh, n) for the CSR kernel and by
-# (mesh, n, rows_per_shard, table_entries, interpret) for the windowed
-# kernel: jax's jit cache is keyed on function identity, so rebuilding
-# the closures per call would recompile the whole while_loop every
-# epoch.
+# (mesh, n, rows_per_shard, table_entries, interpret, table_chunk) for
+# the windowed kernel: jax's jit cache is keyed on function identity,
+# so rebuilding the closures per call would recompile the whole
+# while_loop every epoch.
 _RUN_CACHE: dict = {}
 
 
@@ -238,13 +240,14 @@ def _partition_plan_arrays(
     local[: plan.n_rows * 8] = plan.local
     weight = np.zeros((total_rows * 8, 128), np.float32)
     weight[: plan.n_rows * 8] = plan.weight
+    run_start = np.zeros((total_rows * 8, 128), bool)
+    run_start[: plan.n_rows * 8] = plan.run_start
 
     # Segment table: bucket order is slot order, so the row cuts give
     # contiguous per-shard slices.  Only the plan's live runs partition
     # — its device-capacity pads are regenerated here as per-shard
     # padding.
     live_end = plan.seg_end[: plan.n_segments]
-    live_first = plan.seg_first[: plan.n_segments]
     shard_of = (live_end // ROW) // rows_per_shard
     counts = np.bincount(shard_of, minlength=n_shards)
     offsets = np.concatenate([[0], np.cumsum(counts)])
@@ -261,21 +264,19 @@ def _partition_plan_arrays(
     # Bucket-order run destinations: stored on the plan since layout v3
     # (the delta-update bookkeeping keeps it current).
     seg_dst = plan.seg_dst
-    seg_end = np.zeros((n_shards, s_max), np.int32)
-    seg_first = np.ones((n_shards, s_max), bool)
-    seg_perm = np.zeros((n_shards, s_max), np.int32)
+    # Pad runs end at each shard's slot 0; they land beyond
+    # dst_ptr[k, n] and are dropped.
+    run_end = np.zeros((n_shards, s_max), np.int32)
     dst_ptr = np.zeros((n_shards, plan.n + 1), np.int32)
     for k in range(n_shards):
         beg, end = int(offsets[k]), int(offsets[k + 1])
         sk = end - beg
-        seg_end[k, :sk] = live_end[beg:end] - k * rows_per_shard * ROW
-        seg_first[k, :sk] = live_first[beg:end]
-        # Pad runs stay a valid permutation so XLA's gather cost is
-        # uniform; they land beyond dst_ptr[k, n] and are dropped.
-        seg_perm[k, sk:] = np.arange(sk, s_max, dtype=np.int32)
         if sk:
-            sperm, dst_counts, _ = _counting_sort(seg_dst[beg:end], plan.n)
-            seg_perm[k, :sk] = sperm
+            shard_end = live_end[beg:end] - np.int32(k * rows_per_shard * ROW)
+            _, dst_counts, ends = _counting_sort(
+                seg_dst[beg:end], plan.n, payload=shard_end
+            )
+            run_end[k, :sk] = ends
             np.cumsum(dst_counts, out=dst_ptr[k, 1:])
     return {
         "rows_per_shard": rows_per_shard,
@@ -283,9 +284,8 @@ def _partition_plan_arrays(
         "wid": wid,
         "local": local,
         "weight": weight,
-        "seg_end": seg_end,
-        "seg_first": seg_first,
-        "seg_perm": seg_perm,
+        "run_start": run_start,
+        "run_end": run_end,
         "dst_ptr": dst_ptr,
     }
 
@@ -299,11 +299,10 @@ class ShardedWindowPlan:
     BLOCK_ROWS-aligned vreg-row boundaries — the same cuts split the
     bucket-order segment table, because runs never span rows — then
     rebases each shard's run ends to shard-local slots, re-sorts each
-    shard's runs by dst (per-shard ``seg_perm``/``dst_ptr``), and pads
-    rows and runs to the mesh maximum.  Pad runs point at slot 0 with
-    the row-leading flag set, and the per-shard ``dst_ptr`` never
-    reaches them, so their garbage partials are computed but never
-    reduced into any destination.  The underlying ``plan`` is kept so
+    shard's runs by dst (per-shard ``run_end``/``dst_ptr``), and pads
+    rows and runs to the mesh maximum.  Pad runs end at slot 0, and the
+    per-shard ``dst_ptr`` never reaches them, so their garbage sums are
+    read but never reduced into any destination.  The underlying ``plan`` is kept so
     the node's checkpoint store persists one format for both the
     single-device and sharded windowed backends.
     """
@@ -317,9 +316,8 @@ class ShardedWindowPlan:
     wid: jax.Array  # (n_shards*rows_per_shard,) int32, sharded
     local: jax.Array  # (n_shards*rows_per_shard*8, 128) int32, sharded
     weight: jax.Array  # (n_shards*rows_per_shard*8, 128) f32, sharded
-    seg_end: jax.Array  # (n_shards*s_max,) int32 shard-local, sharded
-    seg_first: jax.Array  # (n_shards*s_max,) bool, sharded
-    seg_perm: jax.Array  # (n_shards*s_max,) int32 per-shard dst order, sharded
+    run_start: jax.Array  # (n_shards*rows_per_shard*8, 128) bool, sharded
+    run_end: jax.Array  # (n_shards*s_max,) int32 shard-local, dst order, sharded
     dst_ptr: jax.Array  # (n_shards, n+1) int32, sharded on axis 0
     p: jax.Array  # (n,) f32, replicated
     dangling: jax.Array  # (n,) f32, replicated
@@ -380,9 +378,8 @@ class ShardedWindowPlan:
             wid=jax.device_put(parts["wid"], edge),
             local=jax.device_put(parts["local"], edge2d),
             weight=jax.device_put(parts["weight"], edge2d),
-            seg_end=jax.device_put(parts["seg_end"].reshape(-1), edge),
-            seg_first=jax.device_put(parts["seg_first"].reshape(-1), edge),
-            seg_perm=jax.device_put(parts["seg_perm"].reshape(-1), edge),
+            run_start=jax.device_put(parts["run_start"], edge2d),
+            run_end=jax.device_put(parts["run_end"].reshape(-1), edge),
             dst_ptr=jax.device_put(parts["dst_ptr"], edge2d),
             p=jax.device_put(graph.pre_trust_vector(), repl),
             dangling=jax.device_put(dangling.astype(np.float32), repl),
@@ -397,9 +394,14 @@ class ShardedWindowPlan:
 
 
 def _get_windowed_runner(
-    mesh: Mesh, n: int, rows_per_shard: int, table_entries: int, interpret: bool
+    mesh: Mesh,
+    n: int,
+    rows_per_shard: int,
+    table_entries: int,
+    interpret: bool,
+    table_chunk: int,
 ):
-    key = (mesh, n, rows_per_shard, table_entries, interpret)
+    key = (mesh, n, rows_per_shard, table_entries, interpret, table_chunk)
     if key in _RUN_CACHE:
         return _RUN_CACHE[key]
 
@@ -410,8 +412,7 @@ def _get_windowed_runner(
             P(SHARD_AXIS),
             P(SHARD_AXIS, None),
             P(SHARD_AXIS, None),
-            P(SHARD_AXIS),
-            P(SHARD_AXIS),
+            P(SHARD_AXIS, None),
             P(SHARD_AXIS),
             P(SHARD_AXIS, None),
             P(),
@@ -426,8 +427,7 @@ def _get_windowed_runner(
         check_vma=False,
     )
     def step(
-        wid, local, weight, seg_end, seg_first, seg_perm, dst_ptr,
-        t, p, dangling, alpha,
+        wid, local, weight, run_start, run_end, dst_ptr, t, p, dangling, alpha,
     ):
         # The identical fused step as the single-device tpu-windowed
         # backend, over this shard's rows/runs; dst rows whose runs
@@ -436,13 +436,13 @@ def _get_windowed_runner(
             wid,
             local,
             weight,
-            seg_end,
-            seg_first,
-            seg_perm,
+            run_start,
+            run_end,
             dst_ptr[0],
             t,
             n_rows=rows_per_shard,
             table_entries=table_entries,
+            table_chunk=table_chunk,
             interpret=interpret,
         )
         ct = lax.psum(partial_ct, SHARD_AXIS)
@@ -456,15 +456,14 @@ def _get_windowed_runner(
         donate_argnames=("t0",),
     )
     def run(
-        wid, local, weight, seg_end, seg_first, seg_perm, dst_ptr,
+        wid, local, weight, run_start, run_end, dst_ptr,
         t0, p, dangling, alpha, *, max_iter, tol, record_residuals=False,
     ):
         from ..ops.sparse import run_power_iteration
 
         return run_power_iteration(
             lambda t: step(
-                wid, local, weight, seg_end, seg_first, seg_perm, dst_ptr,
-                t, p, dangling, alpha,
+                wid, local, weight, run_start, run_end, dst_ptr, t, p, dangling, alpha,
             ),
             t0,
             tol=tol,
@@ -474,6 +473,33 @@ def _get_windowed_runner(
 
     _RUN_CACHE[key] = run
     return run
+
+
+def windowed_operands(problem) -> tuple:
+    """The plan operands of a windowed problem, in the runner's order
+    (its ``t0``, ``p``, ``dangling`` and ``alpha`` follow)."""
+    return (
+        problem.wid,
+        problem.local,
+        problem.weight,
+        problem.run_start,
+        problem.run_end,
+        problem.dst_ptr,
+    )
+
+
+def windowed_runner(problem):
+    """The compiled runner of a windowed problem (``ShardedWindowPlan``
+    or ``parallel.pod.PodWindowPlan``), its static arguments taken from
+    the problem and its plan in this one place."""
+    return _get_windowed_runner(
+        problem.mesh,
+        problem.n,
+        problem.rows_per_shard,
+        problem.table_entries,
+        problem.interpret,
+        problem.plan.table_chunk,
+    )
 
 
 #: Kernels selectable under ``converge_sharded`` (ManagerConfig /
@@ -530,21 +556,9 @@ def converge_sharded(
     # the same field layout as ShardedWindowPlan over a multi-process
     # mesh and rides the identical runner/cache.
     if not isinstance(problem, ShardedTrustProblem):
-        run = _get_windowed_runner(
-            problem.mesh,
-            problem.n,
-            problem.rows_per_shard,
-            problem.table_entries,
-            problem.interpret,
-        )
+        run = windowed_runner(problem)
         out = run(
-            problem.wid,
-            problem.local,
-            problem.weight,
-            problem.seg_end,
-            problem.seg_first,
-            problem.seg_perm,
-            problem.dst_ptr,
+            *windowed_operands(problem),
             t0_dev,
             problem.p,
             problem.dangling,
@@ -594,8 +608,8 @@ declare(
 )
 
 #: Per-shard fused windowed step under shard_map: the single-device
-#: windowed budget per shard (streaming boundary read, one random
-#: n_segments pass, Pallas kernel present) plus the same single psum.
+#: windowed budget per shard (one random n_segments pass, Pallas kernel
+#: present) plus the same single psum.
 declare(
     KernelBudget(
         backend="tpu-sharded:tpu-windowed",
@@ -603,11 +617,7 @@ declare(
         max_scatters=0,
         psum_count=1,
         require_primitives=("pallas_call",),
-        gather_budgets=(
-            GatherBudget(
-                dim="n_segments", max_total=2, max_random=1, boundary_sorted=True
-            ),
-        ),
+        gather_budgets=(GatherBudget(dim="n_segments", max_total=1, max_random=1),),
         donated_args=("t0",),
         notes="sharded fused pipeline: per-shard windowed_ct + one psum",
     )
@@ -692,14 +702,14 @@ declare_mem(
 declare_mem(
     MemBudget(
         backend="tpu-sharded:tpu-windowed",
-        resident_rows=8196.0,  # per-shard local/weight/wid row tables
-        resident_segments=9.0,  # per-shard seg_end/first/perm
+        resident_rows=9220.0,  # per-shard local/weight/run_start/wid row tables
+        resident_segments=4.0,  # per-shard run_end
         resident_n=16.0,  # replicated vectors + clipped dst_ptr
         resident_const=4096.0,
-        transient_rows=98304.0,  # interpret-mode kernel scratch (12x8KB/row)
+        transient_rows=52161.0,  # as tpu-windowed: interpret scratch, chunk ids, scan
         transient_n=36.0,
-        transient_segments=9.0,
-        transient_const=1118208.0,  # runtime-fixed thunk arena, fitted
+        transient_segments=5.0,
+        transient_const=1122960.0,  # runtime-fixed thunk arena, fitted
         donated_args=("t0",),
         notes="per-shard plan slice resident; interpret scratch rides "
         "rows_per_shard, transient follows N across 4x edge growth",

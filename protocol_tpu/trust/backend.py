@@ -9,13 +9,13 @@ backends along the scaling ladder:
 - ``tpu-sparse``    COO segment-sum SpMV, single device
 - ``tpu-csr``       gather-only CSR/compensated-cumsum SpMV (scatter-free)
 - ``tpu-windowed``  fused fixed-slot pipeline: windowed Pallas gather from
-  a VMEM-resident score table + static bucket→dst bridge (PERF.md §7).
-  Needs a static graph layout (the one-time ``WindowPlan``, reusable
-  across epochs/reboots while the graph fingerprint holds) and a score
-  table that fits VMEM as one window set (≤ 4 MB ⇒ ≤ ~1M peers f32).
+  the score table + static bucket→dst bridge (PERF.md §7).  Needs a
+  static graph layout (the one-time ``WindowPlan``, reusable across
+  epochs/reboots while the graph fingerprint holds).  The table is read
+  into VMEM in chunks of ``TABLE_CHUNK`` entries (4 MB f32), one
+  resident block up to ~1M peers, so N is bounded by HBM, not VMEM.
   Prefer ``tpu-csr`` when the graph churns every epoch (plan cost is
-  then per-epoch), when N exceeds the VMEM table cap, or on toolchains
-  where Mosaic is unavailable.
+  then per-epoch), or on toolchains where Mosaic is unavailable.
 - ``tpu-sharded``   edge-sharded convergence + psum over a device mesh.
   Two per-shard kernels (``parallel/sharded.py::SHARDED_KERNELS``),
   selected with a ``:<kernel>`` suffix on the backend name:
@@ -141,6 +141,13 @@ def _plan_phase(plan_span):
         return TRACER.span(name)
 
     return phase
+
+
+def _plan_layout(plan_span, plan: WindowPlan) -> None:
+    """The table layout of a plan just made, on its ``plan`` span: the
+    chunks the kernel streams the score table in, and the rows padded
+    so that no grid block reads two chunks."""
+    plan_span.attrs.update(table_chunks=plan.table_chunks, align_rows=plan.align_rows)
 
 
 def _initial_vector(t0, p: np.ndarray) -> np.ndarray:
@@ -361,7 +368,7 @@ class CsrJaxBackend(TrustBackend):
 
 class WindowedJaxBackend(TrustBackend):
     """Fused fixed-slot pipeline (PERF.md §7): windowed Pallas
-    gather-multiply from a VMEM-resident score table + a static
+    gather-multiply from the score table, streamed into VMEM by chunk, + a static
     bucket→dst bridge, so the per-iteration device step performs no
     O(E) random gather.
 
@@ -418,6 +425,8 @@ class WindowedJaxBackend(TrustBackend):
                     fingerprint=fp,
                     phase=_plan_phase(span),
                 )
+                if delta is not None:
+                    _plan_layout(span, delta)
             if delta is not None:
                 PLAN_OUTCOMES.inc(outcome="delta")
                 JOURNAL.record(
@@ -429,6 +438,7 @@ class WindowedJaxBackend(TrustBackend):
         )
         with TRACER.span("plan", backend=self.name, reason=reason) as span:
             plan = build_window_plan(g.src, g.dst, w, n=g.n, phase=_plan_phase(span))
+            _plan_layout(span, plan)
         PLAN_REBUILDS.inc()
         PLAN_OUTCOMES.inc(outcome="rebuild")
         JOURNAL.record("plan", outcome="rebuild", backend=self.name, reason=reason)
@@ -463,8 +473,7 @@ class WindowedJaxBackend(TrustBackend):
         with TRACER.span("converge", backend=self.name) as span:
             out = converge_windowed(
                 *args,
-                n_rows=plan.n_rows,
-                table_entries=plan.table_entries,
+                **plan.static_kwargs(),
                 alpha=jax.device_put(np.float32(alpha)),
                 tol=tol,
                 max_iter=max_iter,

@@ -138,7 +138,7 @@ class TestRealTree:
             (gb,) = report["backends"][name]["budget"]["gather_budgets"]
             assert gb["dim"] == "n_segments"
             assert gb["max_random"] == 1
-            assert gb["boundary_sorted"] is True
+            assert gb["max_total"] == 1
 
     def test_ast_pass_scanned_the_tree(self, real_report):
         _, report = real_report

@@ -34,9 +34,9 @@ from protocol_tpu.trust.backend import registered_backends
 DONATED_T0_PARAM = {
     "tpu-sparse": 3,
     "tpu-csr": 3,
-    "tpu-windowed": 7,
+    "tpu-windowed": 6,
     "tpu-sharded:tpu-csr": 3,
-    "tpu-sharded:tpu-windowed": 7,
+    "tpu-sharded:tpu-windowed": 6,
 }
 
 

@@ -199,7 +199,7 @@ class TestApplyDelta:
         expect_first[0] = True
         expect_first[1:] = rows_of[1:] != rows_of[:-1]
         np.testing.assert_array_equal(p2.seg_first[: p2.n_segments], expect_first)
-        assert sorted(p2.seg_perm.tolist()) == list(range(p2.seg_capacity))
+        np.testing.assert_array_equal(np.sort(p2.run_end), p2.seg_end)
         assert int(p2.dst_ptr[-1]) == p2.n_segments
         # ...and the same device product as the rebuilt plan.
         t = rng.random(g.n).astype(np.float32)
@@ -207,10 +207,9 @@ class TestApplyDelta:
         def ct(pl):
             return np.asarray(
                 windowed_ct(
-                    *[jnp.asarray(getattr(pl, k)) for k in pl._CORE],
+                    *pl.device_args(),
                     jnp.asarray(t),
-                    n_rows=pl.n_rows,
-                    table_entries=pl.table_entries,
+                    **pl.static_kwargs(),
                     interpret=True,
                 )
             )
@@ -230,7 +229,7 @@ class TestApplyDelta:
             p = p.replace_rows(rows, ns, nd, nw, fingerprint=f"fp{i}")
             cur = TrustGraph(cur.n, full[0], full[1], full[2], cur.pre_trusted)
             cw = full[2]
-            for k in WindowPlan._CORE:
+            for k in WindowPlan._DEVICE:
                 assert getattr(p, k).shape == getattr(plan, k).shape, (i, k)
         assert len(p.lineage) == 5
         assert p.lineage[0] == plan.fingerprint
@@ -393,6 +392,7 @@ def _old_apply_delta(plan, inserts, deletes, *, n=None, fingerprint, phase=None)
     wid = plan.wid.copy()
     local = plan.local.reshape(-1).copy()
     weight = plan.weight.reshape(-1).copy()
+    run_start = plan.run_start.reshape(-1).copy()
     n_data_rows = plan.n_data_rows
     row_window = wid.astype(np.int64).copy()
     row_window[plan.n_data_rows :] = -1
@@ -431,6 +431,7 @@ def _old_apply_delta(plan, inserts, deletes, *, n=None, fingerprint, phase=None)
             slots_w = (rows_w[:, None] * ROW + np.arange(ROW)[None, :]).reshape(-1)
             local[slots_w] = 0
             weight[slots_w] = 0.0
+            run_start[slots_w] = slots_w % ROW == 0
         if count > rows_w.size * ROW:
             extra = -(-(count - rows_w.size * ROW) // ROW)
             grown = np.arange(n_data_rows, n_data_rows + extra, dtype=np.int64)
@@ -451,6 +452,7 @@ def _old_apply_delta(plan, inserts, deletes, *, n=None, fingerprint, phase=None)
         endm = np.empty(count, bool)
         endm[-1] = True
         endm[:-1] = brk[1:]
+        run_start[slots[brk]] = True
         new_end.append(slots[endm])
         new_first.append(lead[brk])
         new_dst.append(d[brk])
@@ -464,7 +466,7 @@ def _old_apply_delta(plan, inserts, deletes, *, n=None, fingerprint, phase=None)
     capacity = plan.seg_capacity
     if s_new > capacity or capacity - s_new > max_pad:
         capacity = gw._segment_capacity(s_new, max_pad)
-    seg_end, seg_first, seg_perm, dst_ptr = gw._pad_segment_tables(
+    seg_end, seg_first, run_end, dst_ptr = gw._pad_segment_tables(
         live_end,
         live_first,
         seg_dst,
@@ -483,13 +485,15 @@ def _old_apply_delta(plan, inserts, deletes, *, n=None, fingerprint, phase=None)
         wid=wid,
         local=local.reshape(plan.local.shape),
         weight=weight.reshape(plan.weight.shape),
-        seg_end=seg_end.astype(np.int32),
+        run_start=run_start.reshape(plan.run_start.shape),
+        seg_end=seg_end,
         seg_first=seg_first,
-        seg_perm=seg_perm.astype(np.int32, copy=False),
-        dst_ptr=dst_ptr.astype(np.int32),
+        run_end=run_end,
+        dst_ptr=dst_ptr,
         seg_dst=seg_dst,
         row_offset=row_offset,
         fingerprint=fingerprint,
+        table_chunk=plan.table_chunk,
         lineage=(plan.lineage + (plan.fingerprint,))[-gw.LINEAGE_DEPTH :],
     )
 

@@ -92,7 +92,7 @@ def test_converge_closes_on_its_result_before_the_monitors(epochs, which):
 def test_upload_bytes_are_the_operands(epochs):
     _, roots = epochs
     for root, _, plan, _ in roots:
-        core = sum(getattr(plan, k).nbytes for k in WindowPlan._CORE)
+        core = sum(getattr(plan, k).nbytes for k in WindowPlan._DEVICE)
         # start vector, pre-trust and dangling: float32 over the peers
         assert root.find("upload").attrs["bytes"] == core + 3 * 4 * plan.n
 

@@ -53,8 +53,8 @@ import perf_sentinel
 pytestmark = pytest.mark.allow_transfer
 
 SHARDED_ARRAYS = (
-    "wid", "local", "weight", "seg_end", "seg_first", "seg_perm",
-    "dst_ptr", "p", "dangling",
+    "wid", "local", "weight", "run_start", "run_end", "dst_ptr", "p",
+    "dangling",
 )
 
 

@@ -254,6 +254,7 @@ class TestWindowedGather:
                 jnp.asarray(b["local"]),
                 jnp.asarray(b["weight"]),
                 n_rows=b["n_rows"],
+                table_chunk=b["table_chunk"],
                 interpret=True,
             )
         ).reshape(-1)

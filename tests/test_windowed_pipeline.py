@@ -28,6 +28,7 @@ from protocol_tpu.ops.gather_window import (
     ROW,
     WINDOW,
     WindowPlan,
+    bridge_partials,
     bucket_by_window,
     build_window_plan,
     graph_fingerprint,
@@ -146,11 +147,11 @@ class TestBucketByWindowProperties:
             bucket_by_window(src, w, table_size=500, dst=dst)
 
     def test_segment_plan_reduces_exactly(self):
-        """Adjacent-run differencing over the row-local prefix sum —
-        the device's exact recipe (``bridge_partials``) emulated in
-        f64 — reproduces the direct per-dst sum of w·x[src] on random
-        graphs: the bucket-order boundary table plus the one dst
-        permutation is a faithful reduction plan."""
+        """Run sums over the slots the run-start flags delimit, read at
+        the dst-order run ends — the device's recipe
+        (``bridge_partials``) emulated in f64 — reproduce the direct
+        per-dst sum of w·x[src] on random graphs: the flags plus the
+        one dst-order read are a faithful reduction plan."""
         n, src, dst, w = self._random_edges(10)
         b = bucket_by_window(src, w, table_size=n, dst=dst, n_dst=n)
         rng = np.random.default_rng(11)
@@ -158,12 +159,10 @@ class TestBucketByWindowProperties:
         contrib = np.zeros(b["n_rows"] * WINDOW, np.float64)
         contrib[b["out_pos"]] = (w[b["order"]].astype(np.float64)
                                  * x[src[b["order"]]].astype(np.float64))
-        # Row-local inclusive prefix, exactly like the device step.
-        rowcum = np.cumsum(contrib.reshape(b["n_rows"], ROW), axis=1).reshape(-1)
-        seg_end = b["seg_end"].astype(np.int64)
-        ends = rowcum[seg_end]
-        prev = np.where(b["seg_first"], 0.0, np.concatenate([[0.0], ends[:-1]]))
-        partial = (ends - prev)[b["seg_perm"]]
+        # Each slot's run sum: the segmented scan's value at a run end.
+        run_id = np.cumsum(b["run_start"].reshape(-1)) - 1
+        run_sum = np.bincount(run_id, weights=contrib)[run_id]
+        partial = run_sum[b["run_end"]]
         ptr = b["dst_ptr"].astype(np.int64)
         per_dst = np.add.reduceat(
             np.concatenate([partial, [0.0]]), np.minimum(ptr[:-1], len(partial))
@@ -174,10 +173,9 @@ class TestBucketByWindowProperties:
         np.testing.assert_allclose(per_dst, expect, rtol=1e-5, atol=1e-12)
 
     def test_segment_plan_layout_invariants(self):
-        """Bucket-order invariants the single-pass bridge relies on:
-        strictly increasing run ends (the boundary read streams), a
-        row-leading flag exactly at vreg-row changes (so the shifted
-        differencing never crosses a row), and a true permutation."""
+        """Layout invariants the bridge relies on: strictly increasing
+        bucket-order run ends, a row-leading flag exactly at vreg-row
+        changes, and ``run_end`` the same ends in dst order."""
         n, src, dst, w = self._random_edges(12)
         b = bucket_by_window(src, w, table_size=n, dst=dst, n_dst=n)
         seg_end, seg_first = b["seg_end"], b["seg_first"]
@@ -187,8 +185,37 @@ class TestBucketByWindowProperties:
         expect_first[0] = True
         expect_first[1:] = rows[1:] != rows[:-1]
         np.testing.assert_array_equal(seg_first, expect_first)
-        assert sorted(b["seg_perm"].tolist()) == list(range(b["n_segments"]))
+        np.testing.assert_array_equal(np.sort(b["run_end"]), seg_end)
+        run_dst = b["seg_dst"][np.searchsorted(seg_end, b["run_end"])]
+        assert (np.diff(run_dst) >= 0).all()
         assert int(b["dst_ptr"][-1]) == b["n_segments"]
+
+
+    @pytest.mark.parametrize("mean_run", [1.2, 5.0, 300.0])
+    def test_bridge_partials_sums_each_run(self, mean_run):
+        """The device's segmented scan sums exactly the slots of each
+        run — runs of one slot, short runs, and runs crossing the
+        scan's every shift up to a whole row — and reads the sums out
+        in the order ``run_end`` gives."""
+        rng = np.random.default_rng(int(mean_run * 10))
+        n_rows = 3
+        slots = n_rows * ROW
+        out = rng.random(slots).astype(np.float32)
+        lengths = rng.geometric(1.0 / mean_run, size=slots)
+        starts = np.unique(np.concatenate([np.cumsum(lengths) % slots, [0]]))
+        flags = np.zeros(slots, bool)
+        flags[starts] = True
+        flags[::ROW] = True
+        ends = np.flatnonzero(np.append(flags[1:], True))
+        read = rng.permutation(ends).astype(np.int32)
+        got = jax.jit(bridge_partials)(
+            jnp.asarray(out.reshape(n_rows * 8, 128)),
+            jnp.asarray(flags.reshape(n_rows * 8, 128)),
+            jnp.asarray(read),
+        )
+        run_id = np.cumsum(flags) - 1
+        run_sum = np.bincount(run_id, weights=out.astype(np.float64))[run_id]
+        np.testing.assert_allclose(np.asarray(got), run_sum[read], rtol=2e-6)
 
 
 class TestSinglePassBoundary:
@@ -209,8 +236,7 @@ class TestSinglePassBoundary:
         jaxpr = jax.make_jaxpr(
             lambda *a: power_step_windowed(
                 *a,
-                n_rows=plan.n_rows,
-                table_entries=plan.table_entries,
+                **plan.static_kwargs(),
                 interpret=True,
             )
         )(*args)
@@ -225,15 +251,11 @@ class TestSinglePassBoundary:
         random_seg = [
             e for e in seg_sized if not e.params.get("indices_are_sorted")
         ]
-        # Exactly two n_segments-sized gathers: the 2-wide boundary
-        # read, declared sorted+unique (bucket-order ends are strictly
-        # increasing — it streams), and the single dst permutation —
-        # the one random pass the tentpole allows.
-        assert len(seg_sized) == 2
+        # Exactly one n_segments-sized gather: the run sums read out in
+        # dst order — the one random pass the tentpole allows.
+        assert len(seg_sized) == 1
         assert len(random_seg) == 1
-        (boundary,) = [e for e in seg_sized if e.params.get("indices_are_sorted")]
-        assert boundary.outvars[0].aval.shape == (s, 2)  # hi/lo interleaved
-        assert boundary.params.get("unique_indices")
+        assert seg_sized[0].outvars[0].aval.shape == (s,)
 
 
 class TestWindowPlanCheckpoint:
@@ -376,9 +398,9 @@ class TestShardedWindowedBackend:
         straddling = ((per_dst_per_shard > 0).sum(axis=0) >= 2).sum()
         assert straddling > 0
         # Every shard's rebased run ends stay inside its row slice.
-        seg_end = np.asarray(swp.seg_end).reshape(len(runs_per_shard), -1)
-        assert seg_end.min() >= 0
-        assert seg_end.max() < swp.rows_per_shard * ROW
+        run_end = np.asarray(swp.run_end).reshape(len(runs_per_shard), -1)
+        assert run_end.min() >= 0
+        assert run_end.max() < swp.rows_per_shard * ROW
 
     def test_explicit_small_mesh(self):
         from protocol_tpu.parallel.mesh import default_mesh

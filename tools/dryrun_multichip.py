@@ -83,8 +83,9 @@ def _body(n_devices: int, n_peers: int, n_edges: int, iters: int) -> dict:
         ShardedTrustProblem,
         ShardedWindowPlan,
         _get_runner,
-        _get_windowed_runner,
         converge_sharded,
+        windowed_operands,
+        windowed_runner,
     )
 
     mesh = default_mesh(n_devices)
@@ -114,14 +115,11 @@ def _body(n_devices: int, n_peers: int, n_edges: int, iters: int) -> dict:
             ),
         )
     }
-    win_run = _get_windowed_runner(
-        mesh, swp.n, swp.rows_per_shard, swp.table_entries, swp.interpret
-    )
+    win_run = windowed_runner(swp)
     comm["tpu-sharded:tpu-windowed"] = _scrape(
         "tpu-sharded:tpu-windowed",
         win_run.lower(
-            swp.wid, swp.local, swp.weight, swp.seg_end, swp.seg_first,
-            swp.seg_perm, swp.dst_ptr, swp.t0(), swp.p, swp.dangling,
+            *windowed_operands(swp), swp.t0(), swp.p, swp.dangling,
             alpha, max_iter=iters, tol=1e-6,
         ),
     )

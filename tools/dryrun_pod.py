@@ -183,17 +183,12 @@ def _scrape(podplan, n_edges: int):
         pod_budget_view,
     )
     from protocol_tpu.parallel.mesh import SHARD_AXIS
-    from protocol_tpu.parallel.sharded import _get_windowed_runner
+    from protocol_tpu.parallel.sharded import windowed_operands, windowed_runner
 
-    run = _get_windowed_runner(
-        podplan.mesh, podplan.n, podplan.rows_per_shard,
-        podplan.table_entries, podplan.interpret,
-    )
+    run = windowed_runner(podplan)
     alpha = jax.device_put(np.float32(0.1), NamedSharding(podplan.mesh, P()))
     plan_args = (
-        podplan.wid, podplan.local, podplan.weight, podplan.seg_end,
-        podplan.seg_first, podplan.seg_perm, podplan.dst_ptr,
-        podplan.t0(), podplan.p, podplan.dangling, alpha,
+        *windowed_operands(podplan), podplan.t0(), podplan.p, podplan.dangling, alpha,
     )
     kw = dict(max_iter=4, tol=1e-6)
     comp = run.lower(*plan_args, **kw).compile()
@@ -210,8 +205,8 @@ def _scrape(podplan, n_edges: int):
         },
         module_text=comp.as_text(),
         arg_names=(
-            "wid", "local", "weight", "seg_end", "seg_first", "seg_perm",
-            "dst_ptr", "t0", "p", "dangling", "alpha",
+            "wid", "local", "weight", "run_start", "run_end", "dst_ptr",
+            "t0", "p", "dangling", "alpha",
         ),
         jaxpr_psums=_jaxpr_psums(jaxpr),
         mem=_mem_stats(comp),
