@@ -618,7 +618,7 @@ class Manager:
         "start from the pre-trust vector".  Runs under a ``warm_seed``
         span (tagged with ``epoch`` where the caller knows it)."""
         attrs = {"peers": len(id_order)} | ({} if epoch is None else {"epoch": epoch})
-        with TRACER.span("warm_seed", **attrs):
+        with TRACER.span("warm_seed", **attrs) as span:
             # Scores and their peer-hash column publish together in
             # converge_prepared (pipeline device thread); read them as a
             # matched pair or the warm seed maps scores onto wrong peers.
@@ -626,35 +626,24 @@ class Manager:
                 scores, hashes = self.last_scores, self.last_peer_hashes
             if scores is None or hashes is None or not len(hashes) or not len(scores):
                 return None
-            # Vectorized remap (PERF.md §20): the per-peer dict walk cost
-            # ~7 s of pure Python at the pod's 10M-peer scale; folding the
-            # Poseidon hashes to 64-bit keys and matching via one sorted
-            # searchsorted pass is ~30x faster.  A low-64-bit collision
-            # (≈ n²/2⁶⁴ odds) can only misplace one seed entry — the seed
-            # is renormalized and the fixed point is start-independent, so
-            # the failure mode is a marginally longer converge, never a
-            # wrong score.
-            from ..parallel.partition import keys_from_hashes
+            # Exact alignment by peer hash: an unchanged or extended
+            # column takes the prefix path; any other, the verified
+            # search (see align_by_hash).
+            from ..parallel.partition import align_by_hash
 
-            prev_keys = keys_from_hashes(hashes)
-            new_keys = keys_from_hashes(id_order)
-            order = np.argsort(prev_keys, kind="stable")
-            sorted_prev = prev_keys[order]
-            pos = np.searchsorted(sorted_prev, new_keys)
-            pos = np.minimum(pos, max(len(sorted_prev) - 1, 0))
-            hit = (
-                (sorted_prev[pos] == new_keys)
-                if len(sorted_prev)
-                else np.zeros(len(new_keys), bool)
-            )
-            j = order[pos]
-            hit &= j < len(scores)
-            prev_scores = np.maximum(np.asarray(scores, np.float64), 0.0)
-            t0 = np.where(hit, prev_scores[np.minimum(j, len(scores) - 1)], 0.0)
+            idx, path = align_by_hash(hashes, id_order)
+            span.attrs["align"] = path
+            obs_metrics.PEER_ALIGN.inc(site="warm_seed", path=path)
+            # A joined peer (-1), or one past the scores, starts at 0.
+            scores = np.asarray(scores)
+            t0 = np.take(scores, idx, mode="clip").astype(np.float64, copy=False)
+            t0[(idx < 0) | (idx >= len(scores))] = 0.0
+            np.maximum(t0, 0.0, out=t0)
             total = t0.sum()
-            if not hit.any() or not np.isfinite(total) or total <= 0:
+            if not np.isfinite(total) or total <= 0:
                 return None
-            return t0 / total
+            t0 /= total
+            return t0
 
     @contextmanager
     def _plan_cache(self, backend, delta_rows: np.ndarray | None = None):
@@ -854,12 +843,13 @@ class Manager:
         # epoch (aligned by peer hash), top movers, and the stall
         # detector over the residual trajectory — the /scores/drift
         # surface.
-        with TRACER.span("drift"):
+        with TRACER.span("drift") as span:
             DRIFT.observe(
                 prepared.epoch.number,
                 prepared.id_order,
                 result.scores,
                 result.residuals,
+                span=span,
             )
         # The epoch's lineage cohort has a converged (not yet proven)
         # fixed point; the timeline gets the converge fragment.

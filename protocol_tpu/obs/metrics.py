@@ -365,6 +365,13 @@ PLAN_OUTCOMES = METRICS.counter(
     "host construction)",
     labelnames=("outcome",),
 )
+PEER_ALIGN = METRICS.counter(
+    "eigentrust_peer_align_total",
+    "Alignments of the previous epoch's peer column to the new one by "
+    "site (warm_seed, drift) and path: prefix (the column unchanged or "
+    "extended) or search (a verified hash search)",
+    labelnames=("site", "path"),
+)
 EPOCH_TICKS_COALESCED = METRICS.counter(
     "eigentrust_epoch_ticks_coalesced_total",
     "Epoch ticks superseded by a newer one while waiting in the "
@@ -718,6 +725,7 @@ __all__ = [
     "PLAN_REBUILDS",
     "PLAN_REUSES",
     "PLAN_OUTCOMES",
+    "PEER_ALIGN",
     "EPOCH_TICKS_COALESCED",
     "PIPELINE_QUEUE_DEPTH",
     "WARM_START_APPLIED",

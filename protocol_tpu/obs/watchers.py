@@ -265,10 +265,19 @@ class ScoreDriftMonitor:
         peer_hashes: Iterable[int],
         scores,
         residuals=None,
+        span=None,
     ) -> dict[str, Any]:
-        """Record one landed epoch; returns the drift summary dict."""
-        hashes = [int(h) for h in peer_hashes]
-        vals = [float(s) for s in scores]
+        """Record one landed epoch; returns the drift summary dict.
+        ``span``, where given, gets the alignment path as ``align``."""
+        import numpy as np
+
+        from ..parallel.partition import align_by_hash
+
+        # The hash column is copied (a caller may hand the same list,
+        # extended, each epoch); an epoch's scores are never written
+        # after they land, so they are kept as given.
+        hashes = list(peer_hashes)
+        vals = np.asarray(scores, np.float64)
         summary: dict[str, Any] = {
             "epoch": int(epoch),
             "peers": len(hashes),
@@ -282,29 +291,40 @@ class ScoreDriftMonitor:
             prev = self._prev
             self._prev = (hashes, vals)
         if prev is not None:
-            prev_by_hash = dict(zip(prev[0], prev[1]))
-            cur_set = set(hashes)
-            deltas: list[tuple[float, int, float]] = []
-            l1 = 0.0
-            linf = 0.0
-            for h, v in zip(hashes, vals):
-                old = prev_by_hash.get(h)
-                if old is None:
-                    summary["joined"] += 1
-                    continue
-                d = v - old
-                l1 += abs(d)
-                if abs(d) > linf:
-                    linf = abs(d)
-                deltas.append((abs(d), h, d))
-            summary["departed"] = sum(1 for h in prev[0] if h not in cur_set)
+            prev_hashes, prev_vals = prev
+            idx, path = align_by_hash(prev_hashes, hashes)
+            _metrics.PEER_ALIGN.inc(site="drift", path=path)
+            if span is not None:
+                span.attrs["align"] = path
+            # |d| by new position; a joined peer moves nothing.
+            gone = idx < 0
+            if len(prev_vals):
+                absd = np.take(prev_vals, idx, mode="clip")
+            else:
+                absd = np.zeros(len(hashes))
+            np.subtract(vals, absd, out=absd)
+            np.abs(absd, out=absd)
+            absd[gone] = 0.0
+            l1 = float(absd.sum())
+            absd[np.isnan(absd)] = 0.0  # a NaN score moves no peer
+            linf = float(absd.max(initial=0.0))
+            matched = len(hashes) - int(gone.sum())
+            summary["joined"] = len(hashes) - matched
+            summary["departed"] = len(prev_hashes) - matched
             summary["l1"] = l1
             summary["linf"] = linf
-            deltas.sort(reverse=True)
+            # Every nonzero |d| at or above the top_k-th largest (ties
+            # included), so sorting their (|d|, hash, d) tuples orders
+            # the top movers as sorting all of them would.
+            k = min(self.top_k, absd.size)
+            kth = np.partition(absd, absd.size - k)[absd.size - k] if k > 0 else np.inf
+            movers = []
+            for p in np.flatnonzero((absd >= kth) & (absd > 0.0)):
+                d = float(vals[p] - prev_vals[idx[p]])
+                movers.append((abs(d), hashes[p], d))
+            movers.sort(reverse=True)
             summary["top_movers"] = [
-                {"peer_hash": hex(h), "delta": d}
-                for absd, h, d in deltas[: self.top_k]
-                if absd > 0.0
+                {"peer_hash": hex(h), "delta": d} for _, h, d in movers[: self.top_k]
             ]
             _metrics.SCORE_DRIFT_L1.set(l1)
             _metrics.SCORE_DRIFT_LINF.set(linf)

@@ -55,7 +55,57 @@ def keys_from_hashes(hashes) -> np.ndarray:
     """Fold an iterable of Python-int peer hashes (arbitrary width —
     the manager keys peers by Poseidon field elements) into the
     partition's uint64 key domain."""
-    return np.asarray([int(h) & MASK64 for h in hashes], dtype=_U64)
+    return np.fromiter(map(MASK64.__and__, map(int, hashes)), dtype=_U64)
+
+
+def align_by_hash(prev_hashes, new_hashes) -> tuple[np.ndarray, str]:
+    """Align the previous epoch's peer column to the new one by peer
+    hash: ``(idx, path)``, ``idx`` an ``int64[len(new)]`` of each new
+    peer's position in ``prev``, or -1 where it is absent.  Exact on
+    both paths.
+
+    - ``"prefix"``: ``new`` is ``prev`` or extends it, so ``idx`` is
+      ``arange(len(prev))`` padded with -1.  One C-level list
+      comparison, no per-peer Python.  A column that only grows takes
+      it, as the benchmark's does;
+    - ``"search"``: any other column.  The node's own takes it when a
+      re-attesting sender drops a peer first seen through its old row:
+      ``Manager.build_graph`` rebuilds first-seen order each epoch, so
+      that peer moves or leaves.  The 64-bit key fold, a stable argsort and
+      a searchsorted of the sorted new keys; then every hit is checked
+      against the full hash, so a low-64-bit collision reads as absent,
+      never as a match (a colliding key's other entries are scanned
+      for the true one)."""
+    prev = prev_hashes if isinstance(prev_hashes, list) else list(prev_hashes)
+    new = new_hashes if isinstance(new_hashes, list) else list(new_hashes)
+    n_prev = len(prev)
+    if new is prev or new[:n_prev] == prev:
+        idx = np.arange(len(new), dtype=np.int64)
+        idx[n_prev:] = -1
+        return idx, "prefix"
+    idx = np.full(len(new), -1, np.int64)
+    if not n_prev or not new:
+        return idx, "search"
+    prev_keys, new_keys = keys_from_hashes(prev), keys_from_hashes(new)
+    order = np.argsort(prev_keys, kind="stable")
+    sorted_keys = prev_keys[order]
+    # Sorted needles: each binary search starts where the last ended.
+    by_key = np.argsort(new_keys)
+    pos = np.empty(len(new), np.int64)
+    pos[by_key] = np.searchsorted(sorted_keys, new_keys[by_key])
+    np.minimum(pos, n_prev - 1, out=pos)
+    cand = np.flatnonzero(sorted_keys[pos] == new_keys)
+    j = order[pos[cand]]
+    same = np.array(prev, dtype=object)[j] == np.array(new, dtype=object)[cand]
+    idx[cand[same]] = j[same]
+    for c in cand[~same]:
+        p = pos[c] + 1
+        while p < n_prev and sorted_keys[p] == new_keys[c]:
+            if prev[order[p]] == new[c]:
+                idx[c] = order[p]
+                break
+            p += 1
+    return idx, "search"
 
 
 @dataclass(frozen=True)
@@ -124,6 +174,7 @@ def remap_fraction(before: np.ndarray, after: np.ndarray) -> float:
 __all__ = [
     "HostPartition",
     "MASK64",
+    "align_by_hash",
     "keys_from_hashes",
     "mix64",
     "remap_fraction",

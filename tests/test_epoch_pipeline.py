@@ -23,9 +23,10 @@ import pytest
 from protocol_tpu.models.graphs import erdos_renyi, scale_free
 from protocol_tpu.node.checkpoint import CheckpointStore
 from protocol_tpu.node.epoch import Epoch
-from protocol_tpu.node.manager import Manager, ManagerConfig
+from protocol_tpu.node.manager import Manager, ManagerConfig, PreparedEpoch
 from protocol_tpu.node.pipeline import EpochPipeline
-from protocol_tpu.obs.metrics import PLAN_OUTCOMES
+from protocol_tpu.obs.metrics import PEER_ALIGN, PLAN_OUTCOMES
+from protocol_tpu.obs.watchers import DRIFT
 from protocol_tpu.ops import gather_window as gw
 from protocol_tpu.ops.gather_window import (
     ROW,
@@ -710,6 +711,115 @@ class TestManagerWarmState:
         prep = m2.prepare_epoch(Epoch(2))
         assert prep.t0 is not None
         np.testing.assert_allclose(prep.t0, r1.scores, atol=1e-9)
+
+
+class TestPeerAlignCounter:
+    """``eigentrust_peer_align_total``: each warm epoch aligns the
+    previous peer column once at the warm seed and once in the drift
+    monitor, by the prefix path while ids only grow."""
+
+    SITES = [(site, path) for site in ("warm_seed", "drift") for path in ("prefix", "search")]
+
+    def _counts(self):
+        return {(s, p): PEER_ALIGN.value(site=s, path=p) for s, p in self.SITES}
+
+    def _since(self, before):
+        now = self._counts()
+        return {k: now[k] - before[k] for k in self.SITES}
+
+    def _only(self, path, n):
+        return {(s, p): n if p == path else 0 for s, p in self.SITES}
+
+    def test_benchmark_shaped_loop_counts_prefix_every_warm_epoch(self):
+        DRIFT.reset()
+        g = erdos_renyi(400, avg_degree=4.0, seed=5)
+        m = Manager(ManagerConfig(backend="tpu-csr", prover="commitment"))
+        ids = list(range(g.n))
+        before = self._counts()
+        for number in range(1, 5):
+            t0 = m._warm_t0(ids)
+            assert (t0 is None) == (number == 1)
+            prepared = PreparedEpoch(Epoch(number), g, ids, t0, None, set())
+            m.converge_prepared(prepared, alpha=0.1)
+        assert self._since(before) == self._only("prefix", 3)
+
+    def test_growing_attestation_set_counts_prefix(self):
+        import random
+
+        from protocol_tpu.crypto.eddsa import SecretKey
+        from tests.test_node import make_attestation
+
+        DRIFT.reset()
+        m = Manager(ManagerConfig(backend="tpu-csr", prover="commitment"))
+        m.generate_initial_attestations()
+        m.converge_epoch(Epoch(1), alpha=0.1)
+        att = make_attestation(sender_idx=0)
+        att.pk = SecretKey.random(random.Random(28)).public()  # a sender new to the graph
+        m.restore_attestation(att)
+        before = self._counts()
+        prepared = m.prepare_epoch(Epoch(2))
+        assert prepared.id_order[:-1] == m.last_peer_hashes
+        m.converge_prepared(prepared, alpha=0.1)
+        assert self._since(before) == self._only("prefix", 1)
+        assert DRIFT.last()["joined"] == 1 and DRIFT.last()["departed"] == 0
+
+    def test_reattestation_reorders_the_node_column_and_counts_search(self):
+        """``build_graph`` rebuilds first-seen order: a sender that drops
+        the peer first seen through its row moves that peer, so the
+        node's column is no prefix and both sites take the search,
+        landing each surviving peer's score on that peer."""
+        import dataclasses
+        import random
+
+        from protocol_tpu.crypto.eddsa import SecretKey
+        from tests.test_node import make_attestation
+
+        rng = random.Random(28)
+        a, b, x, z = (SecretKey.random(rng).public() for _ in range(4))
+        base = make_attestation(sender_idx=0)
+
+        def attest(sender, *targets):
+            return dataclasses.replace(
+                base, pk=sender, neighbours=list(targets), scores=[50] * len(targets)
+            )
+
+        DRIFT.reset()
+        m = Manager(ManagerConfig(backend="tpu-csr", prover="commitment"))
+        m.generate_initial_attestations()
+        m.restore_attestation(attest(a, x))  # x first seen through a's row
+        m.restore_attestation(attest(b, x, a))
+        m.converge_epoch(Epoch(1), alpha=0.1)
+        old = list(m.last_peer_hashes)
+        old_scores = np.asarray(m.last_scores, np.float64)
+        m.restore_attestation(attest(a, z))  # a keeps its place, drops x
+        before = self._counts()
+        prepared = m.prepare_epoch(Epoch(2))
+        new = prepared.id_order
+        assert new[: len(old)] != old and sorted(set(old) - set(new)) == []
+        m.converge_prepared(prepared, alpha=0.1)
+        assert self._since(before) == self._only("search", 1)
+        want = np.zeros(len(new))
+        for i, h in enumerate(new):
+            if h in old:
+                want[i] = max(old_scores[old.index(h)], 0.0)
+        np.testing.assert_array_equal(prepared.t0, want / want.sum())
+        assert DRIFT.last()["joined"] == 1 and DRIFT.last()["departed"] == 0
+
+    def test_shuffled_column_counts_search(self):
+        import threading
+        import types
+
+        DRIFT.reset()
+        ids = list(range(50))
+        shuffled = [int(h) for h in np.random.default_rng(28).permutation(50)]
+        state = types.SimpleNamespace(
+            _state_lock=threading.Lock(), last_scores=np.ones(50), last_peer_hashes=ids
+        )
+        before = self._counts()
+        assert Manager._warm_t0(state, shuffled) is not None
+        DRIFT.observe(1, ids, np.ones(50))
+        DRIFT.observe(2, shuffled, np.ones(50))
+        assert self._since(before) == self._only("search", 1)
 
 
 class TestEpochPipeline:

@@ -97,6 +97,11 @@ def test_upload_bytes_are_the_operands(epochs):
         assert root.find("upload").attrs["bytes"] == core + 3 * 4 * plan.n
 
 
+def test_drift_span_names_the_alignment_path(epochs):
+    _, [_, (root, _, _, _)] = epochs
+    assert root.find("drift").attrs["align"] == "prefix"  # the same id list
+
+
 def test_warm_t0_opens_warm_seed(epochs):
     m, _ = epochs
     ids = list(range(2048))
@@ -104,6 +109,7 @@ def test_warm_t0_opens_warm_seed(epochs):
         t0 = m._warm_t0(ids)
     (span,) = root.children
     assert span.name == "warm_seed" and span.attrs["peers"] == 2048
+    assert span.attrs["align"] == "prefix"  # an equal column
     assert "epoch" not in span.attrs
     assert t0 is not None and t0.shape == (2048,)
 

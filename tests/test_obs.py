@@ -8,6 +8,7 @@ import json
 import logging
 import re
 import threading
+import zlib
 
 import numpy as np
 import pytest
@@ -847,6 +848,142 @@ class TestScoreDriftMonitor:
         bad = mon.observe(2, [1], [1.0], residuals=[0.5, 0.6, 0.4, 0.55])
         assert bad["residual_increases"] == 2 and bad["stalled"]
         assert obs_metrics.RESIDUAL_STALLS.value() == before + 1
+
+
+    @pytest.mark.parametrize(
+        "case",
+        [
+            "same_object",
+            "equal_copy",
+            "append_only",
+            "reordered",
+            "ties_at_top_k",
+            "few_movers",
+            "poseidon_collision",
+            "wide_column",
+        ],
+    )
+    def test_matches_per_peer_walk(self, case):
+        """The vectorized drift equals the per-peer walk it replaced,
+        field by field (``l1`` to summation order), over chains of
+        epochs that take the prefix and the search alignment."""
+        from protocol_tpu.obs.watchers import ScoreDriftMonitor
+
+        mon = ScoreDriftMonitor()
+        prev = None
+        for epoch, (hashes, scores) in enumerate(_drift_chain(case)):
+            got = mon.observe(epoch, hashes, scores)
+            want = _per_peer_drift(prev, hashes, scores, mon.top_k)
+            prev = ([int(h) for h in hashes], [float(s) for s in scores])
+            assert got["epoch"] == epoch and got["peers"] == len(hashes)
+            for key in ("linf", "joined", "departed", "top_movers"):
+                assert got[key] == want[key], (case, epoch, key)
+            if want["l1"] is None:
+                assert got["l1"] is None
+            else:
+                assert got["l1"] == pytest.approx(want["l1"], rel=1e-12, abs=0.0)
+        assert prev is not None and epoch >= 2
+
+
+def _per_peer_drift(prev, hashes, scores, top_k):
+    """The drift monitor's per-peer walk as it stood before the
+    alignment was vectorized: the oracle for the summary fields."""
+    hashes = [int(h) for h in hashes]
+    vals = [float(s) for s in scores]
+    out = {"l1": None, "linf": None, "joined": 0, "departed": 0, "top_movers": []}
+    if prev is None:
+        return out
+    prev_by_hash = dict(zip(prev[0], prev[1]))
+    cur_set = set(hashes)
+    deltas = []
+    l1 = linf = 0.0
+    for h, v in zip(hashes, vals):
+        old = prev_by_hash.get(h)
+        if old is None:
+            out["joined"] += 1
+            continue
+        d = v - old
+        l1 += abs(d)
+        linf = max(linf, abs(d))
+        deltas.append((abs(d), h, d))
+    out["departed"] = sum(1 for h in prev[0] if h not in cur_set)
+    out["l1"], out["linf"] = l1, linf
+    deltas.sort(reverse=True)
+    out["top_movers"] = [
+        {"peer_hash": hex(h), "delta": d} for absd, h, d in deltas[:top_k] if absd > 0.0
+    ]
+    return out
+
+
+def _drift_chain(case):
+    """Four or more ``(peer hashes, scores)`` epochs for one case."""
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+
+    def scores(n):
+        return rng.random(n).astype(np.float32)
+
+    if case == "same_object":
+        ids = list(range(500))
+        return [(ids, scores(500)) for _ in range(4)]
+    if case == "equal_copy":
+        ids = [int(h) for h in rng.integers(1, 1 << 62, 400)]
+        return [(list(ids), scores(400)) for _ in range(4)]
+    if case == "append_only":
+        ids = list(range(1000, 1300))
+        chain = []
+        for n in (300, 300, 340, 420):
+            ids.extend(range(1000 + len(ids), 1000 + n))
+            chain.append((ids[:n], scores(n)))
+        return chain
+    if case == "reordered":
+        chain, ids = [], list(range(600))
+        for _ in range(5):
+            keep = [h for h in ids if rng.random() > 0.1]
+            ids = keep + [int(h) for h in rng.integers(10**6, 10**7, 40)]
+            rng.shuffle(ids)
+            chain.append((list(ids), scores(len(ids))))
+        return chain
+    if case == "ties_at_top_k":
+        # Exact binary steps: 4 peers move by 0.5 and 13 by 0.25, so the
+        # 10th largest |d| is a tie that six places cannot all take.
+        ids = [int(h) for h in rng.permutation(np.arange(100, 140))]
+        base = np.full(40, 1.0)
+        step = np.zeros(40)
+        step[:4] = 0.5
+        step[4:17] = 0.25
+        sign = np.where(rng.random(40) < 0.5, -1.0, 1.0)
+        moved = base + sign * step
+        return [(ids, base), (ids, moved), (ids[::-1], moved[::-1]), (ids, base)]
+    if case == "few_movers":
+        ids = list(range(50))
+        still = np.linspace(0.1, 0.9, 50)
+        moved = still.copy()
+        moved[[3, 17, 41]] += [1e-3, -2e-3, 5e-4]
+        return [(ids, still), (ids, moved), (list(ids), moved), (ids, still)]
+    if case == "poseidon_collision":
+        big = [int.from_bytes(rng.bytes(32), "big") >> 2 for _ in range(200)]
+        a = big[0]
+        b = a ^ (1 << 200)  # the same low 64 bits as ``a``
+        assert (a ^ b) & ((1 << 64) - 1) == 0 and a != b
+        first = big
+        second = [b] + big[1:150] + big[180:] + [int.from_bytes(rng.bytes(32), "big") >> 2]
+        third = [a] + second  # both colliding hashes present
+        fourth = list(third)
+        rng.shuffle(fourth)
+        return [(c, scores(len(c))) for c in (first, second, third, fourth)]
+    if case == "wide_column":
+        # 40 blocks of the monitor's 1024: eight large movers in the last
+        # block and 30 exact ties at 2**-7 spread over the others, under
+        # small noise everywhere, so the top ten take two of the ties.
+        n = 40_000
+        ids = list(range(n))
+        base = 0.5 + 0.25 * rng.random(n)
+        moved = base + 1e-6 * rng.standard_normal(n)
+        moved[-8:] = base[-8:] + 0.01 + 0.09 * rng.random(8)
+        tied = rng.choice(n - 1024, 30, replace=False)
+        moved[tied] = base[tied] + 2.0**-7
+        return [(ids, base), (ids, moved), (ids, base)]
+    raise AssertionError(case)
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,7 @@ from protocol_tpu.ops.gather_window import partition_delta
 from protocol_tpu.parallel.partition import (
     MASK64,
     HostPartition,
+    align_by_hash,
     keys_from_hashes,
     mix64,
     remap_fraction,
@@ -193,3 +194,42 @@ class TestChurnLocality:
         # WAL journaling contract the pod dryrun encodes per host.
         assert np.array_equal(ns1, np.repeat(r1.astype(np.int32), deg))
         assert not np.any(nd1 == ns1)  # no self-edges survive resampling
+
+
+class TestAlignByHash:
+    """The previous column aligned to the new one: each new peer's
+    position in it or -1, by the prefix path (an unchanged or extended
+    column) or the verified search (any other)."""
+
+    def test_prefix_for_the_same_an_equal_and_an_extended_column(self):
+        prev = [7, 3, 11, 5]
+        for old, new, want in (
+            (prev, prev, [0, 1, 2, 3]),
+            (prev, list(prev), [0, 1, 2, 3]),
+            (prev, prev + [2, 13], [0, 1, 2, 3, -1, -1]),
+            ([], [4, 6], [-1, -1]),
+        ):
+            idx, path = align_by_hash(old, new)
+            assert path == "prefix"
+            assert idx.dtype == np.int64
+            assert idx.tolist() == want
+
+    def test_search_for_a_reordered_column_with_joins_and_departures(self):
+        idx, path = align_by_hash([7, 3, 11, 5], [5, 8, 7, 11, 1])
+        assert path == "search"
+        assert idx.dtype == np.int64
+        assert idx.tolist() == [3, -1, 0, 2, -1]
+        idx, path = align_by_hash([7, 3], [3])  # a shorter column
+        assert (path, idx.tolist()) == ("search", [1])
+
+    def test_low_64_bit_collisions_resolve_by_the_full_hash(self):
+        a = (1 << 250) | 0xABC
+        b = (1 << 201) | 0xABC
+        c = (1 << 160) | 0xABC
+        assert len({h & MASK64 for h in (a, b, c)}) == 1
+        # ``a`` departed, ``b`` joined: a key hit, not a match.
+        assert align_by_hash([a, 4], [b, 4])[0].tolist() == [-1, 1]
+        # Three entries under one key: each finds its own position.
+        idx, path = align_by_hash([c, 9, b, a], [a, b, 9, c])
+        assert path == "search"
+        assert idx.tolist() == [3, 2, 1, 0]

@@ -41,6 +41,7 @@ from protocol_tpu.models import scale_free
 from protocol_tpu.models.churn import churn_cohort_dims, sender_centric_churn
 from protocol_tpu.node.manager import Manager
 from protocol_tpu.node.pod import PodDurability
+from protocol_tpu.obs import metrics as obs_metrics
 from protocol_tpu.parallel.mesh import default_mesh
 from protocol_tpu.parallel.partition import HostPartition
 from protocol_tpu.parallel.pod import PodContext, PodWindowPlan
@@ -300,6 +301,51 @@ class TestWarmT0Remap:
         # Negative garbage clamps to zero rather than poisoning the seed.
         got = self._warm(np.array([-1.0, 0.5]), [10, 11], [10, 11])
         np.testing.assert_allclose(got, [0.0, 1.0])
+
+
+    @pytest.mark.parametrize("joined", [0, 37])
+    def test_prefix_seed_is_bit_identical(self, joined):
+        """An unchanged or extended column takes the prefix path; its
+        seed equals, bit for bit, the search path's (the previous column
+        permuted) and the 64-bit searchsorted remap it replaced."""
+        rng = np.random.default_rng(28)
+        prev = [int.from_bytes(rng.bytes(32), "big") >> 2 for _ in range(500)]
+        scores = rng.random(500).astype(np.float32)
+        scores[[5, 9]] = -0.25  # clamped to zero on every path
+        id_order = prev + [int.from_bytes(rng.bytes(32), "big") >> 2 for _ in range(joined)]
+        before = obs_metrics.PEER_ALIGN.value(site="warm_seed", path="prefix")
+        fast = self._warm(scores, prev, id_order)
+        assert obs_metrics.PEER_ALIGN.value(site="warm_seed", path="prefix") == before + 1
+        perm = rng.permutation(500)
+        searched = self._warm(scores[perm], [prev[i] for i in perm], id_order)
+        assert np.array_equal(fast, searched)
+        assert np.array_equal(fast, _searchsorted_remap(scores, prev, id_order))
+
+    def test_low_64_bit_collision_is_absent(self):
+        """A joined hash sharing its low 64 bits with a departed one
+        starts at zero: the search checks the full hash."""
+        a = (0xDEAD << 192) | 0x1234
+        b = (0xBEEF << 192) | 0x1234
+        c = 99
+        got = self._warm(np.array([0.75, 0.25]), [a, c], [b, c])
+        np.testing.assert_array_equal(got, [0.0, 1.0])
+        assert _searchsorted_remap(np.array([0.75, 0.25]), [a, c], [b, c])[0] > 0
+
+
+def _searchsorted_remap(scores, hashes, id_order):
+    """The warm seed as the 64-bit key searchsorted remap computed it
+    before the alignment helper (collisions read as matches)."""
+    from protocol_tpu.parallel.partition import keys_from_hashes
+
+    prev_keys, new_keys = keys_from_hashes(hashes), keys_from_hashes(id_order)
+    order = np.argsort(prev_keys, kind="stable")
+    sorted_prev = prev_keys[order]
+    pos = np.minimum(np.searchsorted(sorted_prev, new_keys), len(sorted_prev) - 1)
+    hit = sorted_prev[pos] == new_keys
+    j = order[pos]
+    prev_scores = np.maximum(np.asarray(scores, np.float64), 0.0)
+    t0 = np.where(hit, prev_scores[j], 0.0)
+    return t0 / t0.sum()
 
 
 class TestPodSentinelKeys:
