@@ -71,15 +71,13 @@ def headline_entry(
     from protocol_tpu.obs import TRACER
     from protocol_tpu.ops.gather_window import build_window_plan, converge_windowed
     from protocol_tpu.ops.sparse import converge_csr
-    from protocol_tpu.trust.graph import TrustGraph
+    from protocol_tpu.trust.backend import normalize_graph
 
     target_seconds = 2.0
 
-    graph = scale_free(n_peers, n_edges, seed=7)
-    g = graph.drop_self_edges()
-    w, dangling = g.row_normalized()
-    g = TrustGraph(g.n, g.src, g.dst, w, graph.pre_trusted).sorted_by_dst()
-    p = graph.pre_trust_vector()
+    ng = normalize_graph(scale_free(n_peers, n_edges, seed=7))
+    g = ng.by_dst()
+    p, dangling = ng.p, ng.dangling
     extra: dict = {}
     # Span-derived phase timings (ISSUE 4): the bench emits the SAME
     # obs spans the node's epoch tick does (plan, converge), so a
@@ -93,7 +91,7 @@ def headline_entry(
             jax.device_put(jnp.asarray(g.row_ptr_by_dst())),
             jax.device_put(jnp.asarray(g.weight)),
             jax.device_put(jnp.asarray(p)),
-            jax.device_put(jnp.asarray(dangling.astype(np.float32))),
+            jax.device_put(jnp.asarray(dangling)),
         )
         alpha = jax.device_put(np.float32(0.1))
         jax.block_until_ready(device_args)
@@ -141,7 +139,7 @@ def headline_entry(
         plan_args = tuple(jax.device_put(a) for a in plan.device_args())
         vec_args = (
             jax.device_put(jnp.asarray(p)),
-            jax.device_put(jnp.asarray(dangling.astype(np.float32))),
+            jax.device_put(jnp.asarray(dangling)),
         )
         alpha = jax.device_put(np.float32(0.1))
         jax.block_until_ready((plan_args, vec_args))
@@ -191,7 +189,7 @@ def headline_entry(
 
         mesh = default_mesh()
         with TRACER.span("plan", backend=backend) as plan_span:
-            swp, plan_dt = _timed(lambda: ShardedWindowPlan.build(graph, mesh))
+            swp, plan_dt = _timed(lambda: ShardedWindowPlan.build(ng, mesh))
         phases["plan"] = round(plan_span.duration_s or 0.0, 4)
         extra = {
             "plan_seconds": round(plan_dt, 4),
@@ -290,7 +288,7 @@ def epochs_entry(
     from protocol_tpu.models.churn import churn_cohort_dims, sender_centric_churn
     from protocol_tpu.models.graphs import scale_free
     from protocol_tpu.obs.metrics import PLAN_OUTCOMES
-    from protocol_tpu.trust.backend import get_backend
+    from protocol_tpu.trust.backend import get_backend, normalize_graph
 
     rng = np.random.default_rng(seed)
     graph = scale_free(n_peers, n_edges, seed=seed).drop_self_edges()
@@ -302,9 +300,9 @@ def epochs_entry(
     if hasattr(b, "plan"):
         from protocol_tpu.ops.gather_window import build_window_plan
 
-        w, _ = graph.row_normalized()
+        ng = normalize_graph(graph)
         plan, plan_seconds = _timed(
-            lambda: build_window_plan(graph.src, graph.dst, w, n=graph.n)
+            lambda: build_window_plan(ng.src, ng.dst, ng.w, n=ng.n)
         )
         b.plan = plan
     b.converge(graph, alpha=0.1, tol=tol, max_iter=max_iter)  # compile

@@ -340,6 +340,7 @@ def sharded_epoch(
         windowed_operands,
         windowed_runner,
     )
+    from protocol_tpu.trust.backend import normalize_graph
 
     backend = "tpu-sharded:tpu-windowed"
     manager = Manager(ManagerConfig(backend=backend, prover="commitment"))
@@ -351,7 +352,9 @@ def sharded_epoch(
 
     mesh = default_mesh()
     check(mesh.size == chips, f"default mesh has {mesh.size} devices, want {chips}")
-    swp = ShardedWindowPlan.build(graph, mesh, plan=manager.window_plan)
+    swp = ShardedWindowPlan.build(
+        normalize_graph(graph), mesh, plan=manager.window_plan
+    )
     arrays = windowed_operands(swp)
     devices = [sorted(s.device.id for s in a.addressable_shards) for a in arrays]
     run = windowed_runner(swp)

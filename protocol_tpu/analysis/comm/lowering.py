@@ -89,15 +89,20 @@ def _graph(n: int, e: int):
     return TrustGraph(g.n, g.src[keep], g.dst[keep], g.weight[keep], g.pre_trusted)
 
 
-def _normalized(graph):
-    import numpy as np
+def _operands(ng, layout) -> tuple:
+    """A single-device converge's operands over the normalized graph
+    (shared with ``analysis.invariants``): ``(src, dst, w)`` for
+    ``layout`` ``"coo"``, ``(src, row_ptr, w)`` for ``"csr"``, or a
+    ``WindowPlan``'s device arrays, then ``(t0, p, dangling)`` seeded at
+    the pre-trust vector."""
+    import jax.numpy as jnp
 
-    from ...trust.graph import TrustGraph
-
-    g = graph.drop_self_edges()
-    w, dangling = g.row_normalized()
-    gs = TrustGraph(g.n, g.src, g.dst, w, g.pre_trusted).sorted_by_dst()
-    return g, gs, w, dangling.astype(np.float32)
+    vectors = tuple(jnp.asarray(a) for a in (ng.p, ng.p, ng.dangling))
+    if not isinstance(layout, str):
+        return layout.device_args() + vectors
+    gs = ng.by_dst()
+    index = gs.dst if layout == "coo" else gs.row_ptr_by_dst()
+    return tuple(jnp.asarray(a) for a in (gs.src, index, gs.weight)) + vectors
 
 
 def _jaxpr_psums(jaxpr: Any) -> int:
@@ -134,25 +139,18 @@ def _lower_sparse(n: int, e: int) -> CommCase:
     import jax.numpy as jnp
 
     from ...ops.sparse import converge_sparse
+    from ...trust.backend import normalize_graph
 
-    g, gs, w, dangling = _normalized(_graph(n, e))
-    p = g.pre_trust_vector()
-    args = (
-        jnp.asarray(gs.src),
-        jnp.asarray(gs.dst),
-        jnp.asarray(gs.weight),
-        jnp.asarray(p),
-        jnp.asarray(p),
-        jnp.asarray(dangling),
-    )
-    kw = dict(n=g.n, alpha=jnp.asarray(0.1, jnp.float32), tol=1e-6, max_iter=4)
+    ng = normalize_graph(_graph(n, e))
+    args = _operands(ng, "coo")
+    kw = dict(n=ng.n, alpha=jnp.asarray(0.1, jnp.float32), tol=1e-6, max_iter=4)
     compiled = converge_sparse.lower(*args, **kw).compile()
     jaxpr = jax.make_jaxpr(
         lambda *a: converge_sparse(*a, **kw), static_argnums=()
     )(*args)
     return CommCase(
         backend="tpu-sparse",
-        dims={"n": g.n, "edges": g.nnz, "n_shards": 1},
+        dims={"n": ng.n, "edges": ng.nnz, "n_shards": 1},
         module_text=compiled.as_text(),
         arg_names=("src", "dst", "w", "t0", "p", "dangling"),
         jaxpr_psums=_jaxpr_psums(jaxpr),
@@ -165,23 +163,16 @@ def _lower_csr(n: int, e: int) -> CommCase:
     import jax.numpy as jnp
 
     from ...ops.sparse import converge_csr
+    from ...trust.backend import normalize_graph
 
-    g, gs, w, dangling = _normalized(_graph(n, e))
-    p = g.pre_trust_vector()
-    args = (
-        jnp.asarray(gs.src),
-        jnp.asarray(gs.row_ptr_by_dst()),
-        jnp.asarray(gs.weight),
-        jnp.asarray(p),
-        jnp.asarray(p),
-        jnp.asarray(dangling),
-    )
+    ng = normalize_graph(_graph(n, e))
+    args = _operands(ng, "csr")
     kw = dict(alpha=jnp.asarray(0.1, jnp.float32), tol=1e-6, max_iter=4)
     compiled = converge_csr.lower(*args, **kw).compile()
     jaxpr = jax.make_jaxpr(lambda *a: converge_csr(*a, **kw))(*args)
     return CommCase(
         backend="tpu-csr",
-        dims={"n": g.n, "edges": g.nnz, "n_shards": 1},
+        dims={"n": ng.n, "edges": ng.nnz, "n_shards": 1},
         module_text=compiled.as_text(),
         arg_names=("src", "row_ptr", "w", "t0", "p", "dangling"),
         jaxpr_psums=_jaxpr_psums(jaxpr),
@@ -194,15 +185,11 @@ def _lower_windowed(n: int, e: int) -> CommCase:
     import jax.numpy as jnp
 
     from ...ops.gather_window import build_window_plan, converge_windowed
+    from ...trust.backend import normalize_graph
 
-    g, gs, w, dangling = _normalized(_graph(n, e))
-    plan = build_window_plan(g.src, g.dst, w, n=g.n)
-    p = g.pre_trust_vector()
-    args = plan.device_args() + (
-        jnp.asarray(p),
-        jnp.asarray(p),
-        jnp.asarray(dangling),
-    )
+    ng = normalize_graph(_graph(n, e))
+    plan = build_window_plan(ng.src, ng.dst, ng.w, n=ng.n)
+    args = _operands(ng, plan)
     kw = dict(
         **plan.static_kwargs(),
         alpha=jnp.asarray(0.1, jnp.float32),
@@ -215,8 +202,8 @@ def _lower_windowed(n: int, e: int) -> CommCase:
     return CommCase(
         backend="tpu-windowed",
         dims={
-            "n": g.n,
-            "edges": g.nnz,
+            "n": ng.n,
+            "edges": ng.nnz,
             "n_segments": plan.seg_capacity,
             "n_rows": plan.n_rows,
             "n_shards": 1,
@@ -239,9 +226,10 @@ def _lower_sharded_csr(n: int, e: int) -> CommCase:
 
     from ...parallel.mesh import SHARD_AXIS, default_mesh
     from ...parallel.sharded import ShardedTrustProblem, _get_runner
+    from ...trust.backend import normalize_graph
 
     mesh = default_mesh()
-    prob = ShardedTrustProblem.build(_graph(n, e), mesh)
+    prob = ShardedTrustProblem.build(normalize_graph(_graph(n, e)), mesh)
     run = _get_runner(mesh, prob.n)
     args = (
         prob.src, prob.w, prob.row_ptr, prob.t0(), prob.p, prob.dangling,
@@ -276,10 +264,11 @@ def _lower_sharded_windowed(n: int, e: int) -> CommCase:
         windowed_operands,
         windowed_runner,
     )
+    from ...trust.backend import normalize_graph
 
     mesh = default_mesh()
-    graph = _graph(n, e)
-    swp = ShardedWindowPlan.build(graph, mesh)
+    ng = normalize_graph(_graph(n, e))
+    swp = ShardedWindowPlan.build(ng, mesh)
     run = windowed_runner(swp)
     args = (
         *windowed_operands(swp), swp.t0(), swp.p, swp.dangling,
@@ -292,7 +281,7 @@ def _lower_sharded_windowed(n: int, e: int) -> CommCase:
         backend="tpu-sharded:tpu-windowed",
         dims={
             "n": swp.n,
-            "edges": int(graph.drop_self_edges().nnz),
+            "edges": ng.nnz,
             "n_segments": swp.s_max,
             "n_rows": swp.rows_per_shard,
             "n_shards": mesh.shape[SHARD_AXIS],

@@ -63,25 +63,9 @@ def _synthetic_graph():
     """Small scale-free graph every trace shares: multi-window N, forced
     dangling peers, sizes chosen so the budget dimensions stay
     distinguishable (asserted in the windowed recipes)."""
-    import numpy as np
+    from .comm.lowering import _graph
 
-    from ..models.graphs import scale_free
-    from ..trust.graph import TrustGraph
-
-    g = scale_free(1500, 9000, seed=2)
-    keep = ~np.isin(g.src, np.asarray([0, 17, 1499], dtype=np.int32))
-    return TrustGraph(g.n, g.src[keep], g.dst[keep], g.weight[keep], g.pre_trusted)
-
-
-def _normalized(graph):
-    import numpy as np
-
-    from ..trust.graph import TrustGraph
-
-    g = graph.drop_self_edges()
-    w, dangling = g.row_normalized()
-    gs = TrustGraph(g.n, g.src, g.dst, w, g.pre_trusted).sorted_by_dst()
-    return g, gs, w, dangling.astype(np.float32)
+    return _graph(1500, 9000)
 
 
 def _trace_dense(graph) -> TraceCase:
@@ -107,26 +91,19 @@ def _trace_sparse(graph) -> TraceCase:
     import jax.numpy as jnp
 
     from ..ops.sparse import converge_sparse, power_step_coo
+    from ..trust.backend import normalize_graph
+    from .comm.lowering import _operands
 
-    g, gs, w, dangling = _normalized(graph)
-    p = g.pre_trust_vector()
-    args = (
-        jnp.asarray(gs.src),
-        jnp.asarray(gs.dst),
-        jnp.asarray(gs.weight),
-        jnp.asarray(p),
-        jnp.asarray(p),
-        jnp.asarray(dangling),
-        jnp.asarray(0.1, jnp.float32),
-    )
+    ng = normalize_graph(graph)
+    args = _operands(ng, "coo") + (jnp.asarray(0.1, jnp.float32),)
     jaxpr = jax.make_jaxpr(
-        lambda s, d, wt, t, pp, dg, a: power_step_coo(s, d, wt, t, pp, dg, a, n=g.n)
+        lambda s, d, wt, t, pp, dg, a: power_step_coo(s, d, wt, t, pp, dg, a, n=ng.n)
     )(*args)
     lowered = converge_sparse.lower(
-        *args[:6], n=g.n, alpha=args[6], tol=1e-6, max_iter=4
+        *args[:6], n=ng.n, alpha=args[6], tol=1e-6, max_iter=4
     ).as_text()
     return TraceCase(
-        "tpu-sparse", jaxpr, dims={"edges": g.nnz, "n": g.n}, lowered_text=lowered
+        "tpu-sparse", jaxpr, dims={"edges": ng.nnz, "n": ng.n}, lowered_text=lowered
     )
 
 
@@ -135,24 +112,17 @@ def _trace_csr(graph) -> TraceCase:
     import jax.numpy as jnp
 
     from ..ops.sparse import converge_csr, power_step_csr
+    from ..trust.backend import normalize_graph
+    from .comm.lowering import _operands
 
-    g, gs, w, dangling = _normalized(graph)
-    p = g.pre_trust_vector()
-    args = (
-        jnp.asarray(gs.src),
-        jnp.asarray(gs.row_ptr_by_dst()),
-        jnp.asarray(gs.weight),
-        jnp.asarray(p),
-        jnp.asarray(p),
-        jnp.asarray(dangling),
-        jnp.asarray(0.1, jnp.float32),
-    )
+    ng = normalize_graph(graph)
+    args = _operands(ng, "csr") + (jnp.asarray(0.1, jnp.float32),)
     jaxpr = jax.make_jaxpr(lambda *a: power_step_csr(*a))(*args)
     lowered = converge_csr.lower(
         *args[:6], alpha=args[6], tol=1e-6, max_iter=4
     ).as_text()
     return TraceCase(
-        "tpu-csr", jaxpr, dims={"edges": g.nnz, "n": g.n}, lowered_text=lowered
+        "tpu-csr", jaxpr, dims={"edges": ng.nnz, "n": ng.n}, lowered_text=lowered
     )
 
 
@@ -165,20 +135,16 @@ def _trace_windowed(graph) -> TraceCase:
         converge_windowed,
         power_step_windowed,
     )
+    from ..trust.backend import normalize_graph
+    from .comm.lowering import _operands
 
-    g, gs, w, dangling = _normalized(graph)
-    plan = build_window_plan(g.src, g.dst, w, n=g.n)
+    ng = normalize_graph(graph)
+    plan = build_window_plan(ng.src, ng.dst, ng.w, n=ng.n)
     # Keep the budget dimensions distinguishable: the rowsum gathers are
     # (n+1)-sized, the bridge reads seg_capacity-sized (the device
     # length of the padded run table, >= n_segments live runs).
-    assert plan.seg_capacity != g.n + 1, "synthetic graph aliases budget dims"
-    p = g.pre_trust_vector()
-    args = plan.device_args() + (
-        jnp.asarray(p),
-        jnp.asarray(p),
-        jnp.asarray(dangling),
-        jnp.asarray(0.1, jnp.float32),
-    )
+    assert plan.seg_capacity != ng.n + 1, "synthetic graph aliases budget dims"
+    args = _operands(ng, plan) + (jnp.asarray(0.1, jnp.float32),)
     jaxpr = jax.make_jaxpr(
         lambda *a: power_step_windowed(
             *a,
@@ -197,7 +163,7 @@ def _trace_windowed(graph) -> TraceCase:
     return TraceCase(
         "tpu-windowed",
         jaxpr,
-        dims={"n_segments": plan.seg_capacity, "n": g.n},
+        dims={"n_segments": plan.seg_capacity, "n": ng.n},
         lowered_text=lowered,
     )
 
@@ -210,9 +176,10 @@ def _trace_sharded_csr(graph) -> TraceCase:
 
     from ..parallel.mesh import SHARD_AXIS, default_mesh
     from ..parallel.sharded import ShardedTrustProblem, _get_runner
+    from ..trust.backend import normalize_graph
 
     mesh = default_mesh()
-    prob = ShardedTrustProblem.build(graph, mesh)
+    prob = ShardedTrustProblem.build(normalize_graph(graph), mesh)
     run = _get_runner(mesh, prob.n)
     args = (
         prob.src,
@@ -246,9 +213,10 @@ def _trace_sharded_windowed(graph) -> TraceCase:
         windowed_operands,
         windowed_runner,
     )
+    from ..trust.backend import normalize_graph
 
     mesh = default_mesh()
-    swp = ShardedWindowPlan.build(graph, mesh)
+    swp = ShardedWindowPlan.build(normalize_graph(graph), mesh)
     assert swp.s_max != swp.n + 1, "synthetic graph aliases budget dims"
     run = windowed_runner(swp)
     args = (

@@ -726,7 +726,7 @@ class Manager:
                 keys = keys_from_hashes(id_order[int(r)] for r in rows)
                 rows = rows[part.assign(keys) == self.config.pod_host_id]
             # Above the churn crossover a full rebuild is cheaper than
-            # repacking that many windows (PERF.md §11).
+            # repacking that many windows.
             if rows.size and rows.size <= self.config.plan_delta_max_churn * max(
                 graph.n, 1
             ):
@@ -767,7 +767,7 @@ class Manager:
         if key not in NON_JAX_BACKENDS and key not in KERNEL_INVARIANTS:
             logger.warning(
                 "trust backend %r has no KERNEL_INVARIANTS declaration; "
-                "its kernel access pattern is not lint-gated (PERF.md §9)",
+                "its kernel access pattern is not lint-gated",
                 self.config.backend,
             )
         # Recompile watch: PR 5 guarantees a steady-state delta epoch

@@ -109,6 +109,18 @@ def _no_phase(name: str, **counts: int) -> ContextManager:
     return contextlib.nullcontext()
 
 
+#: A plan-attempt hook: ``attempt(reason, make)`` returns ``make(phase)``
+#: (a plan, or None where a delta does not apply) for one attempt at a
+#: plan: ``delta``, or a rebuild for its reason (``cold``,
+#: ``stale-layout``, ``fingerprint-miss``).  ``trust/backend.py`` wraps
+#: each attempt in its ``plan`` span.
+PlanAttempt = Callable[[str, Callable[[PlanPhase], object]], object]
+
+
+def _no_attempt(reason: str, make: Callable[[PlanPhase], object]) -> object:
+    return make(_no_phase)
+
+
 def _counting_sort(
     key: np.ndarray, n_keys: int, payload: np.ndarray | None = None
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
@@ -1141,7 +1153,7 @@ def try_plan_delta(
     delta cannot be applied (overflow, shrink), when it would not pay
     (churn spread over too many windows — past the measured crossover
     a full rebuild's vectorized counting sorts beat the per-window
-    repack, PERF.md §11), or when it fails the edge-count tripwire (a
+    repack), or when it fails the edge-count tripwire (a
     stale/incomplete ``rows`` hint would stamp the new fingerprint
     onto a layout that doesn't encode the new graph — in that case the
     caller must rebuild).  ``phase`` wraps the sub-phases:
@@ -1179,6 +1191,37 @@ def try_plan_delta(
     return new_plan
 
 
+def resolve_plan(
+    candidate: WindowPlan | None,
+    src: np.ndarray,
+    dst: np.ndarray,
+    w: np.ndarray,
+    *,
+    n: int,
+    fingerprint: str,
+    rows: np.ndarray | None = None,
+    attempt: PlanAttempt = _no_attempt,
+) -> tuple[WindowPlan, str]:
+    """The plan for the normalized edge list ``(src, dst, w)``: reuse
+    ``candidate`` (cached or checkpoint-restored) while its layout
+    version and ``fingerprint`` hold; else, given the churn hint
+    ``rows``, delta-update it (``try_plan_delta``); else rebuild.
+    Returns the plan and the outcome: ``reuse``, ``delta`` or
+    ``rebuild``."""
+    valid = candidate is not None and getattr(candidate, "version", 0) == PLAN_VERSION
+    if valid and candidate.fingerprint == fingerprint:
+        return candidate, "reuse"
+    if valid and rows is not None:
+        delta = attempt("delta", lambda phase: try_plan_delta(
+            candidate, src, dst, w, n=n, rows=rows, fingerprint=fingerprint, phase=phase
+        ))
+        if delta is not None:
+            return delta, "delta"
+    reason = "cold" if candidate is None else "fingerprint-miss" if valid else "stale-layout"
+    plan = attempt(reason, lambda phase: build_window_plan(src, dst, w, n=n, phase=phase))
+    return plan, "rebuild"
+
+
 def partition_delta(
     rows: np.ndarray | None,
     src: np.ndarray,
@@ -1193,7 +1236,7 @@ def partition_delta(
 
     Edges are owned by their **source** peer, so a dirty row (one
     sender's rewritten out-edges) is dirty on exactly one host: the
-    returned ``owned_rows`` feed :func:`try_plan_delta` against that
+    returned ``owned_rows`` feed :func:`resolve_plan` against that
     host's *local* plan, and hosts owning none of the churn keep their
     plan verbatim — steady-state churn never forces a cross-host
     rebuild.  Returns ``(owned_rows, local_src, local_dst, local_w)``;

@@ -1,20 +1,20 @@
 """Pod scale-out: per-host partition plan build over a multi-process
-``jax.distributed`` mesh (ROADMAP item 1, PERF.md §20).
+``jax.distributed`` mesh.
 
 ``ShardedWindowPlan`` partitions ONE globally-built ``WindowPlan``
 across one host's devices — at 500M edges that single host pays the
-whole O(E) plan construction serially (the PERF.md §11 bottleneck) and
-holds the whole edge set in host RAM.  The pod builder inverts the
-order of operations:
+whole O(E) plan construction serially and holds the whole edge set in
+host RAM.  The pod builder inverts the order of operations:
 
 1. every process computes the identical peer→host partition
    (``parallel.partition.HostPartition`` — rendezvous hash, no
    coordination round) and keeps only the edges whose **source** peer
    it owns;
-2. each host builds a ``WindowPlan`` over its local edges only — N
-   hosts build N partial plans concurrently, so the pod's plan-build
-   critical path is ``max_h(build(E_h))`` ≈ ``build(E)/N`` instead of
-   ``build(E)``;
+2. each host resolves a ``WindowPlan`` over its local edges only
+   (``ops.gather_window.resolve_plan``, against its local fingerprint
+   and its owned churn rows) — N hosts build N partial plans
+   concurrently, so the pod's plan-build critical path is
+   ``max_h(build(E_h))`` ≈ ``build(E)/N`` instead of ``build(E)``;
 3. each host cuts its local plan across its local devices with the
    same BLOCK_ROWS-aligned row cut as the single-host path
    (``sharded._partition_plan_arrays``), padded to pod-wide maxima so
@@ -34,7 +34,7 @@ forces a cross-host rebuild (``ops.gather_window.partition_delta``).
 
 The ``dangling`` vector is the one globally-coupled input (a peer with
 no out-edges anywhere): here every process derives it from its copy of
-the full normalized graph; a production pod exchanges per-host
+the full normalized graph (``trust.backend.normalize_graph``); a production pod exchanges per-host
 out-degree bitmaps through the pod manifest (``node.pod``) — an O(N)
 exchange, never O(E).
 """
@@ -52,14 +52,12 @@ from jax.sharding import PartitionSpec as P
 
 from ..device import pallas_interpret
 from ..ops.gather_window import (
-    PLAN_VERSION,
     WindowPlan,
-    build_window_plan,
     graph_fingerprint,
     partition_delta,
-    try_plan_delta,
+    resolve_plan,
 )
-from ..trust.graph import TrustGraph
+from ..trust.graph import NormalizedGraph
 from .mesh import SHARD_AXIS, default_mesh
 from .partition import HostPartition
 from .sharded import BLOCK_ROWS, ROW, _partition_plan_arrays
@@ -120,7 +118,7 @@ class PodWindowPlan:
     the pod bookkeeping the dryrun and the node durability plane read:
     which host this is, the peer→host owner map, and how long the
     *local* plan build took (the pod's plan-build critical path is the
-    max of these, PERF.md §20).
+    max of these).
     """
 
     mesh: Mesh
@@ -144,7 +142,7 @@ class PodWindowPlan:
     owner: np.ndarray  # (n,) int32 peer→host owner map
     local_edges: int  # edges this host's partition holds
     build_seconds: float  # local plan construction wall-clock
-    #: Pre-collective barrier probe (ISSUE 19): when this host entered
+    #: Pre-collective barrier probe: when this host entered
     #: the dimension-agreement allgather (caller's monotonic clock) and
     #: how long both agreement rounds blocked — the pod trace stitcher
     #: clock-aligns the arrival stamps into the barrier-arrival spread
@@ -161,7 +159,7 @@ class PodWindowPlan:
     @classmethod
     def build(
         cls,
-        graph: TrustGraph,
+        graph: NormalizedGraph,
         pod: PodContext,
         *,
         plan: WindowPlan | None = None,
@@ -170,11 +168,11 @@ class PodWindowPlan:
         clock: Callable[[], float] | None = None,
         wall: Callable[[], float] | None = None,
     ) -> "PodWindowPlan":
-        """Partition the graph by source-peer owner, resolve this
-        host's local plan (reuse / delta / rebuild against the local
-        fingerprint — churn owned by other hosts leaves it untouched),
-        cut it across the local devices, and assemble the global
-        sharded arrays.  ``plan`` is this host's cached *local* plan
+        """Partition the normalized graph by source-peer owner, resolve
+        this host's local plan (``resolve_plan``: reuse / delta /
+        rebuild against the local fingerprint — churn owned by other
+        hosts leaves it untouched), cut it across the local devices,
+        and assemble the global sharded arrays.  ``plan`` is this host's cached *local* plan
         (checkpoint-shard restored); ``delta_rows`` is the global
         churn hint, clipped to owned rows here.  ``clock`` is the
         caller's monotonic clock for the ``build_seconds`` field and
@@ -185,30 +183,18 @@ class PodWindowPlan:
         without them the probe fields stay 0.0."""
         sync_monotonic = clock() if clock is not None else 0.0
         sync_unix = wall() if wall is not None else 0.0
-        g = graph.drop_self_edges()
-        w, dangling = g.row_normalized()
-        owner = pod.partition.assign_ids(g.n)
+        owner = pod.partition.assign_ids(graph.n)
         owned_rows, lsrc, ldst, lw = partition_delta(
-            delta_rows, g.src, g.dst, w, owner, pod.host_id
+            delta_rows, graph.src, graph.dst, graph.w, owner, pod.host_id
         )
-        fp = graph_fingerprint(g.n, lsrc, ldst, lw)
-        outcome = "reuse"
+        fp = graph_fingerprint(graph.n, lsrc, ldst, lw)
+        t_build = clock() if clock is not None else 0.0
+        plan, outcome = resolve_plan(
+            plan, lsrc, ldst, lw, n=graph.n, fingerprint=fp, rows=owned_rows
+        )
         build_seconds = 0.0
-        valid = plan is not None and getattr(plan, "version", 0) == PLAN_VERSION
-        if not (valid and plan.fingerprint == fp):
-            t_build = clock() if clock is not None else 0.0
-            delta = None
-            if valid and owned_rows is not None and owned_rows.size:
-                delta = try_plan_delta(
-                    plan, lsrc, ldst, lw, n=g.n, rows=owned_rows, fingerprint=fp
-                )
-            if delta is not None:
-                plan, outcome = delta, "delta"
-            else:
-                plan = build_window_plan(lsrc, ldst, lw, n=g.n)
-                outcome = "rebuild"
-            if clock is not None:
-                build_seconds = clock() - t_build
+        if clock is not None and outcome != "reuse":
+            build_seconds = clock() - t_build
 
         # Pod-wide dimension agreement: every global shard must carry
         # the same (rows_per_shard, s_max) so the compiled runner sees
@@ -267,8 +253,8 @@ class PodWindowPlan:
             run_start=shard2d(parts["run_start"]),
             run_end=shard1d(parts["run_end"].reshape(-1)),
             dst_ptr=shard2d(parts["dst_ptr"]),
-            p=jax.device_put(graph.pre_trust_vector(), repl),
-            dangling=jax.device_put(dangling.astype(np.float32), repl),
+            p=jax.device_put(graph.p, repl),
+            dangling=jax.device_put(graph.dangling, repl),
             plan=plan,
             plan_outcome=outcome,
             host_id=pod.host_id,

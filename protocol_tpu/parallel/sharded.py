@@ -11,8 +11,8 @@ XLA place collectives):
   (``(n_shards, n+1)`` sharded on axis 0), precomputed on the host by
   clipping the global pointer array to each shard's range.  This lets
   every shard run the same scatter-free ``rowsum_sorted`` cumsum kernel
-  as the single-device ``tpu-csr`` path (PERF.md §1 measured the old
-  per-shard ``segment_sum`` 2.4× slower end-to-end at full scale).
+  as the single-device ``tpu-csr`` path (the old per-shard
+  ``segment_sum`` measured 2.4× slower end-to-end at full scale).
 - **t, p, dangling**: replicated (a 1M-peer f32 vector is 4 MB — cheap
   to replicate, expensive to re-gather per step).
 - per step, inside ``shard_map``: each device computes its partial
@@ -29,7 +29,7 @@ Two kernels share this recipe (``SHARDED_KERNELS``, selectable as
 - ``tpu-csr`` — ``ShardedTrustProblem``: the gather-only CSR/cumsum
   SpMV above, with the O(E) random ``t[src]`` gather per shard.
 - ``tpu-windowed`` — ``ShardedWindowPlan``: the fused fixed-slot
-  pipeline (PERF.md §7-8) taken multi-chip.  The one-time
+  pipeline taken multi-chip.  The one-time
   ``WindowPlan`` is partitioned by *window rows*: each shard owns a
   contiguous, BLOCK_ROWS-aligned slice of the plan's vreg-rows (runs
   never span rows, so the bucket-order segment table splits at the
@@ -42,6 +42,9 @@ Two kernels share this recipe (``SHARDED_KERNELS``, selectable as
   per-shard partial Cᵀt vectors are completed by the same ``lax.psum``
   (dst rows whose runs land on several shards are partially summed on
   each side, exactly like CSR rows straddling a shard cut).
+
+Both take the graph as ``trust.backend.normalize_graph`` leaves it
+(``NormalizedGraph``); neither normalizes anything itself.
 
 This is the distributed analog of the reference's single-threaded
 5×5×10 loop (circuit/src/circuit.rs:434-454) at 10^6 peers.
@@ -72,17 +75,15 @@ from ..analysis.budget import (
 from ..device import pallas_interpret
 from ..ops.gather_window import (
     BLOCK_ROWS,
-    PLAN_VERSION,
     ROW,
     WindowPlan,
     _counting_sort,
-    build_window_plan,
-    graph_fingerprint,
-    try_plan_delta,
+    resolve_plan,
     windowed_ct,
 )
-from ..trust.graph import TrustGraph
+from ..trust.graph import NormalizedGraph
 from .mesh import SHARD_AXIS
+
 
 @dataclass
 class ShardedTrustProblem:
@@ -97,14 +98,11 @@ class ShardedTrustProblem:
     dangling: jax.Array  # (n,) f32, replicated
 
     @classmethod
-    def build(cls, graph: TrustGraph, mesh: Mesh) -> "ShardedTrustProblem":
-        """Host-side assembly: drop self-edges, row-normalize, sort by
-        dst, pad to the mesh size, derive per-shard row pointers, and
-        place arrays with explicit shardings."""
-        g = graph.drop_self_edges()
-        w, dangling = g.row_normalized()
-        g = TrustGraph(g.n, g.src, g.dst, w, g.pre_trusted)
-        g = g.sorted_by_dst()
+    def build(cls, graph: NormalizedGraph, mesh: Mesh) -> "ShardedTrustProblem":
+        """Host-side assembly of the normalized graph: sort by dst, pad
+        to the mesh size, derive per-shard row pointers, and place
+        arrays with explicit shardings."""
+        g = graph.by_dst()
 
         n_shards = mesh.shape[SHARD_AXIS]
         pad = (-g.nnz) % n_shards
@@ -129,15 +127,15 @@ class ShardedTrustProblem:
             src=jax.device_put(src, edge_sharding),
             w=jax.device_put(wpad, edge_sharding),
             row_ptr=jax.device_put(row_ptr, NamedSharding(mesh, P(SHARD_AXIS, None))),
-            p=jax.device_put(graph.pre_trust_vector(), repl),
-            dangling=jax.device_put(dangling.astype(np.float32), repl),
+            p=jax.device_put(graph.p, repl),
+            dangling=jax.device_put(graph.dangling, repl),
         )
 
     def t0(self) -> jax.Array:
         """Initial score vector: the pre-trust distribution (the scaled
         analog of everyone starting at INITIAL_SCORE).  A fresh device
-        copy, not ``p`` itself: the runners donate ``t0`` (PERF.md §15)
-        and ``p`` must survive the iteration it seeds."""
+        copy, not ``p`` itself: the runners donate ``t0`` and ``p``
+        must survive the iteration it seeds."""
         return jnp.copy(self.p)
 
 
@@ -327,37 +325,25 @@ class ShardedWindowPlan:
     @classmethod
     def build(
         cls,
-        graph: TrustGraph,
+        graph: NormalizedGraph,
         mesh: Mesh,
         *,
         plan: WindowPlan | None = None,
         delta_rows: np.ndarray | None = None,
         interpret: bool | None = None,
     ) -> "ShardedWindowPlan":
-        """Normalize the graph, reuse (or build) its ``WindowPlan``, and
-        partition it across the mesh.  A candidate ``plan`` (e.g.
-        checkpoint-restored) is revalidated by fingerprint and layout
-        version, exactly like the single-device backend; on a
-        fingerprint miss with a ``delta_rows`` churn hint the plan is
-        delta-updated (``WindowPlan.apply_delta``) instead of rebuilt,
-        and the partition is recut from the updated plan — the
-        ``plan_outcome`` field reports which path ran."""
-        g = graph.drop_self_edges()
-        w, dangling = g.row_normalized()
-        fp = graph_fingerprint(g.n, g.src, g.dst, w)
-        outcome = "reuse"
-        valid = plan is not None and getattr(plan, "version", 0) == PLAN_VERSION
-        if not (valid and plan.fingerprint == fp):
-            delta = None
-            if valid and delta_rows is not None:
-                delta = try_plan_delta(
-                    plan, g.src, g.dst, w, n=g.n, rows=delta_rows, fingerprint=fp
-                )
-            if delta is not None:
-                plan, outcome = delta, "delta"
-            else:
-                plan = build_window_plan(g.src, g.dst, w, n=g.n)
-                outcome = "rebuild"
+        """Resolve the normalized graph's ``WindowPlan`` and partition it
+        across the mesh.  A candidate ``plan`` (cached, checkpoint-
+        restored, or resolved already by the sharded backend) goes
+        through the single-device backend's decision
+        (``ops.gather_window.resolve_plan``): reused while its layout
+        version and fingerprint hold, delta-updated given a
+        ``delta_rows`` churn hint, else rebuilt — the ``plan_outcome``
+        field reports which path ran."""
+        plan, outcome = resolve_plan(
+            plan, graph.src, graph.dst, graph.w,
+            n=graph.n, fingerprint=graph.fingerprint, rows=delta_rows,
+        )
 
         n_shards = mesh.shape[SHARD_AXIS]
         parts = _partition_plan_arrays(plan, n_shards)
@@ -381,8 +367,8 @@ class ShardedWindowPlan:
             run_start=jax.device_put(parts["run_start"], edge2d),
             run_end=jax.device_put(parts["run_end"].reshape(-1), edge),
             dst_ptr=jax.device_put(parts["dst_ptr"], edge2d),
-            p=jax.device_put(graph.pre_trust_vector(), repl),
-            dangling=jax.device_put(dangling.astype(np.float32), repl),
+            p=jax.device_put(graph.p, repl),
+            dangling=jax.device_put(graph.dangling, repl),
             plan=plan,
             plan_outcome=outcome,
         )
@@ -588,7 +574,7 @@ def converge_sharded(
 
 
 # ---------------------------------------------------------------------------
-# Pinned kernel invariants (PERF.md §9) — checked per step by
+# Pinned kernel invariants — checked per step by
 # `python -m protocol_tpu.analysis` under the 8-device CPU mesh.
 # ---------------------------------------------------------------------------
 
@@ -625,7 +611,7 @@ declare(
 
 
 # ---------------------------------------------------------------------------
-# Pinned communication budgets (PERF.md §15) — checked against the
+# Pinned communication budgets — checked against the
 # COMPILED (SPMD-partitioned) module by graftlint pass 8 at two problem
 # scales, and at runtime by the 2-process tools/comm_probe.py smoke.
 # ---------------------------------------------------------------------------
@@ -673,7 +659,7 @@ declare_comm(
 
 
 # ---------------------------------------------------------------------------
-# Pinned memory budgets (PERF.md §19) — checked against the compiled
+# Pinned memory budgets — checked against the compiled
 # module's buffer assignment by graftlint pass 12 at two problem
 # scales (E x4 vs N x2), and at runtime by tools/mem_probe.py.  All
 # numbers are PER DEVICE: the resident edge term is E/n_shards by

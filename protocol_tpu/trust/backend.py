@@ -9,7 +9,7 @@ backends along the scaling ladder:
 - ``tpu-sparse``    COO segment-sum SpMV, single device
 - ``tpu-csr``       gather-only CSR/compensated-cumsum SpMV (scatter-free)
 - ``tpu-windowed``  fused fixed-slot pipeline: windowed Pallas gather from
-  the score table + static bucket→dst bridge (PERF.md §7).  Needs a
+  the score table + static bucket→dst bridge.  Needs a
   static graph layout (the one-time ``WindowPlan``, reusable across
   epochs/reboots while the graph fingerprint holds).  The table is read
   into VMEM in chunks of ``TABLE_CHUNK`` entries (4 MB f32), one
@@ -21,16 +21,19 @@ backends along the scaling ladder:
   selected with a ``:<kernel>`` suffix on the backend name:
   ``tpu-sharded:tpu-csr`` (default — per-shard CSR ``rowsum_sorted``
   via clipped row pointers) and ``tpu-sharded:tpu-windowed`` (the fused
-  fixed-slot pipeline partitioned by window rows, PERF.md §8 — the
-  multi-chip path that keeps the 50× windowed gather).
+  fixed-slot pipeline partitioned by window rows — the multi-chip path
+  that keeps the windowed gather).
 
 All float backends compute the damped EigenTrust fixed point over the
 row-normalized graph; ``native-cpu`` additionally reproduces the
-reference's field semantics for the proof layer.
+reference's field semantics for the proof layer.  Each host decision
+is made in one place: the normalized graph (``normalize_graph``) and
+the plan's reuse, delta or rebuild (``_resolve_plan``).
 """
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from fractions import Fraction
 
@@ -40,20 +43,13 @@ import numpy as np
 
 from ..device import pallas_interpret
 from ..ops.dense import converge_dense
-from ..ops.gather_window import (
-    PLAN_VERSION,
-    WindowPlan,
-    build_window_plan,
-    converge_windowed,
-    graph_fingerprint,
-    try_plan_delta,
-)
+from ..ops.gather_window import WindowPlan, converge_windowed, resolve_plan
 from ..obs import TRACER
 from ..obs.journal import JOURNAL
 from ..obs.metrics import PLAN_OUTCOMES, PLAN_REBUILDS, PLAN_REUSES
 from ..obs.watchers import RECOMPILES
 from ..ops.sparse import converge_csr, converge_sparse
-from .graph import TrustGraph
+from .graph import NormalizedGraph, TrustGraph
 
 # Register the jit'd converge entry points with the recompile tracker
 # (obs/watchers.py): the epoch path brackets each converge with a
@@ -114,21 +110,32 @@ def _fetch(out, backend: str, record_residuals: bool, span) -> ConvergenceResult
     return result
 
 
-def _normalized(graph: TrustGraph) -> tuple[TrustGraph, np.ndarray, np.ndarray]:
-    """``(self-edge-free graph, normalized weights, dangling mask)``,
-    each step under its own span inside the caller's ``graph_prep``."""
+def normalize_graph(graph: TrustGraph) -> NormalizedGraph:
+    """The host normalization of every edge-list backend (``tpu-sparse``,
+    ``tpu-csr``, ``tpu-windowed``, both ``tpu-sharded`` kernels, the pod
+    builder and the analyzers): self-edges dropped, weights
+    row-normalized, the dangling mask and the pre-trust vector, each
+    step under its own span inside the caller's ``graph_prep``.
+
+    ``native-cpu`` (exact rationals) and ``tpu-dense`` (a dense matrix
+    normalized by its own row sums) keep their own arithmetic: routing
+    them through float32 per-edge weights would change their numbers."""
     with TRACER.span("drop_self_edges"):
         g = graph.drop_self_edges()
     with TRACER.span("row_normalize"):
         w, dangling = g.row_normalized()
-    return g, w, dangling
+        dangling = dangling.astype(np.float32)
+    return NormalizedGraph(g.n, g.src, g.dst, w, dangling, graph.pre_trust_vector())
 
 
-def _by_dst(graph: TrustGraph, g: TrustGraph, w: np.ndarray) -> TrustGraph:
-    """The normalized graph sorted by destination (the sparse and CSR
-    layouts), under a ``sort_by_dst`` span."""
+def _by_dst(ng: NormalizedGraph) -> TrustGraph:
     with TRACER.span("sort_by_dst"):
-        return TrustGraph(g.n, g.src, g.dst, w, graph.pre_trusted).sorted_by_dst()
+        return ng.by_dst()
+
+
+def _fingerprint(ng: NormalizedGraph) -> str:
+    with TRACER.span("fingerprint"):  # computed once: later reads are free
+        return ng.fingerprint
 
 
 def _plan_phase(plan_span):
@@ -143,11 +150,53 @@ def _plan_phase(plan_span):
     return phase
 
 
-def _plan_layout(plan_span, plan: WindowPlan) -> None:
-    """The table layout of a plan just made, on its ``plan`` span: the
-    chunks the kernel streams the score table in, and the rows padded
-    so that no grid block reads two chunks."""
-    plan_span.attrs.update(table_chunks=plan.table_chunks, align_rows=plan.align_rows)
+def _resolve_plan(
+    backend: str,
+    candidate: WindowPlan | None,
+    ng: NormalizedGraph,
+    rows: np.ndarray | None,
+    span=None,
+) -> WindowPlan:
+    """Reuse, delta-update or rebuild the cached plan for the normalized
+    graph (``ops.gather_window.resolve_plan``), and count the outcome
+    once.  Each attempt opens a ``plan`` span with its ``reason``, its
+    sub-phases and the made plan's table layout (the chunks the kernel
+    streams the score table in, and the rows padded so that no grid
+    block reads two chunks) — or reports into ``span``, where the
+    caller holds it open (the sharded backend's partition follows).
+    Delta application is host-side, strictly before any device dispatch
+    (graftlint's plan-mutation-in-converge rule pins the converse)."""
+    reasons: list[str] = []
+
+    def attempt(reason, make):
+        reasons.append(reason)
+        opened = (
+            TRACER.span("plan", backend=backend, reason=reason)
+            if span is None else contextlib.nullcontext(span)
+        )
+        with opened as s:
+            s.attrs["reason"] = reason
+            plan = make(_plan_phase(s))
+            if plan is not None:
+                s.attrs.update(table_chunks=plan.table_chunks, align_rows=plan.align_rows)
+        return plan
+
+    plan, outcome = resolve_plan(
+        candidate, ng.src, ng.dst, ng.w,
+        n=ng.n, fingerprint=ng.fingerprint, rows=rows, attempt=attempt,
+    )
+    if outcome == "reuse":
+        PLAN_REUSES.inc()
+    elif outcome == "rebuild":
+        PLAN_REBUILDS.inc()
+    PLAN_OUTCOMES.inc(outcome=outcome)
+    detail = {}
+    if outcome == "delta":
+        detail["rows"] = int(rows.size)
+    elif outcome == "rebuild":
+        detail["reason"] = reasons[-1]
+    JOURNAL.record("plan", outcome=outcome, backend=backend, **detail)
+    return plan
 
 
 def _initial_vector(t0, p: np.ndarray) -> np.ndarray:
@@ -157,7 +206,7 @@ def _initial_vector(t0, p: np.ndarray) -> np.ndarray:
     ``p`` — the cold start — when ``t0`` is absent, mis-shaped, or
     degenerate.  A near-fixed-point start is pure initial-carry data:
     the step function, and therefore the pinned kernel budgets, are
-    untouched (PERF.md §11)."""
+    untouched."""
     if t0 is None:
         return p
     t0 = np.asarray(t0, dtype=np.float32).reshape(-1)
@@ -317,17 +366,16 @@ class SparseJaxBackend(TrustBackend):
     def converge(self, graph, *, alpha=0.0, tol=1e-6, max_iter=50,
                  record_residuals=True, t0=None):
         with TRACER.span("graph_prep", edges=graph.nnz):
-            g, w, dangling = _normalized(graph)
-            g = _by_dst(graph, g, w)
-            p = graph.pre_trust_vector()
+            ng = normalize_graph(graph)
+            g = _by_dst(ng)
         with TRACER.span("converge", backend=self.name) as span:
             out = converge_sparse(
                 jnp.asarray(g.src),
                 jnp.asarray(g.dst),
                 jnp.asarray(g.weight),
-                jnp.asarray(_initial_vector(t0, p)),
-                jnp.asarray(p),
-                jnp.asarray(dangling.astype(np.float32)),
+                jnp.asarray(_initial_vector(t0, ng.p)),
+                jnp.asarray(ng.p),
+                jnp.asarray(ng.dangling),
                 n=g.n,
                 alpha=jax.device_put(np.float32(alpha)),
                 tol=tol,
@@ -346,18 +394,17 @@ class CsrJaxBackend(TrustBackend):
     def converge(self, graph, *, alpha=0.0, tol=1e-6, max_iter=50,
                  record_residuals=True, t0=None):
         with TRACER.span("graph_prep", edges=graph.nnz):
-            g, w, dangling = _normalized(graph)
-            g = _by_dst(graph, g, w)
+            ng = normalize_graph(graph)
+            g = _by_dst(ng)
             row_ptr = g.row_ptr_by_dst()
-            p = graph.pre_trust_vector()
         with TRACER.span("converge", backend=self.name) as span:
             out = converge_csr(
                 jnp.asarray(g.src),
                 jnp.asarray(row_ptr),
                 jnp.asarray(g.weight),
-                jnp.asarray(_initial_vector(t0, p)),
-                jnp.asarray(p),
-                jnp.asarray(dangling.astype(np.float32)),
+                jnp.asarray(_initial_vector(t0, ng.p)),
+                jnp.asarray(ng.p),
+                jnp.asarray(ng.dangling),
                 alpha=jax.device_put(np.float32(alpha)),
                 tol=tol,
                 max_iter=max_iter,
@@ -367,7 +414,7 @@ class CsrJaxBackend(TrustBackend):
 
 
 class WindowedJaxBackend(TrustBackend):
-    """Fused fixed-slot pipeline (PERF.md §7): windowed Pallas
+    """Fused fixed-slot pipeline: windowed Pallas
     gather-multiply from the score table, streamed into VMEM by chunk, + a static
     bucket→dst bridge, so the per-iteration device step performs no
     O(E) random gather.
@@ -378,7 +425,8 @@ class WindowedJaxBackend(TrustBackend):
     plan from a checkpoint — skip construction entirely.  On a
     fingerprint miss with a churn hint (``delta_rows``: the source
     peers whose out-edges changed since the cached plan's graph), the
-    plan is delta-updated in place of a full rebuild (PERF.md §11).
+    plan is delta-updated in place of a full rebuild
+    (``_resolve_plan``).
     """
 
     name = "tpu-windowed"
@@ -399,60 +447,13 @@ class WindowedJaxBackend(TrustBackend):
         #: delta-updated instead of rebuilt.
         self.delta_rows: np.ndarray | None = None
 
-    def _resolve_plan(self, g, w, fp: str) -> WindowPlan:
-        """Reuse, delta-update, or rebuild the cached plan for the
-        normalized graph; counts the outcome on the plan metrics.
-        Delta application is host-side, strictly before any device
-        dispatch (graftlint's plan-mutation-in-converge rule pins the
-        converse)."""
-        plan, rows = self.plan, self.delta_rows
-        self.delta_rows = None
-        valid = plan is not None and getattr(plan, "version", 0) == PLAN_VERSION
-        if valid and plan.fingerprint == fp:
-            PLAN_REUSES.inc()
-            PLAN_OUTCOMES.inc(outcome="reuse")
-            JOURNAL.record("plan", outcome="reuse", backend=self.name)
-            return plan
-        if valid and rows is not None:
-            with TRACER.span("plan", backend=self.name, reason="delta") as span:
-                delta = try_plan_delta(
-                    plan,
-                    g.src,
-                    g.dst,
-                    w,
-                    n=g.n,
-                    rows=rows,
-                    fingerprint=fp,
-                    phase=_plan_phase(span),
-                )
-                if delta is not None:
-                    _plan_layout(span, delta)
-            if delta is not None:
-                PLAN_OUTCOMES.inc(outcome="delta")
-                JOURNAL.record(
-                    "plan", outcome="delta", backend=self.name, rows=int(rows.size)
-                )
-                return delta
-        reason = "cold" if plan is None else (
-            "stale-layout" if not valid else "fingerprint-miss"
-        )
-        with TRACER.span("plan", backend=self.name, reason=reason) as span:
-            plan = build_window_plan(g.src, g.dst, w, n=g.n, phase=_plan_phase(span))
-            _plan_layout(span, plan)
-        PLAN_REBUILDS.inc()
-        PLAN_OUTCOMES.inc(outcome="rebuild")
-        JOURNAL.record("plan", outcome="rebuild", backend=self.name, reason=reason)
-        return plan
-
     def converge(self, graph, *, alpha=0.0, tol=1e-6, max_iter=50,
                  record_residuals=True, t0=None):
         with TRACER.span("graph_prep", edges=graph.nnz):
-            g, w, dangling = _normalized(graph)
-            with TRACER.span("fingerprint"):
-                fp = graph_fingerprint(g.n, g.src, g.dst, w)
-            p = graph.pre_trust_vector()
-            dangling = dangling.astype(np.float32)
-        plan = self._resolve_plan(g, w, fp)
+            ng = normalize_graph(graph)
+            _fingerprint(ng)
+        rows, self.delta_rows = self.delta_rows, None
+        plan = _resolve_plan(self.name, self.plan, ng, rows)
         self.plan = plan
         self.last_plan = plan
         interpret = (
@@ -464,9 +465,9 @@ class WindowedJaxBackend(TrustBackend):
             args = jax.block_until_ready(
                 (
                     *plan.device_args(),
-                    jnp.asarray(_initial_vector(t0, p)),
-                    jnp.asarray(p),
-                    jnp.asarray(dangling),
+                    jnp.asarray(_initial_vector(t0, ng.p)),
+                    jnp.asarray(ng.p),
+                    jnp.asarray(ng.dangling),
                 )
             )
             span.attrs["bytes"] = sum(int(a.nbytes) for a in args)
@@ -526,26 +527,21 @@ class ShardedJaxBackend(TrustBackend):
         )
         problem: ShardedTrustProblem | ShardedWindowPlan
         if self.kernel == "tpu-windowed":
-            candidate, rows = self.plan, self.delta_rows
-            self.delta_rows = None
-            with TRACER.span("plan", backend=name):
-                swp = ShardedWindowPlan.build(
-                    graph, mesh, plan=candidate, delta_rows=rows
-                )
-            if swp.plan_outcome == "reuse":
-                PLAN_REUSES.inc()
-            elif swp.plan_outcome == "rebuild":
-                PLAN_REBUILDS.inc()
-            PLAN_OUTCOMES.inc(outcome=swp.plan_outcome)
-            JOURNAL.record("plan", outcome=swp.plan_outcome, backend=name)
-            self.plan = self.last_plan = swp.plan
-            problem = swp
+            with TRACER.span("graph_prep", edges=graph.nnz):
+                ng = normalize_graph(graph)
+                _fingerprint(ng)
+            rows, self.delta_rows = self.delta_rows, None
+            # The partition and the device placement stay under the
+            # one ``plan`` span the resolution reports into.
+            with TRACER.span("plan", backend=name) as span:
+                plan = _resolve_plan(name, self.plan, ng, rows, span=span)
+                problem = ShardedWindowPlan.build(ng, mesh, plan=plan)
+            self.plan = self.last_plan = plan
         else:
             with TRACER.span("graph_prep", edges=graph.nnz):
-                problem = ShardedTrustProblem.build(graph, mesh)
-        start = (
-            None if t0 is None else _initial_vector(t0, graph.pre_trust_vector())
-        )
+                ng = normalize_graph(graph)
+                problem = ShardedTrustProblem.build(ng, mesh)
+        start = None if t0 is None else _initial_vector(t0, ng.p)
         with TRACER.span("converge", backend=name) as span:
             out = converge_sharded(
                 problem, alpha=alpha, tol=tol, max_iter=max_iter,

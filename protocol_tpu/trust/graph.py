@@ -10,6 +10,7 @@ the arrays it produces.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import cached_property
 
 import numpy as np
 
@@ -102,3 +103,32 @@ class TrustGraph:
         return np.searchsorted(self.dst, np.arange(self.n + 1), side="left").astype(
             np.int32
         )
+
+
+@dataclass(frozen=True, eq=False)
+class NormalizedGraph:
+    """What every edge-list backend iterates, as
+    ``trust.backend.normalize_graph`` builds it: self-edges dropped,
+    weights row-normalized, the dangling mask and the pre-trust vector."""
+
+    n: int
+    src: np.ndarray  # int32 (nnz,), no self-edges
+    dst: np.ndarray  # int32 (nnz,)
+    w: np.ndarray  # float32 (nnz,), row-normalized
+    dangling: np.ndarray  # float32 (n,), 1 where a row has no positive mass
+    p: np.ndarray  # float32 (n,), the pre-trust vector
+
+    @property
+    def nnz(self) -> int:
+        return int(self.src.shape[0])
+
+    @cached_property
+    def fingerprint(self) -> str:
+        """The plan-reuse identity, computed once."""
+        from ..ops.gather_window import graph_fingerprint
+
+        return graph_fingerprint(self.n, self.src, self.dst, self.w)
+
+    def by_dst(self) -> TrustGraph:
+        """The sparse and CSR layout: the edges sorted by destination."""
+        return TrustGraph(self.n, self.src, self.dst, self.w).sorted_by_dst()

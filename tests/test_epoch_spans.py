@@ -133,6 +133,33 @@ def test_csr_backend_prepares_then_converges():
     assert root.children[1].attrs["iterations"] == result.iterations
 
 
+def test_sharded_windowed_backend_prepares_then_plans():
+    """``tpu-sharded:tpu-windowed`` opens the single-chip path's
+    ``graph_prep``, then one ``plan`` span holding the reason, the
+    sub-phases and the partition."""
+    from protocol_tpu.parallel.mesh import default_mesh
+
+    g = erdos_renyi(2048, avg_degree=6.0, seed=3)
+    backend = get_backend("tpu-sharded:tpu-windowed", mesh=default_mesh(4))
+    rows = np.array([7, 1500])
+    for number, graph, delta, reason, phases in (
+        (9106, g, None, "cold", ["plan_bucket", "plan_seg_tables"]),
+        (9107, _churned(g, rows), rows, "delta", DELTA_PHASES),
+    ):
+        backend.delta_rows = delta
+        with TRACER.epoch(number) as root:
+            result = backend.converge(graph, alpha=0.1)
+        assert root.child_names() == ["graph_prep", "plan", "converge"]
+        prep, plan, converge = root.children
+        assert prep.child_names() == ["drop_self_edges", "row_normalize", "fingerprint"]
+        assert prep.attrs["edges"] == graph.nnz
+        assert plan.attrs["backend"] == "tpu-sharded:tpu-windowed"
+        assert plan.attrs["reason"] == reason
+        assert plan.child_names() == phases
+        assert plan.attrs["table_chunks"] == backend.last_plan.table_chunks
+        assert converge.attrs["iterations"] == result.iterations
+
+
 def _recording():
     names: list[str] = []
     counts: dict = {}

@@ -46,6 +46,7 @@ from protocol_tpu.parallel.mesh import default_mesh
 from protocol_tpu.parallel.partition import HostPartition
 from protocol_tpu.parallel.pod import PodContext, PodWindowPlan
 from protocol_tpu.parallel.sharded import ShardedWindowPlan
+from protocol_tpu.trust.backend import normalize_graph
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "tools"))
 
@@ -60,7 +61,7 @@ SHARDED_ARRAYS = (
 
 
 def _graph(n=1024, e=8192, seed=16):
-    return scale_free(n, e, seed=seed)
+    return normalize_graph(scale_free(n, e, seed=seed))
 
 
 def _pod1():
@@ -84,11 +85,12 @@ class TestPodPlanBuild:
         assert pp.plan.fingerprint == sp.plan.fingerprint
         assert pp.host_id == 0 and pp.n_hosts == 1
         assert np.array_equal(pp.owner, np.zeros(g.n, np.int32))
-        assert pp.local_edges == g.drop_self_edges().nnz
+        assert pp.local_edges == g.nnz
         assert pp.plan_outcome == "rebuild" and pp.build_seconds > 0
 
     def test_plan_reuse_and_delta_outcomes(self):
-        g = _graph()
+        raw = scale_free(1024, 8192, seed=16)
+        g = normalize_graph(raw)
         pod = _pod1()
         cold = PodWindowPlan.build(g, pod)
         # Same graph + cached plan: fingerprint revalidates, no build.
@@ -97,10 +99,11 @@ class TestPodPlanBuild:
         assert warm.build_seconds == 0.0
         # Churn one epoch and resolve with the hint: delta path, and
         # the partition arrays match a cold rebuild of the new graph.
-        cohort_size, deg = churn_cohort_dims(g, 0.01)
+        cohort_size, deg = churn_cohort_dims(raw, 0.01)
         rows, g2, _ = sender_centric_churn(
-            np.random.default_rng(16), g, cohort_size=cohort_size, deg=deg
+            np.random.default_rng(16), raw, cohort_size=cohort_size, deg=deg
         )
+        g2 = normalize_graph(g2)
         delta = PodWindowPlan.build(g2, pod, plan=cold.plan, delta_rows=rows)
         rebuilt = PodWindowPlan.build(g2, pod)
         assert delta.plan_outcome == "delta"

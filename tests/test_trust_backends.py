@@ -12,7 +12,7 @@ from protocol_tpu.models.graphs import erdos_renyi, scale_free, sybil_mass, sybi
 from protocol_tpu.ops.dense import converge_dense, filter_and_normalize, set_converge_dense
 from protocol_tpu.parallel.mesh import default_mesh
 from protocol_tpu.parallel.sharded import ShardedTrustProblem, converge_sharded
-from protocol_tpu.trust.backend import get_backend
+from protocol_tpu.trust.backend import get_backend, normalize_graph
 from protocol_tpu.trust.graph import TrustGraph
 from protocol_tpu.trust.native import EigenTrustSet, Opinion, power_iterate_rational
 from protocol_tpu.crypto.eddsa import Signature
@@ -172,7 +172,7 @@ class TestShardedBackend:
     def test_sharded_problem_padding(self):
         # nnz not divisible by the mesh size must zero-pad cleanly.
         g = erdos_renyi(50, avg_degree=3.1, seed=7)
-        problem = ShardedTrustProblem.build(g, default_mesh(8))
+        problem = ShardedTrustProblem.build(normalize_graph(g), default_mesh(8))
         assert problem.src.shape[0] % 8 == 0
         t, it, resid = converge_sharded(problem, alpha=0.2, max_iter=30)
         assert np.asarray(t).sum() == pytest.approx(1.0, rel=1e-5)

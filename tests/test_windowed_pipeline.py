@@ -34,7 +34,7 @@ from protocol_tpu.ops.gather_window import (
     graph_fingerprint,
     power_step_windowed,
 )
-from protocol_tpu.trust.backend import WindowedJaxBackend, get_backend
+from protocol_tpu.trust.backend import WindowedJaxBackend, get_backend, normalize_graph
 from protocol_tpu.trust.graph import TrustGraph
 
 
@@ -324,12 +324,12 @@ class TestWindowPlanCheckpoint:
         store.save(Epoch(1), g, plan=plan)
         restored = store.load_latest().plan
 
-        import protocol_tpu.trust.backend as backend_mod
+        import protocol_tpu.ops.gather_window as gather_window_mod
 
         def boom(*a, **k):  # a fingerprint hit must not reconstruct
             raise AssertionError("plan rebuilt despite checkpoint restore")
 
-        monkeypatch.setattr(backend_mod, "build_window_plan", boom)
+        monkeypatch.setattr(gather_window_mod, "build_window_plan", boom)
         backend = WindowedJaxBackend(plan=restored)
         res = backend.converge(g, alpha=0.1, tol=1e-9, max_iter=40)
         csr = get_backend("tpu-csr").converge(g, alpha=0.1, tol=1e-9, max_iter=40)
@@ -390,7 +390,7 @@ class TestShardedWindowedBackend:
         from protocol_tpu.parallel.mesh import default_mesh
         from protocol_tpu.parallel.sharded import ShardedWindowPlan
 
-        swp = ShardedWindowPlan.build(self._graph(), default_mesh())
+        swp = ShardedWindowPlan.build(normalize_graph(self._graph()), default_mesh())
         dst_ptr = np.asarray(swp.dst_ptr)  # (n_shards, n+1)
         runs_per_shard = dst_ptr[:, -1]
         assert (runs_per_shard > 0).sum() >= 2, runs_per_shard
@@ -414,7 +414,7 @@ class TestShardedWindowedBackend:
     def test_restored_plan_skips_rebuild(self, monkeypatch):
         """A fingerprint-valid WindowPlan seeds the sharded build — the
         checkpoint-restore path shared with the single-device backend."""
-        import protocol_tpu.parallel.sharded as sharded_mod
+        import protocol_tpu.ops.gather_window as gather_window_mod
 
         g = self._graph().drop_self_edges()
         w, _ = g.row_normalized()
@@ -423,7 +423,7 @@ class TestShardedWindowedBackend:
         def boom(*a, **k):
             raise AssertionError("plan rebuilt despite valid seed")
 
-        monkeypatch.setattr(sharded_mod, "build_window_plan", boom)
+        monkeypatch.setattr(gather_window_mod, "build_window_plan", boom)
         backend = get_backend("tpu-sharded:tpu-windowed")
         backend.plan = plan
         res = backend.converge(g, alpha=0.1, tol=1e-9, max_iter=20)

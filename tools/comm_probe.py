@@ -92,6 +92,7 @@ def _worker(process_id: int, coordinator: str, out_path: str,
         _get_runner,
         converge_sharded,
     )
+    from protocol_tpu.trust.backend import normalize_graph
 
     backend = "tpu-sharded:tpu-csr"
     budget = COMM_INVARIANTS[backend]
@@ -106,7 +107,7 @@ def _worker(process_id: int, coordinator: str, out_path: str,
     )
 
     graph = scale_free(n_peers, n_edges, seed=1)
-    prob = ShardedTrustProblem.build(graph, mesh)
+    prob = ShardedTrustProblem.build(normalize_graph(graph), mesh)
     t, iters, resid = converge_sharded(prob, alpha=0.1, tol=1e-6, max_iter=8)
     scores = np.asarray(t)
     result.update(

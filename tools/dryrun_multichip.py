@@ -87,12 +87,14 @@ def _body(n_devices: int, n_peers: int, n_edges: int, iters: int) -> dict:
         windowed_operands,
         windowed_runner,
     )
+    from protocol_tpu.trust.backend import normalize_graph
 
     mesh = default_mesh(n_devices)
     graph = scale_free(n_peers, n_edges, seed=1)
     alpha = jnp.asarray(0.1, jnp.float32)
 
-    prob = ShardedTrustProblem.build(graph, mesh)
+    ng = normalize_graph(graph)
+    prob = ShardedTrustProblem.build(ng, mesh)
     t, iters_run, resid = converge_sharded(
         prob, alpha=0.1, tol=1e-6, max_iter=iters
     )
@@ -100,7 +102,7 @@ def _body(n_devices: int, n_peers: int, n_edges: int, iters: int) -> dict:
     assert scores.shape == (graph.n,)
     assert abs(float(scores.sum()) - 1.0) < 1e-3
 
-    swp = ShardedWindowPlan.build(graph, mesh)
+    swp = ShardedWindowPlan.build(ng, mesh)
     tw, _, _ = converge_sharded(swp, alpha=0.1, tol=1e-6, max_iter=iters)
     drift = float(np.abs(np.asarray(tw) - scores).sum())
     assert drift < 1e-4, f"windowed sharded kernel drifted from csr: {drift}"

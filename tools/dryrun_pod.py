@@ -272,6 +272,7 @@ def worker_main(args) -> int:
     from protocol_tpu.parallel.mesh import SHARD_AXIS
     from protocol_tpu.parallel.pod import PodContext, PodWindowPlan
     from protocol_tpu.parallel.sharded import converge_sharded
+    from protocol_tpu.trust.backend import normalize_graph
     from protocol_tpu.trust.graph import TrustGraph
 
     pod = PodContext.current(seed=args.seed)
@@ -437,7 +438,7 @@ def worker_main(args) -> int:
                 # sync stamps share a clock base with the
                 # clock_sync_samples() pairs the stitcher aligns on.
                 podplan = PodWindowPlan.build(
-                    cur, pod, plan=plan, delta_rows=rows,
+                    normalize_graph(cur), pod, plan=plan, delta_rows=rows,
                     clock=time.monotonic, wall=time.time,
                 )
                 plan_update_seconds = time.perf_counter() - t_plan
@@ -712,24 +713,25 @@ def reference_main(args) -> int:
     from protocol_tpu.parallel.partition import HostPartition
     from protocol_tpu.parallel.pod import PodContext, PodWindowPlan
     from protocol_tpu.parallel.sharded import converge_sharded
+    from protocol_tpu.trust.backend import normalize_graph
 
     result: dict = {}
     cur = bootstrap_graph(args)
-    w, _ = cur.row_normalized()
+    ng = normalize_graph(cur)
 
     # Serial full build (the PERF.md §11 bottleneck) vs the per-host
     # partitioned builds, run back-to-back in ONE process so neither
     # side pays multi-process core contention: the pod's plan-build
     # critical path is the slowest partition.
     t0 = time.perf_counter()
-    build_window_plan(cur.src, cur.dst, w, n=cur.n)
+    build_window_plan(ng.src, ng.dst, ng.w, n=ng.n)
     serial = time.perf_counter() - t0
     owner = HostPartition(args.processes, seed=args.seed).assign_ids(cur.n)
     per_part = []
     for h in range(args.processes):
-        m = owner[cur.src] == h
+        m = owner[ng.src] == h
         t0 = time.perf_counter()
-        build_window_plan(cur.src[m], cur.dst[m], w[m], n=cur.n)
+        build_window_plan(ng.src[m], ng.dst[m], ng.w[m], n=ng.n)
         per_part.append(round(time.perf_counter() - t0, 4))
     critical = max(per_part)
     result.update(
@@ -744,7 +746,7 @@ def reference_main(args) -> int:
     for e in range(1, args.epochs):
         _, cur, _ = churn_epoch(cur, e, args)
     pod = PodContext.current(seed=args.seed)  # single process
-    podplan = PodWindowPlan.build(cur, pod, clock=time.perf_counter)
+    podplan = PodWindowPlan.build(normalize_graph(cur), pod, clock=time.perf_counter)
     t, iters, resid = converge_sharded(
         podplan, alpha=0.1, tol=args.tol, max_iter=args.max_iter
     )

@@ -177,6 +177,7 @@ def _worker(process_id: int, coordinator: str, out_path: str) -> int:
         _get_runner,
         converge_sharded,
     )
+    from protocol_tpu.trust.backend import normalize_graph
 
     backend = "tpu-sharded:tpu-csr"
     budget = MEM_INVARIANTS[backend]
@@ -184,7 +185,7 @@ def _worker(process_id: int, coordinator: str, out_path: str) -> int:
     n_shards = mesh.shape[SHARD_AXIS]
 
     graph = scale_free(PROBE_PEERS, PROBE_EDGES, seed=1)
-    prob = ShardedTrustProblem.build(graph, mesh)
+    prob = ShardedTrustProblem.build(normalize_graph(graph), mesh)
     t, iters, resid = converge_sharded(prob, alpha=0.1, tol=1e-6, max_iter=8)
     scores = np.asarray(t)
 
