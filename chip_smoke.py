@@ -271,6 +271,9 @@ async def node_epoch(interval: int, timeout_s: float) -> None:
     from protocol_tpu.obs.timeline import TIMELINE
 
     known = set(TIMELINE.epochs())
+    # This node's anomalies alone: the journal is the process's, and an
+    # earlier node in the same process may have recorded its own.
+    since = max((e["seq"] for e in JOURNAL.tail()), default=0)
     node = Node.from_config(
         ProtocolConfig(
             epoch_interval=interval,
@@ -292,7 +295,8 @@ async def node_epoch(interval: int, timeout_s: float) -> None:
             check(time.perf_counter() - start < timeout_s, "node: no proven epoch in time")
             failures = [
                 e for e in JOURNAL.tail()
-                if e.get("kind") == "anomaly" and e.get("what") in EPOCH_FAILURES
+                if e["seq"] > since
+                and e.get("kind") == "anomaly" and e.get("what") in EPOCH_FAILURES
             ]
             check(not failures, f"node: {failures}")
             await asyncio.sleep(0.25)

@@ -372,6 +372,14 @@ PEER_ALIGN = METRICS.counter(
     "extended) or search (a verified hash search)",
     labelnames=("site", "path"),
 )
+EDGE_WEIGHTS = METRICS.counter(
+    "eigentrust_edge_weights_total",
+    "Materializations of row-normalized edge weights by path: rows "
+    "(only selected edges divided: a plan delta's re-attested rows, the "
+    "fingerprint's samples) or full (the whole array: a plan rebuild, "
+    "another backend, an analyzer)",
+    labelnames=("path",),
+)
 EPOCH_TICKS_COALESCED = METRICS.counter(
     "eigentrust_epoch_ticks_coalesced_total",
     "Epoch ticks superseded by a newer one while waiting in the "
@@ -726,6 +734,7 @@ __all__ = [
     "PLAN_REUSES",
     "PLAN_OUTCOMES",
     "PEER_ALIGN",
+    "EDGE_WEIGHTS",
     "EPOCH_TICKS_COALESCED",
     "PIPELINE_QUEUE_DEPTH",
     "WARM_START_APPLIED",

@@ -121,6 +121,15 @@ def _no_attempt(reason: str, make: Callable[[PlanPhase], object]) -> object:
     return make(_no_phase)
 
 
+#: The row-normalized edge weights, read by selection: ``weights(sel)``
+#: returns float32 ``w[sel]`` for a boolean mask, an index array or a
+#: slice, ``slice(None)`` being the whole array.  A caller that divides
+#: on demand (``trust.graph.NormalizedGraph.weights_of``) lets a plan
+#: delta and the fingerprint divide only the edges they read; an array
+#: ``w`` is passed as ``w.__getitem__``.
+EdgeWeights = Callable[[object], np.ndarray]
+
+
 def _counting_sort(
     key: np.ndarray, n_keys: int, payload: np.ndarray | None = None
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
@@ -1061,16 +1070,19 @@ class WindowPlan:
         )
 
 
-def graph_fingerprint(n: int, src: np.ndarray, dst: np.ndarray, w: np.ndarray) -> str:
+def graph_fingerprint(
+    n: int, src: np.ndarray, dst: np.ndarray, weights: EdgeWeights
+) -> str:
     """Cheap identity for plan-reuse validation: exact (n, nnz) plus a
     sha1 over strided samples of the edge arrays (hashing all 600 MB at
     bench scale would cost a meaningful fraction of plan construction;
-    a strided digest catches every realistic graph change)."""
+    a strided digest catches every realistic graph change).  The
+    weights are read at the samples alone."""
     h = hashlib.sha1()
     h.update(np.asarray([n, src.shape[0]], np.int64).tobytes())
-    stride = max(1, src.shape[0] // (1 << 20))
-    for a in (src, dst, w):
-        h.update(np.ascontiguousarray(a[::stride]).tobytes())
+    samples = slice(None, None, max(1, src.shape[0] // (1 << 20)))
+    for a in (src[samples], dst[samples], weights(samples)):
+        h.update(np.ascontiguousarray(a).tobytes())
     return h.hexdigest()
 
 
@@ -1126,7 +1138,7 @@ def build_window_plan(
         dst_ptr=dst_ptr,
         seg_dst=b["seg_dst"],
         row_offset=b["row_offset"],
-        fingerprint=graph_fingerprint(n, src, dst, w),
+        fingerprint=graph_fingerprint(n, src, dst, w.__getitem__),
         table_chunk=table_chunk,
         order=b["order"],
         out_pos=b["out_pos"],
@@ -1138,7 +1150,7 @@ def try_plan_delta(
     plan: WindowPlan,
     src: np.ndarray,
     dst: np.ndarray,
-    w: np.ndarray,
+    weights: EdgeWeights,
     *,
     n: int,
     rows: np.ndarray,
@@ -1149,7 +1161,8 @@ def try_plan_delta(
     the hinted ``rows`` (every source peer whose attestation changed
     since the plan's graph — row normalization rewrites exactly those
     rows) with their slice of the new normalized edge list
-    ``(src, dst, w)``.  Returns the delta-updated plan, or None when the
+    ``(src, dst, weights)``, whose weights are read for those rows
+    alone.  Returns the delta-updated plan, or None when the
     delta cannot be applied (overflow, shrink), when it would not pay
     (churn spread over too many windows — past the measured crossover
     a full rebuild's vectorized counting sorts beat the per-window
@@ -1176,8 +1189,8 @@ def try_plan_delta(
     if touched_windows > max(64, data_windows // 4):
         return None
     with phase("plan_select", rows=int(rows.size), touched_windows=touched_windows):
-        mask = np.isin(src, rows.astype(src.dtype))
-        new_src, new_dst, new_w = src[mask], dst[mask], w[mask]
+        sel = np.flatnonzero(np.isin(src, rows.astype(src.dtype)))
+        new_src, new_dst, new_w = src[sel], dst[sel], weights(sel)
     try:
         new_plan = plan.replace_rows(
             rows, new_src, new_dst, new_w, n=n, fingerprint=fingerprint, phase=phase
@@ -1195,30 +1208,34 @@ def resolve_plan(
     candidate: WindowPlan | None,
     src: np.ndarray,
     dst: np.ndarray,
-    w: np.ndarray,
+    weights: EdgeWeights,
     *,
     n: int,
     fingerprint: str,
     rows: np.ndarray | None = None,
     attempt: PlanAttempt = _no_attempt,
 ) -> tuple[WindowPlan, str]:
-    """The plan for the normalized edge list ``(src, dst, w)``: reuse
-    ``candidate`` (cached or checkpoint-restored) while its layout
+    """The plan for the normalized edge list ``(src, dst, weights)``:
+    reuse ``candidate`` (cached or checkpoint-restored) while its layout
     version and ``fingerprint`` hold; else, given the churn hint
-    ``rows``, delta-update it (``try_plan_delta``); else rebuild.
-    Returns the plan and the outcome: ``reuse``, ``delta`` or
-    ``rebuild``."""
+    ``rows``, delta-update it (``try_plan_delta``, which reads the
+    hinted rows' weights alone); else rebuild from the whole weight
+    array, read once inside the attempt.  Returns the plan and the
+    outcome: ``reuse``, ``delta`` or ``rebuild``."""
     valid = candidate is not None and getattr(candidate, "version", 0) == PLAN_VERSION
     if valid and candidate.fingerprint == fingerprint:
         return candidate, "reuse"
     if valid and rows is not None:
         delta = attempt("delta", lambda phase: try_plan_delta(
-            candidate, src, dst, w, n=n, rows=rows, fingerprint=fingerprint, phase=phase
+            candidate, src, dst, weights, n=n, rows=rows, fingerprint=fingerprint,
+            phase=phase,
         ))
         if delta is not None:
             return delta, "delta"
     reason = "cold" if candidate is None else "fingerprint-miss" if valid else "stale-layout"
-    plan = attempt(reason, lambda phase: build_window_plan(src, dst, w, n=n, phase=phase))
+    plan = attempt(reason, lambda phase: build_window_plan(
+        src, dst, weights(slice(None)), n=n, phase=phase
+    ))
     return plan, "rebuild"
 
 

@@ -187,10 +187,10 @@ class PodWindowPlan:
         owned_rows, lsrc, ldst, lw = partition_delta(
             delta_rows, graph.src, graph.dst, graph.w, owner, pod.host_id
         )
-        fp = graph_fingerprint(graph.n, lsrc, ldst, lw)
+        fp = graph_fingerprint(graph.n, lsrc, ldst, lw.__getitem__)
         t_build = clock() if clock is not None else 0.0
         plan, outcome = resolve_plan(
-            plan, lsrc, ldst, lw, n=graph.n, fingerprint=fp, rows=owned_rows
+            plan, lsrc, ldst, lw.__getitem__, n=graph.n, fingerprint=fp, rows=owned_rows
         )
         build_seconds = 0.0
         if clock is not None and outcome != "reuse":

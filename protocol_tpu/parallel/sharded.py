@@ -341,7 +341,7 @@ class ShardedWindowPlan:
         ``delta_rows`` churn hint, else rebuilt — the ``plan_outcome``
         field reports which path ran."""
         plan, outcome = resolve_plan(
-            plan, graph.src, graph.dst, graph.w,
+            plan, graph.src, graph.dst, graph.weights_of,
             n=graph.n, fingerprint=graph.fingerprint, rows=delta_rows,
         )
 

@@ -115,17 +115,24 @@ def normalize_graph(graph: TrustGraph) -> NormalizedGraph:
     ``tpu-csr``, ``tpu-windowed``, both ``tpu-sharded`` kernels, the pod
     builder and the analyzers): self-edges dropped, weights
     row-normalized, the dangling mask and the pre-trust vector, each
-    step under its own span inside the caller's ``graph_prep``.
+    step under its own span inside the caller's ``graph_prep``.  The
+    per-edge weights are divided where a caller reads them
+    (``NormalizedGraph.w``, ``weights_of``): a plan delta reads only
+    its re-attested rows, a rebuild the whole array under its ``plan``
+    span.
 
     ``native-cpu`` (exact rationals) and ``tpu-dense`` (a dense matrix
     normalized by its own row sums) keep their own arithmetic: routing
     them through float32 per-edge weights would change their numbers."""
-    with TRACER.span("drop_self_edges"):
+    with TRACER.span("drop_self_edges") as span:
         g = graph.drop_self_edges()
+        span.attrs["self_edges"] = graph.nnz - g.nnz
     with TRACER.span("row_normalize"):
-        w, dangling = g.row_normalized()
+        safe, dangling = g.row_sums()
         dangling = dangling.astype(np.float32)
-    return NormalizedGraph(g.n, g.src, g.dst, w, dangling, graph.pre_trust_vector())
+    return NormalizedGraph(
+        g.n, g.src, g.dst, g.weight, safe, dangling, graph.pre_trust_vector()
+    )
 
 
 def _by_dst(ng: NormalizedGraph) -> TrustGraph:
@@ -182,7 +189,7 @@ def _resolve_plan(
         return plan
 
     plan, outcome = resolve_plan(
-        candidate, ng.src, ng.dst, ng.w,
+        candidate, ng.src, ng.dst, ng.weights_of,
         n=ng.n, fingerprint=ng.fingerprint, rows=rows, attempt=attempt,
     )
     if outcome == "reuse":

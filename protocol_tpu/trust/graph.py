@@ -14,6 +14,8 @@ from functools import cached_property
 
 import numpy as np
 
+from ..obs.metrics import EDGE_WEIGHTS
+
 
 @dataclass
 class TrustGraph:
@@ -58,11 +60,25 @@ class TrustGraph:
         return ops
 
     def drop_self_edges(self) -> "TrustGraph":
-        """EigenTrust nullifies self-scores (native.rs:183-191)."""
+        """EigenTrust nullifies self-scores (native.rs:183-191).  A graph
+        with no self-edge is returned as it is, its arrays uncopied."""
         keep = self.src != self.dst
+        if keep.all():
+            return self
         return TrustGraph(
             self.n, self.src[keep], self.dst[keep], self.weight[keep], self.pre_trusted
         )
+
+    def row_sums(self) -> tuple[np.ndarray, np.ndarray]:
+        """Return ``(safe, dangling mask)``: each row's (sender's) weight
+        sum in float64, added in edge order, with 1.0 in place of the
+        sum of a row with no positive mass.  Such rows are *dangling*;
+        device kernels redistribute their rank to the pre-trust
+        vector."""
+        sums = np.zeros(self.n, dtype=np.float64)
+        np.add.at(sums, self.src, self.weight.astype(np.float64))
+        dangling = sums <= 0
+        return np.where(dangling, 1.0, sums), dangling
 
     def row_normalized(self) -> tuple[np.ndarray, np.ndarray]:
         """Return ``(normalized weights, dangling mask)``.
@@ -70,15 +86,9 @@ class TrustGraph:
         Weights are divided by their row (sender) sum — the local-trust
         normalization ``c_ij = s_ij / Σ_j s_ij`` of the EigenTrust paper
         and of the set kernel's credit distribution (native.rs:89-102).
-        Rows with no positive mass are *dangling*; device kernels
-        redistribute their rank to the pre-trust vector.
         """
-        sums = np.zeros(self.n, dtype=np.float64)
-        np.add.at(sums, self.src, self.weight.astype(np.float64))
-        dangling = sums <= 0
-        safe = np.where(dangling, 1.0, sums)
-        w = (self.weight.astype(np.float64) / safe[self.src]).astype(np.float32)
-        return w, dangling
+        safe, dangling = self.row_sums()
+        return _divide(self.weight, self.src, safe), dangling
 
     def pre_trust_vector(self) -> np.ndarray:
         """p: uniform over the pre-trusted set, or uniform over all peers
@@ -105,16 +115,29 @@ class TrustGraph:
         )
 
 
+def _divide(weight: np.ndarray, src: np.ndarray, safe: np.ndarray) -> np.ndarray:
+    """Each edge's weight over its row's sum, in float64, rounded to
+    float32: elementwise, so any selection of edges divides to the bits
+    the whole array would hold there."""
+    return (weight.astype(np.float64) / safe[src]).astype(np.float32)
+
+
 @dataclass(frozen=True, eq=False)
 class NormalizedGraph:
     """What every edge-list backend iterates, as
     ``trust.backend.normalize_graph`` builds it: self-edges dropped,
-    weights row-normalized, the dangling mask and the pre-trust vector."""
+    the row sums, the dangling mask and the pre-trust vector.  The
+    row-normalized weights are divided on demand: ``w`` builds the whole
+    array once, at its first read; ``weights_of`` divides only the
+    edges a caller selects (a plan delta reads its re-attested rows,
+    the fingerprint its strided samples), each counted on
+    ``eigentrust_edge_weights_total{path}``."""
 
     n: int
     src: np.ndarray  # int32 (nnz,), no self-edges
     dst: np.ndarray  # int32 (nnz,)
-    w: np.ndarray  # float32 (nnz,), row-normalized
+    weight: np.ndarray  # float32 (nnz,), as attested
+    safe: np.ndarray  # float64 (n,), row sums, 1.0 where dangling
     dangling: np.ndarray  # float32 (n,), 1 where a row has no positive mass
     p: np.ndarray  # float32 (n,), the pre-trust vector
 
@@ -123,11 +146,26 @@ class NormalizedGraph:
         return int(self.src.shape[0])
 
     @cached_property
+    def w(self) -> np.ndarray:
+        """The row-normalized weights, float32 (nnz,), built once."""
+        EDGE_WEIGHTS.inc(path="full")
+        return _divide(self.weight, self.src, self.safe)
+
+    def weights_of(self, sel) -> np.ndarray:
+        """``w[sel]`` for a boolean mask, an index array or a slice,
+        dividing only the selected edges; the whole selection
+        (``slice(None)``) reads ``w``."""
+        if isinstance(sel, slice) and sel == slice(None):
+            return self.w
+        EDGE_WEIGHTS.inc(path="rows")
+        return _divide(self.weight[sel], self.src[sel], self.safe)
+
+    @cached_property
     def fingerprint(self) -> str:
-        """The plan-reuse identity, computed once."""
+        """The plan-reuse identity, computed once from strided samples."""
         from ..ops.gather_window import graph_fingerprint
 
-        return graph_fingerprint(self.n, self.src, self.dst, self.w)
+        return graph_fingerprint(self.n, self.src, self.dst, self.weights_of)
 
     def by_dst(self) -> TrustGraph:
         """The sparse and CSR layout: the edges sorted by destination."""

@@ -286,7 +286,7 @@ class TestApplyDelta:
         full_dst = np.concatenate([g.dst[keep], nd])
         full_w = np.concatenate([w[keep], nw])
         out = try_plan_delta(
-            plan, full_src, full_dst, full_w, n=g.n, rows=rows, fingerprint="of"
+            plan, full_src, full_dst, full_w.__getitem__, n=g.n, rows=rows, fingerprint="of"
         )
         assert out is None
 
@@ -302,7 +302,7 @@ class TestApplyDelta:
             plan,
             g.src[keep],
             g.dst[keep],
-            w[keep],
+            w[keep].__getitem__,
             n=g.n,
             rows=np.array([1]),
             fingerprint="stale",

@@ -79,6 +79,19 @@ def test_delta_epoch_plan_holds_the_delta_sub_phases(epochs):
 
 
 @pytest.mark.parametrize("which", [0, 1])
+def test_graph_prep_counts_the_self_edges_it_drops(epochs, which):
+    _, roots = epochs
+    root, _, _, prepared = roots[which]
+    (prep,) = [c for c in root.children if c.name == "graph_prep"]
+    assert prep.child_names() == ["drop_self_edges", "row_normalize", "fingerprint"]
+    graph = prepared.graph
+    dropped = int(np.count_nonzero(graph.src == graph.dst))
+    assert prep.children[0].attrs["self_edges"] == dropped
+    if which == 0:
+        assert dropped > 0  # the case holds what it is named for
+
+
+@pytest.mark.parametrize("which", [0, 1])
 def test_converge_closes_on_its_result_before_the_monitors(epochs, which):
     _, roots = epochs
     root, result, _, _ = roots[which]
@@ -185,9 +198,9 @@ def test_phase_hook_leaves_the_plan_unchanged():
     g2 = _churned(TrustGraph(g.n, src, dst, w, g.pre_trusted), rows)
     w2, _ = g2.row_normalized()
     args = dict(n=g.n, rows=rows, fingerprint="next")
-    plain = try_plan_delta(built, g2.src, g2.dst, w2, **args)
+    plain = try_plan_delta(built, g2.src, g2.dst, w2.__getitem__, **args)
     phase, names, counts = _recording()
-    hooked = try_plan_delta(built, g2.src, g2.dst, w2, phase=phase, **args)
+    hooked = try_plan_delta(built, g2.src, g2.dst, w2.__getitem__, phase=phase, **args)
     assert names == DELTA_PHASES
     assert counts == {"rows": 3, "touched_windows": 3, "delta_edges": 12}
     for k in WindowPlan._CORE + WindowPlan._HOST:

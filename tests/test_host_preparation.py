@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from protocol_tpu.models.graphs import erdos_renyi, scale_free
+from protocol_tpu.obs.metrics import EDGE_WEIGHTS
 from protocol_tpu.ops.gather_window import (
     WindowPlan,
     build_window_plan,
@@ -41,38 +42,91 @@ def _scale_free_dangling_unseeded() -> TrustGraph:
     return TrustGraph(g.n, g.src[keep], g.dst[keep], g.weight[keep], None)
 
 
-@pytest.mark.parametrize(
-    "make", [_erdos_renyi_with_self_edges, _scale_free_dangling_unseeded],
-    ids=["erdos-renyi-self-edges", "scale-free-dangling-no-pre-trust"],
+def _scale_free_without_self_edges() -> TrustGraph:
+    g = scale_free(1200, 7000, seed=5)
+    keep = g.src != g.dst
+    return TrustGraph(g.n, g.src[keep], g.dst[keep], g.weight[keep], g.pre_trusted)
+
+
+GRAPHS = pytest.mark.parametrize(
+    "make",
+    [_erdos_renyi_with_self_edges, _scale_free_dangling_unseeded,
+     _scale_free_without_self_edges],
+    ids=["erdos-renyi-self-edges", "scale-free-dangling-no-pre-trust",
+         "scale-free-no-self-edges"],
 )
-def test_normalize_graph_is_the_recipe_bit_for_bit(make):
-    graph = make()
-    # The recipe every backend, builder and analyzer ran on its own.
-    g = graph.drop_self_edges()
-    w, dangling = g.row_normalized()
-    want = {
-        "src": g.src,
-        "dst": g.dst,
+
+
+def _recipe(graph: TrustGraph) -> dict:
+    """The normalization every backend, builder and analyzer ran on its
+    own before ``normalize_graph``, spelled out: the whole weight array
+    divided at once."""
+    keep = graph.src != graph.dst
+    src, dst, weight = graph.src[keep], graph.dst[keep], graph.weight[keep]
+    sums = np.zeros(graph.n, dtype=np.float64)
+    np.add.at(sums, src, weight.astype(np.float64))
+    dangling = sums <= 0
+    safe = np.where(dangling, 1.0, sums)
+    w = (weight.astype(np.float64) / safe[src]).astype(np.float32)
+    return {
+        "src": src,
+        "dst": dst,
         "w": w,
         "dangling": dangling.astype(np.float32),
         "p": graph.pre_trust_vector(),
     }
+
+
+@GRAPHS
+def test_normalize_graph_is_the_recipe_bit_for_bit(make):
+    graph = make()
+    want = _recipe(graph)
+    src, dst, w = want["src"], want["dst"], want["w"]
     ng = normalize_graph(graph)
-    assert ng.n == graph.n and ng.nnz == g.nnz
+    assert ng.n == graph.n and ng.nnz == src.size
     for name, a in want.items():
         b = getattr(ng, name)
         assert b.dtype == a.dtype and b.tobytes() == a.tobytes(), name
     # The case holds what it is named for.
     if graph.pre_trusted is None:
-        assert dangling.any() and np.all(ng.p == ng.p[0])
-    else:
-        assert (graph.src == graph.dst).any() and ng.nnz < graph.nnz
+        assert want["dangling"].any() and np.all(ng.p == ng.p[0])
+    if (graph.src == graph.dst).any():
+        assert ng.nnz < graph.nnz
+    else:  # nothing to drop: the arrays are the graph's own
+        assert np.shares_memory(ng.src, graph.src)
+        assert np.shares_memory(ng.dst, graph.dst)
     # The fingerprint and the dst-sorted layout, each derived once.
-    assert ng.fingerprint == graph_fingerprint(g.n, g.src, g.dst, w)
-    ref = TrustGraph(g.n, g.src, g.dst, w, graph.pre_trusted).sorted_by_dst()
+    assert ng.fingerprint == graph_fingerprint(graph.n, src, dst, w.__getitem__)
+    ref = TrustGraph(graph.n, src, dst, w, graph.pre_trusted).sorted_by_dst()
     got = ng.by_dst()
     for name in ("src", "dst", "weight"):
         assert getattr(got, name).tobytes() == getattr(ref, name).tobytes(), name
+
+
+def _selection(kind: str, src: np.ndarray):
+    rows = np.unique(src)[::5]
+    mask = np.isin(src, rows)
+    return {
+        "mask": mask,
+        "index": np.flatnonzero(mask),
+        "strided": slice(None, None, 3),
+    }[kind]
+
+
+@GRAPHS
+@pytest.mark.parametrize("kind", ["mask", "index", "strided"])
+def test_weights_of_divides_the_selection_to_the_recipe_bits(make, kind):
+    graph = make()
+    w = _recipe(graph)["w"]
+    ng = normalize_graph(graph)
+    sel = _selection(kind, ng.src)
+    rows = EDGE_WEIGHTS.value(path="rows")
+    got = ng.weights_of(sel)
+    assert got.dtype == np.float32 and got.tobytes() == w[sel].tobytes()
+    # Only the selection was divided: the whole array stays unbuilt.
+    assert "w" not in ng.__dict__
+    assert EDGE_WEIGHTS.value(path="rows") == rows + 1
+    assert ng.weights_of(slice(None)) is ng.w
 
 
 def _arrays(graph: TrustGraph):
@@ -152,7 +206,7 @@ def test_resolve_plan_outcome_and_reason(base, name, outcome, attempts):
         return make(phase)
 
     plan, got = resolve_plan(
-        candidate, src, dst, w, n=n, fingerprint=fp, rows=rows, attempt=attempt
+        candidate, src, dst, w.__getitem__, n=n, fingerprint=fp, rows=rows, attempt=attempt
     )
     assert got == outcome
     assert [r for r, _ in seen] == [r for r, _ in attempts]
@@ -169,6 +223,8 @@ def test_resolve_plan_outcome_and_reason(base, name, outcome, attempts):
     else:
         assert plan.lineage[-1] == candidate.fingerprint
     # The default hook runs the same decision unrecorded.
-    quiet, quiet_outcome = resolve_plan(candidate, src, dst, w, n=n, fingerprint=fp, rows=rows)
+    quiet, quiet_outcome = resolve_plan(
+        candidate, src, dst, w.__getitem__, n=n, fingerprint=fp, rows=rows
+    )
     assert quiet_outcome == outcome and quiet.fingerprint == fp
 

@@ -154,8 +154,8 @@ def _grow_window(plan, src, dst, w, window, rng):
     w = np.concatenate([w[keep], rng.random(k, dtype=np.float32) + 0.01])
     dst[src == dst] = (dst[src == dst] + 1) % n
     wn = _normalized(n, src, dst, w)
-    fp = graph_fingerprint(n, src, dst, wn)
-    plan = try_plan_delta(plan, src, dst, wn, n=n, rows=rows, fingerprint=fp)
+    fp = graph_fingerprint(n, src, dst, wn.__getitem__)
+    plan = try_plan_delta(plan, src, dst, wn.__getitem__, n=n, rows=rows, fingerprint=fp)
     assert plan is not None, f"growing window {window} fell back to a rebuild"
     rs, rd, _ = plan.recovered_edges()
     mine = np.sort(rs.astype(np.int64) << 32 | rd)
@@ -221,8 +221,8 @@ def test_delta_across_chunk_boundaries_matches_a_rebuild(chunk):
     keep = src != dst
     src, dst, w = src[keep], dst[keep], w[keep]
     wn = _normalized(n, src, dst, w)
-    fp = graph_fingerprint(n, src, dst, wn)
-    delta = try_plan_delta(plan, src, dst, wn, n=n, rows=rows, fingerprint=fp)
+    fp = graph_fingerprint(n, src, dst, wn.__getitem__)
+    delta = try_plan_delta(plan, src, dst, wn.__getitem__, n=n, rows=rows, fingerprint=fp)
     assert delta is not None
     _assert_one_chunk_per_block(delta)
     rebuilt = build_window_plan(src, dst, wn, n=n)
@@ -255,8 +255,8 @@ def test_peers_that_join_past_the_first_chunk_stream_the_table(chunk):
     w = np.concatenate([w, rng.random(k, dtype=np.float32) + 0.01])
     dst[src == dst] = (dst[src == dst] + 1) % n_new
     wn = _normalized(n_new, src, dst, w)
-    fp = graph_fingerprint(n_new, src, dst, wn)
-    delta = try_plan_delta(plan, src, dst, wn, n=n_new, rows=rows, fingerprint=fp)
+    fp = graph_fingerprint(n_new, src, dst, wn.__getitem__)
+    delta = try_plan_delta(plan, src, dst, wn.__getitem__, n=n_new, rows=rows, fingerprint=fp)
     assert delta is not None and delta.table_chunks == 3
     _assert_one_chunk_per_block(delta)
     rebuilt = build_window_plan(src, dst, wn, n=n_new)
@@ -276,8 +276,8 @@ def test_a_delta_keeps_the_segment_capacity_where_runs_are_many_per_edge():
     keep = ~np.isin(src, rows)
     src, dst, w = src[keep], dst[keep], w[keep]
     wn = _normalized(n, src, dst, w)
-    fp = graph_fingerprint(n, src, dst, wn)
-    delta = try_plan_delta(plan, src, dst, wn, n=n, rows=rows, fingerprint=fp)
+    fp = graph_fingerprint(n, src, dst, wn.__getitem__)
+    delta = try_plan_delta(plan, src, dst, wn.__getitem__, n=n, rows=rows, fingerprint=fp)
     assert delta is not None and delta.n_segments < plan.n_segments
     assert (delta.n_rows, delta.seg_capacity) == (plan.n_rows, plan.seg_capacity)
 

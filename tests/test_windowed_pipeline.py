@@ -352,12 +352,109 @@ class TestWindowPlanCheckpoint:
     def test_fingerprint_sensitivity(self):
         g = scale_free(700, 4000, seed=16).drop_self_edges()
         w, _ = g.row_normalized()
-        fp = graph_fingerprint(g.n, g.src, g.dst, w)
-        assert fp == graph_fingerprint(g.n, g.src, g.dst, w)  # deterministic
+        fp = graph_fingerprint(g.n, g.src, g.dst, w.__getitem__)
+        assert fp == graph_fingerprint(g.n, g.src, g.dst, w.__getitem__)  # deterministic
         w2 = w.copy()
         w2[0] += 0.5
-        assert fp != graph_fingerprint(g.n, g.src, g.dst, w2)
-        assert fp != graph_fingerprint(g.n + 1, g.src, g.dst, w)
+        assert fp != graph_fingerprint(g.n, g.src, g.dst, w2.__getitem__)
+        assert fp != graph_fingerprint(g.n + 1, g.src, g.dst, w.__getitem__)
+
+
+def _rewritten(g: TrustGraph, rows, per_row: int, seed: int) -> TrustGraph:
+    """``g`` with the out-rows of ``rows`` re-attested, ``per_row``
+    edges each (a self-edge among them is dropped by normalization)."""
+    rng = np.random.default_rng(seed)
+    rows = np.asarray(rows, np.int32)
+    keep = ~np.isin(g.src, rows)
+    k = per_row * rows.size
+    return TrustGraph(
+        g.n,
+        np.concatenate([g.src[keep], np.repeat(rows, per_row)]),
+        np.concatenate([g.dst[keep], rng.integers(0, g.n, k).astype(np.int32)]),
+        np.concatenate([g.weight[keep], rng.integers(1, 100, k).astype(np.float32)]),
+        g.pre_trusted,
+    )
+
+
+class TestWeightsOnDemand:
+    """The windowed backend divides per-edge weights where the plan
+    reads them: a delta epoch its re-attested rows and the fingerprint
+    its samples, a rebuild the whole array.  The plans, fingerprints,
+    scores and iteration counts equal those of the same chain read
+    through the whole array."""
+
+    OUTCOMES = ("reuse", "delta", "rebuild")
+    PATHS = ("rows", "full")
+
+    def _chain(self, monkeypatch):
+        """cold, three deltas, a reuse, then a delta past the plan's
+        spare rows that falls back to a rebuild: per epoch the outcome,
+        the counter's growth by path, the normalized graph, the plan and
+        the result."""
+        import protocol_tpu.trust.backend as backend_mod
+
+        from protocol_tpu.obs.metrics import EDGE_WEIGHTS, PLAN_OUTCOMES
+
+        seen = []
+
+        def recording(graph):
+            ng = normalize_graph(graph)
+            seen.append(ng)
+            return ng
+
+        monkeypatch.setattr(backend_mod, "normalize_graph", recording)
+        g = erdos_renyi(2048, avg_degree=6.0, seed=41)
+        backend = WindowedJaxBackend()
+        out = []
+        hints = [None, [7, 1500], [100, 2000], [5, 1024, 1025], None, None]
+        for epoch, rows in enumerate(hints):
+            if epoch in (1, 2, 3):
+                g = _rewritten(g, rows, 6, seed=epoch)
+            elif epoch == 4:  # the same graph again
+                g = TrustGraph(g.n, g.src, g.dst, g.weight, g.pre_trusted)
+            elif epoch == 5:  # more edges in window 0 than the plan has rows
+                plan = backend.last_plan
+                rows = np.arange(0, 100)
+                g = _rewritten(g, rows, -(-(plan.n_rows + 1) * ROW // 100), seed=epoch)
+            before = (
+                {o: PLAN_OUTCOMES.value(outcome=o) for o in self.OUTCOMES},
+                {p: EDGE_WEIGHTS.value(path=p) for p in self.PATHS},
+            )
+            backend.delta_rows = None if rows is None else np.asarray(rows)
+            result = backend.converge(g, alpha=0.1, tol=1e-7, max_iter=30)
+            (outcome,) = [
+                o for o in self.OUTCOMES if PLAN_OUTCOMES.value(outcome=o) > before[0][o]
+            ]
+            grew = {p: EDGE_WEIGHTS.value(path=p) - before[1][p] for p in self.PATHS}
+            out.append((outcome, grew, seen[-1], backend.last_plan, result))
+        return out
+
+    def test_delta_chain_divides_only_what_the_plan_reads(self, monkeypatch):
+        from protocol_tpu.trust.graph import NormalizedGraph
+
+        chain = self._chain(monkeypatch)
+        assert [c[0] for c in chain] == [
+            "rebuild", "delta", "delta", "delta", "reuse", "rebuild"
+        ]
+        for outcome, grew, ng, _, _ in chain:
+            if outcome == "rebuild":
+                assert grew["full"] == 1 and "w" in ng.__dict__
+            else:  # the fingerprint's samples, and a delta's rows
+                assert grew == {"rows": 1 + (outcome == "delta"), "full": 0}
+                assert "w" not in ng.__dict__
+
+        monkeypatch.setattr(NormalizedGraph, "weights_of", lambda self, sel: self.w[sel])
+        whole = self._chain(monkeypatch)
+        for (_, _, ng, plan, res), (_, _, ng_w, plan_w, res_w) in zip(chain, whole):
+            assert ng.fingerprint == ng_w.fingerprint == plan.fingerprint
+            for k in WindowPlan._CORE + WindowPlan._HOST:
+                a, b = getattr(plan, k), getattr(plan_w, k)
+                assert (a is None and b is None) or np.array_equal(a, b), k
+            assert {k: getattr(plan, k) for k in WindowPlan._META} == {
+                k: getattr(plan_w, k) for k in WindowPlan._META
+            }
+            assert res.scores.tobytes() == res_w.scores.tobytes()
+            assert res.iterations == res_w.iterations
 
 
 class TestShardedWindowedBackend:
